@@ -61,7 +61,7 @@ def main():
         dt = (time.perf_counter() - t0) / args.steps
     print(f"{args.batch * args.seq / dt:,.0f} tokens/s "
           f"({dt * 1e3:.1f} ms/step, includes host sync each step — "
-          f"see bench.py for the RTT-amortized measurement)")
+          f"see bench.py for the async-window measurement)")
 
 
 if __name__ == "__main__":

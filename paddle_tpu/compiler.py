@@ -129,6 +129,36 @@ class CompiledProgram:
                                          else batch_axes[0]))
         return NamedSharding(mesh, P())
 
+    def state_sharding(self, name) -> Optional[NamedSharding]:
+        """Placement of one persistable under the mesh: state_spec_fn's
+        spec, replicated by default; None when sharding is inactive.
+        build_jit pins the step's state in/out shardings to this, and
+        the Executor places the scope's state with it before the first
+        call: jax types a mesh-placed array differently from a
+        single-device one, so state left where the startup program put
+        it would trace and compile the whole step a second time, once
+        the first step's outputs came back on the mesh."""
+        if not self._is_data_parallel or len(jax.devices()) == 1:
+            return None
+        spec = self._state_spec_fn(name) \
+            if self._state_spec_fn is not None else None
+        return NamedSharding(self.mesh(), spec if spec is not None
+                             else P())
+
+    def place_state(self, scope, names):
+        """Put the scope's persistables `names` where state_sharding
+        says (the Executor calls this once per new executable).
+        Multi-process runs globalize inside build_jit's wrapper
+        instead: a process-local array cannot be device_put onto
+        another process's devices."""
+        if jax.process_count() > 1:
+            return
+        for n in names:
+            ns = self.state_sharding(n)
+            v = scope.find_var(n)
+            if ns is not None and v is not None:
+                scope.set(n, jax.device_put(v, ns))
+
     def build_jit(self, step_fn, state_in_names, feed_arrays,
                   state_out_names=()):
         """jit `step_fn(state, feeds, step_idx)` with SPMD shardings:
@@ -143,12 +173,7 @@ class CompiledProgram:
             return jax.jit(step_fn, donate_argnums=(0,))
         mesh = self.mesh()
         repl = NamedSharding(mesh, P())
-        spec_fn = self._state_spec_fn
-
-        def shard_of(n):
-            spec = spec_fn(n) if spec_fn is not None else None
-            return NamedSharding(mesh, spec) if spec is not None else repl
-
+        shard_of = self.state_sharding
         state_shard = {n: shard_of(n) for n in state_in_names}
         unknown = [a for a in self._batch_axes if a not in mesh.axis_names]
         if unknown:

@@ -215,10 +215,11 @@ DEFINE_int32(
     "Fallback q-block tile for the Pallas flash-attention kernel when "
     "the op attr is unset AND the autotune cache has no entry for the "
     "shape (FLAGS_flash_autotune). Multiples of 128 only; clamped to "
-    "the largest divisor of the (padded) sequence. 512 is the measured "
-    "v5e winner at seq 512/1024/2048 — 2x faster fwd+bwd than XLA "
-    "composed attention, where 128 was 2-4x SLOWER (PERF.md r05 "
-    "attention microbench; docs/attention_tuning.md).", traced=True)
+    "the largest divisor of the (padded) sequence. 512 won a "
+    "kernel-only microbench of 2026-08-02 on a v5e at seq "
+    "512/1024/2048, where 128 was 2-4x slower "
+    "(docs/attention_tuning.md; the end-to-end race is ROADMAP queue 1 "
+    "item 7).", traced=True)
 
 DEFINE_int32(
     "flash_attention_block_k", 512,
@@ -240,15 +241,18 @@ DEFINE_string(
 DEFINE_string(
     "flash_autotune_cache", "",
     "Path of the persistent flash-tile cache (JSON). Empty = "
-    "flash_autotune.json alongside the JAX compilation cache dir, or "
-    "~/.cache/paddle_tpu when no compilation cache is configured. "
+    "flash_autotune.json in the compilation-cache directory "
+    "(core/compile_cache.py: JAX_COMPILATION_CACHE_DIR, else "
+    "<checkout>/.jax_cache). "
     "Seed it from real chip time with tools/attn_micro.py "
     "--emit-cache.")
 
 DEFINE_bool(
     "pallas_interpret", False,
     "Force Pallas kernels into interpret mode even on TPU (debugging "
-    "numerics; very slow).", traced=True)
+    "numerics; very slow). Without it kernels interpret only on the "
+    "CPU backend; a backend that is neither CPU nor TPU raises.",
+    traced=True)
 
 DEFINE_bool(
     "op_trace_scopes", True,
@@ -288,12 +292,14 @@ DEFINE_int64(
     "memory_budget_bytes", 0,
     "HBM budget for the static memory gate (analysis/memory.py). 0 "
     "(default) = auto: use the device's reported bytes_limit "
-    "(core.memory.device_memory_stats) when the backend reports one, "
-    "otherwise no budget — CPU backends report nothing, so the gate "
-    "never fires there. -1 = never apply a budget even when the device "
-    "reports a limit. Any positive value is the budget in bytes. "
-    "PTV050 fires when a program's estimated peak exceeds it, PTV051 "
-    "when one tensor alone does. Docs: docs/memory_planning.md.")
+    "(core.memory.device_memory_stats) when the backend reports one "
+    "(CPU backends report nothing). Findings against the auto budget "
+    "only warn, whatever FLAGS_memory_gate says, so default flags give "
+    "the same verdict on every backend. -1 = never apply a budget. Any "
+    "positive value is an explicit budget in bytes, and the only kind "
+    "the gate raises on. PTV050 fires when a program's estimated peak "
+    "exceeds the budget, PTV051 when one tensor alone does. Docs: "
+    "docs/memory_planning.md.")
 
 DEFINE_string(
     "memory_gate", "error",
@@ -302,12 +308,15 @@ DEFINE_string(
     "analysis; 'warn' = analyze once per (fingerprint, feed shapes, "
     "fetches, budget) and surface PTV05x findings as one summarized "
     "warning; 'error' (default) = raise ProgramVerificationError on "
-    "PTV050/PTV051 — in Executor._resolve_step BEFORE the executable "
-    "cache records a miss, and in ServingEngine.warmup before any "
-    "ladder cell compiles — so a program that cannot fit is rejected "
-    "with zero compiles attempted. Estimates with unresolved dynamic "
-    "dims are documented lower bounds and the finding says so "
-    "(Spec.nbytes). Docs: docs/memory_planning.md.")
+    "PTV050/PTV051 against an explicit positive "
+    "FLAGS_memory_budget_bytes — in Executor._resolve_step BEFORE the "
+    "executable cache records a miss, and in ServingEngine.warmup "
+    "before any ladder cell compiles — so a program that cannot fit "
+    "is rejected with zero compiles attempted. Against the "
+    "auto-detected device limit the findings warn and the program "
+    "goes on to XLA, whose own buffer assignment decides. Estimates "
+    "with unresolved dynamic dims are documented lower bounds and the "
+    "finding says so (Spec.nbytes). Docs: docs/memory_planning.md.")
 
 DEFINE_string(
     "sharding_verify", "warn",
@@ -411,8 +420,7 @@ DEFINE_bool(
     "KV cache (serving/kv_blocks.py + models/gpt."
     "build_paged_decode_step) with prefix caching and chunked prefill; "
     "False = the PR-7 contiguous [max_slots, max_seq] slab decode, "
-    "retained for the paged-vs-slab A/B (sweep_driver "
-    "gen_paged_vs_slab pair). Host-side program choice only — not part "
+    "retained for a paged-vs-slab A/B. Host-side program choice only — not part "
     "of any executable cache key.")
 
 DEFINE_int32(

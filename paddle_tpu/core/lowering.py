@@ -167,10 +167,7 @@ def run_op(op, env, ctx, op_idx=None):
                       for s, vs in ins.items()}
             note = (f"[operator {op.type!r}] inputs {shapes} -> outputs "
                     f"{dict(op.outputs)}, attrs {op.attrs}")
-            if hasattr(e, "add_note"):  # PEP 678, Python >= 3.11
-                e.add_note(note)
-            else:
-                e.__notes__ = [*getattr(e, "__notes__", []), note]
+            e.add_note(note)
             raise
         check = FLAGS.check_nan_inf
         for slot, names in op.outputs.items():

@@ -72,8 +72,7 @@ CUDAPinnedPlace = CPUPlace
 
 
 def default_place() -> Place:
-    try:
-        kind = jax.devices()[0].platform
-    except RuntimeError:
-        kind = "cpu"
+    """The place of jax's default backend. A backend that cannot be
+    initialised raises here; it is never replaced by the CPU."""
+    kind = jax.devices()[0].platform
     return CPUPlace() if kind == "cpu" else XLAPlace()

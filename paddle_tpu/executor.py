@@ -363,6 +363,11 @@ class Executor:
             self._cache[key] = step_fn
             if compiled is not None:
                 self._compiled_refs[id(compiled)] = compiled
+                # once per executable: the scope's state goes onto the
+                # mesh now, so that the first call sees what every later
+                # call sees and the step compiles once
+                # (CompiledProgram.state_sharding)
+                compiled.place_state(scope, step_fn.state_in_names)
             from .core.flags import FLAGS
             cap = FLAGS.executor_cache_capacity
             while cap > 0 and len(self._cache) > cap:
@@ -623,7 +628,7 @@ class Executor:
     def lowered_stablehlo(self, program=None, feed=None, fetch_list=None,
                           scope: Optional[Scope] = None) -> str:
         """StableHLO text of the jitted whole-block step for (program,
-        feed, fetch_list) — the audit surface behind PERF.md's bf16
+        feed, fetch_list) — the audit surface behind the bf16
         dot/conv checks (tools/hlo_audit.py). No reference equivalent:
         the reference interprets ops one-by-one, so there is no single
         compiled artifact to audit."""
@@ -666,13 +671,16 @@ class Executor:
                                   dialect="stablehlo")
         return ir.operation.get_asm(enable_debug_info=True)
 
-    def compiled_hlo(self, program=None, feed=None, fetch_list=None,
-                     scope: Optional[Scope] = None) -> str:
-        """Post-optimization HLO text of the jitted step. Every fused
-        instruction carries metadata={op_name="...{op.type}:{blk}/{idx}
-        ..."} (FLAGS_op_trace_scopes), which is the join key
-        tools/op_profile.py uses to attribute XPlane trace events back
-        to framework ops (reference print_profiler's per-op table)."""
+    def compiled(self, program=None, feed=None, fetch_list=None,
+                 scope: Optional[Scope] = None):
+        """The jitted step for (program, feed, fetch_list), lowered and
+        compiled ahead of time: a `jax.stages.Compiled`, whose
+        `as_text()` is the post-optimization HLO and whose
+        `memory_analysis()` is XLA's own account of argument, output
+        and temporary bytes — the compiled counterpart of the static
+        planner's estimate (analysis/memory.py). Runs nothing; with a
+        persistent compilation cache a step that already ran is read
+        back from it."""
         from .compiler import CompiledProgram  # local: avoid cycle
 
         if program is None:
@@ -685,8 +693,21 @@ class Executor:
         scope = scope or global_scope()
         step_fn, state, feed_arrays = self._resolve_step(
             program, feed, fetch_list, scope, compiled)
-        return step_fn.fn.lower(state, feed_arrays,
-                                jnp.uint32(0)).compile().as_text()
+        # the same default device as run(): it is part of jit's cache
+        # key, and without it a step that already ran is traced, lowered
+        # and compiled all over again
+        with jax.default_device(self.place.jax_device()):
+            return step_fn.fn.lower(state, feed_arrays,
+                                    jnp.uint32(0)).compile()
+
+    def compiled_hlo(self, program=None, feed=None, fetch_list=None,
+                     scope: Optional[Scope] = None) -> str:
+        """Post-optimization HLO text of the jitted step. Every fused
+        instruction carries metadata={op_name="...{op.type}:{blk}/{idx}
+        ..."} (FLAGS_op_trace_scopes), which is the join key
+        tools/op_profile.py uses to attribute XPlane trace events back
+        to framework ops (reference print_profiler's per-op table)."""
+        return self.compiled(program, feed, fetch_list, scope).as_text()
 
     def cache_stats(self) -> Dict[str, int]:
         """Per-instance executable-cache counters (the global

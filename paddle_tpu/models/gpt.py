@@ -523,7 +523,7 @@ def _ensure_decode_state(scope, blk, cache_names):
 
 def kv_generate(exe, scope, decode_prog, token_var, logits_var,
                 cache_names, prompt, max_new_tokens, temperature=0.0,
-                seed=0, top_k=0, stream_cb=None):
+                seed=0, top_k=0, stream_cb=None, logits_cb=None):
     """Autoregressive generation over the KV-cache decode step: feed
     the prompt token by token (prefill), then sample/argmax the
     continuation.
@@ -538,8 +538,12 @@ def kv_generate(exe, scope, decode_prog, token_var, logits_var,
 
     `stream_cb(token_id)` (optional) fires after each generated token —
     the serial-baseline hook the generation loadgen uses for TTFT /
-    inter-token timing. `top_k` > 0 restricts sampling to the k highest
-    logits (see models/sampling.py)."""
+    inter-token timing. `logits_cb(logits_row)` (optional) sees the
+    [vocab] logits each generated token is chosen from, before the
+    choice — what a caller needs to tell a near-tie from a wrong answer
+    when it compares another decode path against this one (chip_smoke.py).
+    `top_k` > 0 restricts sampling to the k highest logits (see
+    models/sampling.py)."""
     import paddle_tpu as fluid
 
     if not len(prompt):
@@ -586,7 +590,10 @@ def kv_generate(exe, scope, decode_prog, token_var, logits_var,
         out = []
         cur = int(prompt[-1])
         for _ in range(max_new_tokens):
-            cur = _sample(step(cur), temperature, rng, top_k=top_k)
+            row = step(cur)
+            if logits_cb is not None:
+                logits_cb(row)
+            cur = _sample(row, temperature, rng, top_k=top_k)
             out.append(cur)
             if stream_cb is not None:
                 stream_cb(cur)

@@ -244,8 +244,8 @@ def build_train_mlm(cfg: TransformerConfig, batch, seq_len, n_mask,
     objective (BERT gathers mask positions the same way; the full-T
     lm head in build_train is the GPT-shaped objective). At 15% masking
     this removes ~85% of the lm-head matmul + vocab-wide CE + their
-    backward, the single largest cost block in the measured step
-    (PERF.md r05 profile: lm-head fwd/bwd/CE fusions ~87 of 185 ms).
+    backward, the single largest cost block of the step in a profile
+    of 2026-08-02 (a removed setup; ROADMAP queue 1 item 6).
 
     Feeds: tokens [b, T] int64; mask_pos [b*n_mask] int32 (flattened
     row-major indices into [b*T]); mask_label [b*n_mask, 1] int64.

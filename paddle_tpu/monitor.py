@@ -628,7 +628,7 @@ def export_chrome_tracing(path: str) -> int:
 # ---------------------------------------------------------------------------
 # Background exporter: periodic JSONL snapshots so even a run the
 # harness timeout-kills leaves a usable log behind (the failure mode
-# that produced BENCH_r05's `parsed: null`).
+# that leaves a driver wrapper with `parsed: null`).
 # ---------------------------------------------------------------------------
 
 _exporter = None

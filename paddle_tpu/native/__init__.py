@@ -75,11 +75,15 @@ def _load():
         return lib
 
 
+# AVAILABLE False = no toolchain or a failed build: consumers fall back
+# to Python, and LOAD_ERROR says why (chip_smoke.py prints both).
+LOAD_ERROR = None
 try:
     _load()
     AVAILABLE = True
-except Exception:  # toolchain missing — consumers fall back to Python
+except (OSError, subprocess.CalledProcessError) as e:
     AVAILABLE = False
+    LOAD_ERROR = f"{type(e).__name__}: {e}"
 
 
 def version() -> str:

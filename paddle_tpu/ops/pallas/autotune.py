@@ -16,8 +16,8 @@ This module makes the choice measured, cached, and shared:
     simply falls back to `FLAGS_flash_attention_block_{q,k}`, so CPU
     tier-1 runs pay one dict lookup and nothing else. `off` disables
     even the lookup.
-  * The JSON cache (`FLAGS_flash_autotune_cache`, default alongside the
-    JAX compilation cache) can be seeded from real chip time by
+  * The JSON cache (`FLAGS_flash_autotune_cache`, default in the
+    compilation-cache directory of core/compile_cache.py) can be seeded from real chip time by
     `tools/attn_micro.py --emit-cache`, so one microbench run tunes
     every later process.
 
@@ -58,21 +58,14 @@ def cache_key(t: int, d: int, dtype, causal: bool) -> str:
 
 
 def default_cache_path() -> str:
-    """FLAGS_flash_autotune_cache, or a file alongside the JAX
-    compilation cache (falling back to ~/.cache/paddle_tpu)."""
+    """FLAGS_flash_autotune_cache, or a file in the compilation-cache
+    directory (core/compile_cache.py: JAX_COMPILATION_CACHE_DIR, else
+    <checkout>/.jax_cache)."""
+    from ...core.compile_cache import compile_cache_dir
     from ...core.flags import FLAGS
     if FLAGS.flash_autotune_cache:
         return FLAGS.flash_autotune_cache
-    cache_dir = None
-    try:
-        import jax
-        cache_dir = jax.config.jax_compilation_cache_dir
-    except Exception:  # noqa: BLE001 — path resolution must never raise
-        cache_dir = None
-    if not cache_dir:
-        cache_dir = os.path.join(os.path.expanduser("~"), ".cache",
-                                 "paddle_tpu")
-    return os.path.join(cache_dir, "flash_autotune.json")
+    return os.path.join(compile_cache_dir(), "flash_autotune.json")
 
 
 def load_cache(path: Optional[str] = None) -> Dict[str, dict]:
@@ -150,10 +143,11 @@ def _on_device() -> bool:
 
 
 def _sweep(t: int, d: int, dtype, causal: bool,
-           iters: int = 5) -> Optional[Tuple[int, int]]:
+           iters: int = 5) -> Tuple[int, int]:
     """Time the candidate grid (fwd+bwd, q=k tiles) on the real device
-    and return the winner. Any failure returns None — tuning must never
-    take a training run down."""
+    and return the winner. A candidate that does not compile or run
+    raises: every candidate is a tile the flags may select, so a kernel
+    failure here is a kernel failure, not a reason to pick another."""
     import jax
     import jax.numpy as jnp
     from .flash_attention import _pick_block, flash_attention
@@ -161,32 +155,29 @@ def _sweep(t: int, d: int, dtype, causal: bool,
     candidates = sorted({_pick_block(t, c) for c in CANDIDATE_BLOCKS})
     if len(candidates) == 1:
         return candidates[0], candidates[0]
-    try:
-        key = jax.random.PRNGKey(0)
-        bh = 8
-        q = jax.random.normal(key, (bh, t, d), jnp.dtype(dtype))
-        k = jax.random.normal(key, (bh, t, d), jnp.dtype(dtype))
-        v = jax.random.normal(key, (bh, t, d), jnp.dtype(dtype))
-        best, best_dt = None, None
-        for blk in candidates:
-            def loss(q_, k_, v_, _blk=blk):
-                return jnp.sum(flash_attention(
-                    q_, k_, v_, causal=causal, block_q=_blk,
-                    block_k=_blk).astype(jnp.float32))
+    key = jax.random.PRNGKey(0)
+    bh = 8
+    q = jax.random.normal(key, (bh, t, d), jnp.dtype(dtype))
+    k = jax.random.normal(key, (bh, t, d), jnp.dtype(dtype))
+    v = jax.random.normal(key, (bh, t, d), jnp.dtype(dtype))
+    best, best_dt = None, None
+    for blk in candidates:
+        def loss(q_, k_, v_, _blk=blk):
+            return jnp.sum(flash_attention(
+                q_, k_, v_, causal=causal, block_q=_blk,
+                block_k=_blk).astype(jnp.float32))
 
-            g = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)))
+        g = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)))
+        out = g(q, k, v)
+        jax.block_until_ready(out)   # compile outside the window
+        t0 = time.perf_counter()
+        for _ in range(iters):
             out = g(q, k, v)
-            jax.block_until_ready(out)   # compile outside the window
-            t0 = time.perf_counter()
-            for _ in range(iters):
-                out = g(q, k, v)
-            jax.block_until_ready(out)
-            dt = (time.perf_counter() - t0) / iters
-            if best_dt is None or dt < best_dt:
-                best, best_dt = blk, dt
-        return (best, best) if best is not None else None
-    except Exception:  # noqa: BLE001 — fall back to the flag default
-        return None
+        jax.block_until_ready(out)
+        dt = (time.perf_counter() - t0) / iters
+        if best_dt is None or dt < best_dt:
+            best, best_dt = blk, dt
+    return best, best
 
 
 def resolve(t: int, d: int, dtype, causal: bool) \
@@ -224,8 +215,6 @@ def resolve(t: int, d: int, dtype, causal: bool) \
     tuned = _sweep(t, d, dtype, causal)
     STAT_OBSERVE("flash.autotune_sweep_seconds",
                  time.perf_counter() - t0)
-    if tuned is None:
-        return None
     with _LOCK:
         _MEMO[memo_key] = tuned
     try:

@@ -11,7 +11,9 @@ k-block) tile recompute p = exp(qk - lse), accumulate dq, dk, dv. Wired to
 jax.custom_vjp so both the IR-level generic grad (core/lowering.py) and
 dygraph tape differentiate through it for free.
 
-Falls back to interpret mode off-TPU (CPU tests), same numerics.
+Runs in interpret mode on the CPU backend (tests) and under
+FLAGS_pallas_interpret, same numerics; on any other backend that is not
+a TPU it raises.
 """
 from __future__ import annotations
 
@@ -21,18 +23,25 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # TPU-specific memory spaces; absent on some CPU-only builds
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except ImportError:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _interpret():
+    """Interpret mode only where it is asked for (FLAGS_pallas_interpret)
+    or where there is no device to run a kernel on (the CPU backend). A
+    backend that is neither a TPU nor the CPU is an error: a Mosaic
+    kernel cannot run there, and interpreting it silently would pass a
+    run that never touched the kernel."""
     from ...core.flags import FLAGS
-    return FLAGS.pallas_interpret or jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if FLAGS.pallas_interpret or backend == "cpu":
+        return True
+    if backend != "tpu":
+        raise RuntimeError(
+            f"flash_attention: backend {backend!r} is neither a TPU nor "
+            f"the CPU; set FLAGS_pallas_interpret to interpret the "
+            f"kernel there")
+    return False
 
 
 NEG_INF = -1e30
@@ -112,20 +121,12 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_s, l_s, acc_s, *,
             (8, block_q))
 
 
-def _grid_kw():
-    """compiler_params kwargs: bh/q dims parallel, the streamed dim
-    arbitrary (sequential — scratch state persists across it). Old
-    pallas (jax<=0.4.x) spells this TPUCompilerParams with string
-    semantics instead of CompilerParams with the enum."""
-    cp = getattr(pltpu, "CompilerParams", None)
-    if cp is not None:
-        sem = pltpu.GridDimensionSemantics
-        params = cp(dimension_semantics=(
-            sem.PARALLEL, sem.PARALLEL, sem.ARBITRARY))
-    else:
-        params = pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
-    return {"compiler_params": params}
+def _compiler_params():
+    """bh/q dims parallel, the streamed dim arbitrary (sequential —
+    scratch state persists across it)."""
+    sem = pltpu.GridDimensionSemantics
+    return pltpu.CompilerParams(dimension_semantics=(
+        sem.PARALLEL, sem.PARALLEL, sem.ARBITRARY))
 
 
 def _scratch(shape):
@@ -151,10 +152,7 @@ def _fwd(q, k, v, sm_scale, causal, block_q, block_k, kv_len):
     grid = (bh, t // block_q, t // block_k)
     kernel = functools.partial(_fwd_kernel, sm_scale=sm_scale,
                                causal=causal, kv_len=kv_len, t=t)
-    kw = {}
-    if _VMEM is not None:
-        kw = {"memory_space": _VMEM}
-    extra = _grid_kw()
+    kw = {"memory_space": pltpu.VMEM}
     kv_idx = _kv_index(causal, block_q, block_k)
     o, lse = pl.pallas_call(
         kernel,
@@ -175,7 +173,8 @@ def _fwd(q, k, v, sm_scale, causal, block_q, block_k, kv_len):
         scratch_shapes=[_scratch((block_q, 1)), _scratch((block_q, 1)),
                         _scratch((block_q, d))],
         interpret=_interpret(),
-        **extra,
+        compiler_params=_compiler_params(),
+        name="flash_attention_fwd",
     )(q, k, v)
     return o, lse
 
@@ -290,10 +289,7 @@ def _bwd(sm_scale, causal, block_q, block_k, kv_len, res, do):
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
     # replicate across the 8-sublane dim to match the lse carry layout
     delta = jnp.broadcast_to(delta[:, None, :], (bh, 8, t))
-    kw = {}
-    if _VMEM is not None:
-        kw = {"memory_space": _VMEM}
-    extra = _grid_kw()
+    kw = {"memory_space": pltpu.VMEM}
 
     # dq pass: (bh, q, k) — fix q block on the middle dim
     spec_q_qk = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0),
@@ -312,7 +308,8 @@ def _bwd(sm_scale, causal, block_q, block_k, kv_len, res, do):
         out_shape=jax.ShapeDtypeStruct((bh, t, d), q.dtype),
         scratch_shapes=[_scratch((block_q, d))],
         interpret=_interpret(),
-        **extra,
+        compiler_params=_compiler_params(),
+        name="flash_attention_bwd_dq",
     )(q, k, v, delta, lse, do)
 
     # dk/dv pass: (bh, k, q) — fix k block on the middle dim. Causal:
@@ -347,7 +344,8 @@ def _bwd(sm_scale, causal, block_q, block_k, kv_len, res, do):
         out_shape=[jax.ShapeDtypeStruct((bh, t, d), q.dtype)] * 2,
         scratch_shapes=[_scratch((block_k, d)), _scratch((block_k, d))],
         interpret=_interpret(),
-        **extra,
+        compiler_params=_compiler_params(),
+        name="flash_attention_bwd_dkv",
     )(q, k, v, delta, lse, do)
     return dq, dk, dv
 
@@ -418,9 +416,8 @@ def flash_attention(q, k, v, causal=False, sm_scale=None, block_q=None,
     t, d = q.shape[1], q.shape[2]
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(d)
-    if t < 128 or pltpu is None:
-        # short sequences: exact path is cheaper than kernel padding;
-        # builds without pallas-TPU (no pltpu.VMEM scratch) also take it
+    if t < 128:
+        # short sequences: exact path is cheaper than kernel padding
         out = reference_attention(q, k, v, causal=causal, sm_scale=sm_scale)
         return out.reshape(orig_shape)
     # Pad T to a 128-multiple so every length stays on the flash path; the

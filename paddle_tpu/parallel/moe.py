@@ -119,8 +119,7 @@ def _moe_shard_map(inner, x, params, mesh, ep_axis, batch_axis,
     fn = functools.partial(inner, axis_name=ep_axis,
                            n_experts_global=params["gate_w"].shape[-1],
                            batch_axis=reduce_axes or None, **kw)
-    from ..core.jax_compat import shard_map
-    sm = shard_map(fn, mesh=mesh, in_specs=(x_spec, param_specs),
+    sm = jax.shard_map(fn, mesh=mesh, in_specs=(x_spec, param_specs),
                    out_specs=(x_spec, P()), check_vma=False)
     return sm(x, params)
 
@@ -153,8 +152,7 @@ def moe_ffn_sparse(x, params, axis_name="ep", capacity=None,
     w2, b2 = params["w2"], params["b2"]
     e_local = w1.shape[0]
     e_global = n_experts_global or gate_w.shape[-1]
-    from ..core.jax_compat import axis_size
-    n_shards = axis_size(axis_name)
+    n_shards = jax.lax.axis_size(axis_name)
     b, t, d = x.shape
     n = b * t
     if capacity is None:

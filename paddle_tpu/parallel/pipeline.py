@@ -27,7 +27,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..core.jax_compat import pcast, shard_map
 
 from .mesh import get_mesh
 
@@ -89,8 +88,8 @@ def gpipe(stage_fn: Callable, stacked_params, x, *, n_microbatches: int,
         # The carry is device-varying over the pp axis (each stage holds a
         # different activation), so the init must be cast to varying for
         # shard_map's per-axis type check to accept the scan.
-        init = pcast((jnp.zeros_like(x_mb[0]),
-                      jnp.zeros_like(x_mb)), axis, to="varying")
+        init = jax.lax.pcast((jnp.zeros_like(x_mb[0]),
+                              jnp.zeros_like(x_mb)), axis, to="varying")
         (_, outputs), _ = jax.lax.scan(
             body, init, jnp.arange(n_microbatches + n_stages - 1))
         # outputs are only valid on the last stage; replicate across pp
@@ -98,8 +97,9 @@ def gpipe(stage_fn: Callable, stacked_params, x, *, n_microbatches: int,
         return jax.lax.psum(outputs * mask, axis)
 
     pspec = jax.tree.map(lambda _: P(axis), stacked_params)
-    out = shard_map(run, mesh=mesh, in_specs=(pspec, P()), out_specs=P(),
-                    axis_names={axis})(stacked_params, x_mb)
+    out = jax.shard_map(run, mesh=mesh, in_specs=(pspec, P()),
+                        out_specs=P(),
+                        axis_names={axis})(stacked_params, x_mb)
     return out.reshape(batch, *out.shape[2:])
 
 

@@ -23,7 +23,6 @@ import math
 import jax
 import jax.numpy as jnp
 
-from ..core.jax_compat import axis_size
 from jax.sharding import PartitionSpec as P
 
 NEG_INF = -1e30
@@ -71,7 +70,7 @@ def _lse_merge(o, lse, o_i, lse_i):
 
 
 def _ring_fwd_loop(q, k, v, axis_name, causal, sm_scale):
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     t_local = q.shape[2]
     q_off = idx * t_local
@@ -116,7 +115,7 @@ def _ring_vjp_bwd(axis_name, causal, sm_scale, res, do):
     (a custom-vjp backward is safe from jax's dot-transpose f32
     poisoning; see ops/math.py:_mul)."""
     q, k, v, o, lse = res
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     t_local = q.shape[2]
     q_off = idx * t_local
@@ -177,14 +176,13 @@ def ring_attention_sharded(q, k, v, mesh, seq_axis, causal=False,
                            sm_scale=None, batch_axis=None):
     """Global [b, h, T, d] arrays -> shard_map over the mesh seq axis
     (+ optional batch axis on dim 0)."""
-    from jax.experimental.shard_map import shard_map
     spec = P(batch_axis, None, seq_axis, None)
 
     fn = functools.partial(ring_attention, axis_name=seq_axis,
                            causal=causal, sm_scale=sm_scale)
-    sm = shard_map(lambda q_, k_, v_: fn(q_, k_, v_), mesh=mesh,
-                   in_specs=(spec, spec, spec), out_specs=spec,
-                   check_rep=False)
+    sm = jax.shard_map(lambda q_, k_, v_: fn(q_, k_, v_), mesh=mesh,
+                       in_specs=(spec, spec, spec), out_specs=spec,
+                       check_vma=False)
     return sm(q, k, v)
 
 

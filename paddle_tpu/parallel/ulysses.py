@@ -28,8 +28,7 @@ def ulysses_attention(q, k, v, axis_name="sp", causal=False,
     """Inside shard_map: q/k/v are LOCAL sequence chunks
     [b, h, t_local, d] with h divisible by the axis size. Returns the
     local output chunk [b, h, t_local, d]."""
-    from ..core.jax_compat import axis_size
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     h = q.shape[1]
     if h % n:
         raise ValueError(
@@ -105,12 +104,9 @@ def ulysses_attention_sharded(q, k, v, mesh, seq_axis, causal=False,
     spec = P(batch_axis, None, seq_axis, None)
     fn = functools.partial(ulysses_attention, axis_name=seq_axis,
                            causal=causal, sm_scale=sm_scale)
-    # core.jax_compat: jax.shard_map (check_vma) on new jax, the
-    # experimental home (check_rep) on old
-    from ..core.jax_compat import shard_map
-    sm = shard_map(lambda q_, k_, v_: fn(q_, k_, v_), mesh=mesh,
-                   in_specs=(spec, spec, spec), out_specs=spec,
-                   check_vma=False)
+    sm = jax.shard_map(lambda q_, k_, v_: fn(q_, k_, v_), mesh=mesh,
+                       in_specs=(spec, spec, spec), out_specs=spec,
+                       check_vma=False)
     return sm(q, k, v)
 
 

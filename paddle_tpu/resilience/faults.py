@@ -15,7 +15,7 @@ Spec grammar (comma-separated ``kind:param=value[:param=value]``)::
     slow_step:ms=500:p=0.1     sleep before dispatch (stuck-step /
                                straggler model; p defaults to 1)
     transient_fail:p=0.02      raise TransientFault BEFORE device
-                               dispatch (flaky-tunnel / infeed model;
+                               dispatch (flaky-transport / infeed model;
                                retry-safe by construction)
     preempt_at:step=40         deliver SIGTERM to this process when the
                                hook sees global step 40 (one-shot;

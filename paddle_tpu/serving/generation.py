@@ -413,45 +413,55 @@ class GenerationEngine:
         self.exe.run(self._startup, scope=self.scope)
         return self
 
+    def executables(self):
+        """The fixed-shape executables of the engine's lifetime, as
+        (name, program, feed, fetch_var) with every slot muted: slab —
+        one decode step; paged — decode + chunk prefill (+ the spec
+        verify step). `start()` warms exactly these; a caller can hand
+        one to `exe.compiled(...)` to read its HLO or XLA's memory
+        analysis, or run it again with other feed containers of the
+        same shapes to check that nothing recompiles."""
+        B = self.max_slots
+        if not self.paged:
+            return [("decode", self._prog,
+                     {self.step.token_var.name:
+                      np.zeros((B, 1), np.int64),
+                      self.step.reset_var.name: np.ones(B, np.float32),
+                      self.step.active_var.name:
+                      np.zeros(B, np.float32)},
+                     self.step.logits_var)]
+        cells = [("decode", self._prog, self.step, 1),
+                 ("prefill", self._prefill_prog, self.prefill_step,
+                  self.block_size)]
+        if self.spec_step is not None:
+            cells.append(("spec_verify", self._spec_prog, self.spec_step,
+                          self.spec_k + 1))
+        mb = self.step.max_blocks_per_slot
+        return [(name, prog,
+                 {step.token_var.name: np.zeros((B, t), np.int64),
+                  step.table_var.name: np.zeros((B, mb), np.int64),
+                  step.start_var.name: np.zeros(B, np.int64),
+                  step.nvalid_var.name: np.zeros(B, np.int64)},
+                 step.logits_var)
+                for name, prog, step, t in cells]
+
     def start(self):
         """Seed the decode state, run one warmup step per executable
-        (slab: one; paged: decode + chunk prefill — ALL the compiles of
-        the engine's lifetime, slots muted), then start the worker
-        thread."""
+        (`executables()` — ALL the compiles of the engine's lifetime,
+        slots muted), then start the worker thread."""
         if self._worker is not None:
             return self
         from ..models import gpt
         blk = self._prog.global_block()
         gpt._ensure_decode_state(self.scope, blk, self.step.cache_names)
+        for _, prog, feed, fetch in self.executables():
+            self.exe.run(prog, feed=feed, fetch_list=[fetch],
+                         scope=self.scope)
         if self.paged:
-            B = self.max_slots
-            mb = self.step.max_blocks_per_slot
-            self._run_paged(self._prog, self.step,
-                            np.zeros((B, 1), np.int64),
-                            np.zeros((B, mb), np.int64),
-                            np.zeros(B, np.int64),
-                            np.zeros(B, np.int64))
-            self._run_paged(self._prefill_prog, self.prefill_step,
-                            np.zeros((B, self.block_size), np.int64),
-                            np.zeros((B, mb), np.int64),
-                            np.zeros(B, np.int64),
-                            np.zeros(B, np.int64))
-            if self.spec_step is not None:
-                # the verify executable's one compile of the lifetime
-                self._run_paged(self._spec_prog, self.spec_step,
-                                np.zeros((B, self.spec_k + 1),
-                                         np.int64),
-                                np.zeros((B, mb), np.int64),
-                                np.zeros(B, np.int64),
-                                np.zeros(B, np.int64))
             STAT_SET("serving.gen_kv_blocks_total",
                      self._pool.capacity())
             STAT_SET("serving.gen_kv_blocks_free",
                      self._pool.free_count())
-        else:
-            self._run_step(np.zeros((self.max_slots, 1), np.int64),
-                           reset=np.ones(self.max_slots, np.float32),
-                           active=np.zeros(self.max_slots, np.float32))
         self._warm_misses = self.cache_stats()["misses"]
         self._closed = False
         self._worker = threading.Thread(target=self._worker_loop,

@@ -188,15 +188,28 @@ def _run_gate(args, cwd=REPO):
         capture_output=True, text=True, timeout=120)
 
 
-def test_e2e_checked_in_history_gate(tmp_path):
-    """Ingest BENCH_r01..r05 (only r02 carries a real number — the
-    null/errored wrappers are skipped, not averaged), add two synthetic
+def test_e2e_wrapper_history_gate(tmp_path):
+    """Ingest five driver wrappers of which one carries a real number
+    (the null/errored ones are skipped, not averaged), add two synthetic
     same-config runs to reach min-samples, then: a 25% lower candidate
     exits nonzero with a validated kind="perf_gate" report; the
     unchanged value exits 0; and metrics_report renders the section."""
     ledger = tmp_path / "perf_ledger.jsonl"
-    paths = [os.path.join(REPO, f"BENCH_r0{i}.json")
-             for i in range(1, 6)]
+    result = {"metric": "bert_base_pretrain_tokens_per_sec_per_chip",
+              "value": 35440.8, "unit": "tokens/s", "vs_baseline": 0.2543}
+    wrappers = [
+        {"rc": 1, "parsed": None},
+        {"rc": 0, "parsed": result},
+        {"rc": 124, "parsed": None},
+        {"rc": 0, "parsed": dict(result, value=0.0,
+                                 error="backend unavailable")},
+        {"rc": 124, "parsed": None},
+    ]
+    paths = []
+    for i, w in enumerate(wrappers):
+        path = tmp_path / f"wrapper{i}.json"
+        path.write_text(json.dumps(dict(w, cmd="python bench.py")))
+        paths.append(str(path))
     n, skipped = perf_ledger.ingest(
         paths, str(ledger), perf_ledger.provenance("seed", "tpu", ""))
     assert n == 1 and skipped == 4

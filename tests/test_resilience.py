@@ -151,7 +151,7 @@ def test_fault_decisions_deterministic_per_seed():
 def test_is_transient_taxonomy():
     assert is_transient(TransientFault("x"))
     assert is_transient(RetryExhausted("x"))
-    assert is_transient(OSError("tunnel reset"))
+    assert is_transient(OSError("connection reset"))
     assert is_transient(TimeoutError("stuck"))
     for poison in (ValueError("bad shape"), TypeError("bad type"),
                    KeyError("missing feed"), AssertionError("no"),

@@ -1,9 +1,9 @@
 """Flash (Pallas) vs composed-XLA attention A/B at bench shapes.
 
-Sweeps seq 512/1024/2048 (fwd and fwd+bwd, amortized-RTT timing) and,
-at each seq, the flash block-tile grid — the measurement VERDICT r04
-next-step #4 needs to settle `models/transformer.py`'s `use_flash`
-default with a number. Run on a healthy chip:
+Sweeps seq 512/1024/2048 (fwd and fwd+bwd) and, at each seq, the flash
+block-tile grid — the kernel-level half of what settles
+`models/transformer.py`'s `use_flash` default with a number (the
+end-to-end half is ROADMAP queue 1 item 7). Run on the chip:
 
     python tools/attn_micro.py [--seqs 512,1024,2048] [--bh 384]
 
@@ -18,7 +18,6 @@ import time
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(
     __file__))))
 
-import numpy as np  # noqa: E402
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
@@ -26,24 +25,14 @@ from paddle_tpu.ops.pallas.flash_attention import (  # noqa: E402
     flash_attention, reference_attention)
 
 
-def sync(x):
-    return np.asarray(jax.device_get(jnp.sum(x)))
-
-
 def timed(f, *args, n=20):
     g = jax.jit(f)
-    o = g(*args)
-    sync(o)
-    z = jnp.zeros(())
-    np.asarray(z + 1)
-    t0 = time.perf_counter()
-    np.asarray(z + 2)
-    rtt = time.perf_counter() - t0
+    jax.block_until_ready(g(*args))  # compile outside the window
     t0 = time.perf_counter()
     for _ in range(n):
         o = g(*args)
-    sync(o)
-    return max(time.perf_counter() - t0 - rtt, 1e-9) / n
+    jax.block_until_ready(o)
+    return (time.perf_counter() - t0) / n
 
 
 def main():

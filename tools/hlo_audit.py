@@ -4,8 +4,7 @@ Lowers the EXACT benched step (tiny shapes — dtypes are shape-
 independent) on CPU and reports every dot_general / convolution with
 its operand dtypes. An f32 dot on the MXU runs at 1/4-1/8 the bf16
 rate, so "ALL dots bf16" is the strongest off-chip evidence the AMP
-rewrite holds end-to-end (fwd + vjp + optimizer). PERF.md records the
-per-model results.
+rewrite holds end-to-end (fwd + vjp + optimizer).
 
     python tools/hlo_audit.py [bert|resnet50|gpt|transformer|deeplab|all]
 

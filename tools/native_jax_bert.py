@@ -18,7 +18,7 @@ paddle/fluid/operators/benchmark/op_tester.cc (it benches ops outside
 the full executor for the same reason).
 
 Usage: python tools/native_jax_bert.py   (env: BENCH_BATCH, BENCH_SEQ,
-BENCH_STEPS, BENCH_WAIT_TPU_S as in bench.py)
+BENCH_STEPS as in bench.py). One process; fails without an accelerator.
 """
 from __future__ import annotations
 
@@ -33,7 +33,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(
 
 import numpy as np  # noqa: E402
 
-import bench  # noqa: E402 — probe/flops/peak helpers
+import bench  # noqa: E402 — flops/peak helpers
 
 
 class _Cfg:
@@ -180,16 +180,11 @@ def main():
     batch = int(os.environ.get("BENCH_BATCH", "32"))
     seq_len = int(os.environ.get("BENCH_SEQ", "512"))
     steps = int(os.environ.get("BENCH_STEPS", "30"))
-    ok, detail = bench._probe_backend()
-    if not ok:
-        print(json.dumps({
-            "metric": "bert_base_native_jax_tokens_per_sec_per_chip",
-            "value": 0.0, "unit": "tokens/s", "vs_baseline": 0.0,
-            "error": detail}), flush=True)
-        return
     import jax
     import jax.numpy as jnp
     import jax.tree_util as jtu
+    # an unknown device (the CPU included) raises here, before any work
+    peak = bench.peak_flops_per_chip()
     cfg = _Cfg()
     p = jtu.tree_map(jnp.asarray, init_params(0, cfg))
     zeros = jtu.tree_map(jnp.zeros_like, p)
@@ -200,29 +195,19 @@ def main():
     toks = jnp.asarray(r.randint(0, cfg.vocab_size, (batch, seq_len)),
                        jnp.int32)
     state, lv = step(state, toks, toks)  # compile + warm
-    np.asarray(lv)
+    jax.block_until_ready(lv)
 
-    # identical timing discipline to bench.py _timed_steps: median-of-5
-    # RTT probe, async windows synced once, 5%-of-elapsed floor on the
-    # RTT subtraction — the bench-vs-twin comparison is only meaningful
-    # if both sides measure the same way
-    np.asarray(jnp.zeros(()) + 1)  # compile the probe expression
-    rtts = []
-    for _ in range(5):
-        t0 = time.perf_counter()
-        np.asarray(jnp.zeros(()) + 1)
-        rtts.append(time.perf_counter() - t0)
-    rtt = float(np.median(rtts))
-
+    # identical timing discipline to bench.py _timed_steps: async
+    # windows ended by block_until_ready — the bench-vs-twin comparison
+    # is only meaningful if both sides measure the same way
     def window(n):
         nonlocal state
         t0 = time.perf_counter()
         lv = None
         for _ in range(n):
             state, lv = step(state, toks, toks)
-        lv = float(np.asarray(lv))
-        elapsed = time.perf_counter() - t0
-        return max(elapsed - rtt, 0.05 * elapsed) / n, lv
+        jax.block_until_ready(lv)
+        return (time.perf_counter() - t0) / n, float(np.asarray(lv))
 
     n1 = max(1, steps // 2)
     n2 = max(1, steps - n1)
@@ -230,14 +215,14 @@ def main():
     dt2, lv = window(n2)
     dt = (dt1 * n1 + dt2 * n2) / (n1 + n2)
     flops = bench.model_flops_per_token(cfg, seq_len) * batch * seq_len
-    mfu = flops / dt / bench.peak_flops_per_chip()
+    mfu = flops / dt / peak
     print(json.dumps({
         "metric": "bert_base_native_jax_tokens_per_sec_per_chip",
         "value": round(batch * seq_len / dt, 1), "unit": "tokens/s",
         "vs_baseline": round(mfu / 0.50, 4),
         "extra": {"step_ms": round(dt * 1000, 2), "mfu": round(mfu, 4),
                   "batch": batch, "seq_len": seq_len, "loss": lv,
-                  "rtt_ms": round(rtt * 1000, 1),
+                  **bench.device_stamp(),
                   "windows_ms": [round(dt1 * 1000, 2),
                                  round(dt2 * 1000, 2)],
                   "window_spread": round(abs(dt1 - dt2) / dt, 4)}}),

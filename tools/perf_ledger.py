@@ -34,7 +34,7 @@ is ignored. Rows are append-only and fsynced — the same crash-safety
 contract as the monitor's JSONL exporter. Importable API:
 `rows_from_record`, `rows_from_file`, `ingest`, `load_rows`, plus the
 provenance helpers `detect_git_rev` / `detect_platform` /
-`detect_mesh` that bench.py and tools/sweep_driver.py stamp rows with.
+`detect_mesh` that bench.py stamps rows with.
 """
 from __future__ import annotations
 
@@ -136,7 +136,7 @@ def _row(record_kind, config, metric, value, unit, ts=None, extra=None):
 
 def _bench_result_rows(rec) -> List[dict]:
     # an errored config (backend unavailable, crash, budget skip) must
-    # never be averaged into a baseline — BENCH_r04's 0.0 tok/s would
+    # never be averaged into a baseline — a 0.0 tok/s error line would
     # poison the median
     if rec.get("error"):
         return []

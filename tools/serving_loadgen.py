@@ -1378,6 +1378,22 @@ def run_disagg(args):
         print("--disagg races local subprocess replicas; --url and "
               "--chaos are not supported", file=sys.stderr)
         return 2
+    # This parent builds an engine and the serial reference in-process,
+    # so it holds whatever device jax gave it. The workers run on the
+    # parent's platform, stated in their environment and in the record.
+    # On a TPU that cannot work: a chip belongs to one process at a
+    # time, and the children would fail, hang, or quietly come up on
+    # the CPU beside a parent on the chip.
+    import jax
+    worker_platform = jax.default_backend()
+    if worker_platform != "cpu":
+        print(f"--disagg spawns subprocess replicas, and this parent "
+              f"holds the {worker_platform} backend: its children "
+              f"cannot claim the same chip. Run the fleet on the CPU "
+              f"(JAX_PLATFORMS=cpu); replicas on chips need one chip a "
+              f"process and a parent that stays off jax.",
+              file=sys.stderr)
+        return 2
     n_rep = args.router
     n_p = max(1, args.disagg_prefill)
     n_d = n_rep - n_p
@@ -1437,7 +1453,7 @@ def run_disagg(args):
         os.path.dirname(os.path.abspath(__file__)),
         "serving_replica.py")
     worker_env = dict(os.environ)
-    worker_env.setdefault("JAX_PLATFORMS", "cpu")
+    worker_env["JAX_PLATFORMS"] = worker_platform
     if args.service_ms > 0:
         # deterministic per-prefill-chunk service time in EVERY worker
         # of BOTH fleets: prefill cost dominates and is identical
@@ -1689,6 +1705,7 @@ def run_disagg(args):
         "mode": "closed",
         "replicas": {"prefill": n_p, "decode": n_d,
                      "baseline_unified": n_rep},
+        "worker_platform": worker_platform,
         "requests": dis["requests"],
         "errors": err_a + err_b,
         "wrong_answers": wrong_a + wrong_b,

@@ -102,8 +102,10 @@ def main(argv=None):
         print("need --model-dir and/or --weights", file=sys.stderr)
         return 2
 
+    from paddle_tpu.core.compile_cache import configure_compile_cache
     from paddle_tpu.serving import serve
 
+    configure_compile_cache()
     engine = None
     gen = None
     if args.model_dir:
@@ -134,7 +136,9 @@ def main(argv=None):
         with open(tmp, "w") as f:
             f.write(str(port))
         os.replace(tmp, args.port_file)  # atomic: readers never see ""
+    import jax
     print(json.dumps({"kind": "replica_ready", "pid": os.getpid(),
+                      "platform": jax.default_backend(),
                       "port": port, "url": f"http://{args.host}:{port}",
                       "predict": engine is not None,
                       "generate": gen is not None}), flush=True)
