@@ -131,8 +131,10 @@ class Executor:
         t_run0 = time.perf_counter()
         self._last_feed_s = 0.0
         self._last_build_s = 0.0
-        step_fn, state, feed_arrays = self._resolve_step(
-            program, feed, fetch_list, scope, compiled, use_program_cache)
+        with _trace.region("executor.resolve"):
+            step_fn, state, feed_arrays = self._resolve_step(
+                program, feed, fetch_list, scope, compiled,
+                use_program_cache)
 
         fp = program.fingerprint()
         step = self._step_counters.get(fp, 0)
@@ -150,46 +152,49 @@ class Executor:
                 if _gled is not None else 0.0)
 
         t_disp0 = time.perf_counter()
-
-        # Fault injection (FLAGS_fault_spec; paddle_tpu/resilience).
-        # Empty spec = one cached None-check. An injected TransientFault
-        # fires BEFORE device dispatch, so retrying here is donation-safe
-        # (the scope still holds valid pre-step buffers); real dispatch
-        # errors are NOT retried at this level — a failed dispatch may
-        # have invalidated donated state.
-        from .resilience.faults import injector as _fault_injector
-        inj = _fault_injector()
-        if inj is None:
-            with jax.default_device(self.place.jax_device()):
-                fetches, new_state = step_fn.fn(state, feed_arrays,
-                                                jnp.uint32(step))
-        else:
-            from .resilience.faults import TransientFault
-            from .resilience.retry import RetryPolicy
-
-            def _dispatch():
-                inj.pre_step("executor", step=step)
+        with _trace.region("executor.dispatch"):
+            # Fault injection (FLAGS_fault_spec; paddle_tpu/resilience).
+            # Empty spec = one cached None-check. An injected
+            # TransientFault fires BEFORE device dispatch, so retrying
+            # here is donation-safe (the scope still holds valid
+            # pre-step buffers); real dispatch errors are NOT retried at
+            # this level — a failed dispatch may have invalidated
+            # donated state.
+            from .resilience.faults import injector as _fault_injector
+            inj = _fault_injector()
+            if inj is None:
                 with jax.default_device(self.place.jax_device()):
-                    return step_fn.fn(state, feed_arrays,
-                                      jnp.uint32(step))
+                    fetches, new_state = step_fn.fn(state, feed_arrays,
+                                                    jnp.uint32(step))
+            else:
+                from .resilience.faults import TransientFault
+                from .resilience.retry import RetryPolicy
 
-            policy = RetryPolicy(is_retryable=lambda e: isinstance(
-                e, TransientFault))
-            fetches, new_state = policy.call(_dispatch)
+                def _dispatch():
+                    inj.pre_step("executor", step=step)
+                    with jax.default_device(self.place.jax_device()):
+                        return step_fn.fn(state, feed_arrays,
+                                          jnp.uint32(step))
 
-        for n, val in new_state.items():
-            scope.set(n, val)
+                policy = RetryPolicy(is_retryable=lambda e: isinstance(
+                    e, TransientFault))
+                fetches, new_state = policy.call(_dispatch)
+
+            for n, val in new_state.items():
+                scope.set(n, val)
 
         t_fetch0 = time.perf_counter()
-        if return_numpy:
-            out = [np.asarray(f) for f in fetches]
-            if inj is not None:
-                # step_nan corrupts only these host-side copies — the
-                # device state written back above stays clean, so a
-                # caller-level re-run of the same step is a valid cure
-                inj.corrupt_fetches("executor", out)
-        else:
-            out = list(fetches)
+        with _trace.region("executor.fetch"):
+            if return_numpy:
+                out = [np.asarray(f) for f in fetches]
+                if inj is not None:
+                    # step_nan corrupts only these host-side copies —
+                    # the device state written back above stays clean,
+                    # so a caller-level re-run of the same step is a
+                    # valid cure
+                    inj.corrupt_fetches("executor", out)
+            else:
+                out = list(fetches)
         now = time.perf_counter()
         self.last_step_timings = {
             "feed_s": self._last_feed_s,
@@ -222,22 +227,6 @@ class Executor:
                              now - t_run0, exemplar=tid)
             from .core.memory import record_device_memory
             record_device_memory(self.place.jax_device())
-        cur = _trace.current_span()
-        if cur is not None:
-            # Retroactive per-step sub-spans (feed staging / dispatch /
-            # fetch-block) under whatever span is current — the batch
-            # span in the serving worker, a step span in tests. Wall-
-            # clock endpoints are reconstructed from the perf deltas.
-            wall_end = time.time()
-            w_fetch0 = wall_end - (now - t_fetch0)
-            w_disp0 = wall_end - (now - t_disp0)
-            w_run0 = wall_end - (now - t_run0)
-            if self._last_feed_s > 0:
-                _trace.record_span("executor.feed", w_run0,
-                                   w_run0 + self._last_feed_s, cur)
-            _trace.record_span("executor.dispatch", w_disp0, w_fetch0,
-                               cur, attrs={"first_run": first_run})
-            _trace.record_span("executor.fetch", w_fetch0, wall_end, cur)
         # flight recorder (FLAGS_flight_recorder): one bounded-ring
         # record per completed step — the post-mortem trail dumped on
         # crash/SIGTERM (monitor.dump_flight_recorder)
@@ -285,7 +274,8 @@ class Executor:
             if compiled._state_spec_fn is not None:
                 STAT_ADD("parallel.sharded_steps")
 
-        feed_arrays = self._prepare_feed(block, feed, compiled)
+        with _trace.region("executor.feed"):
+            feed_arrays = self._prepare_feed(block, feed, compiled)
 
         # Surface fetch targets hidden inside recompute sub-blocks BEFORE
         # keying the cache: the rewrite mutates the program fingerprint
@@ -353,8 +343,11 @@ class Executor:
             self._cache_misses += 1
             STAT_ADD("executor.compile_cache_miss")
             t0 = time.perf_counter()
-            step_fn = self._compile(program, block, feed_arrays,
-                                    fetch_names, scope, compiled)
+            # on a miss only: the region that says WHICH step rebuilt
+            # its executable
+            with _trace.region("executor.compile"):
+                step_fn = self._compile(program, block, feed_arrays,
+                                        fetch_names, scope, compiled)
             # host-side lowering/closure build only — XLA compile itself
             # is lazy (first call; see executor.compile_first_step_seconds)
             self._last_build_s = time.perf_counter() - t0

@@ -57,17 +57,19 @@ def profiler(state="All", sorted_key=None, profile_path=None,
 
 @contextlib.contextmanager
 def record_event(name):
-    """RecordEvent RAII (profiler.h:81) -> XPlane trace annotation + native
-    host-phase event (native/src/profiler.cc) + monitor phase aggregate
-    (monitor.phase: nested scopes accumulate EXCLUSIVE time per phase),
-    so the chrome trace merges framework phases with the device timeline
-    like the reference's host+CUPTI merge (device_tracer.cc:58) and
-    host_phase_stats() answers "where does host step time go" without a
-    trace viewer."""
+    """RecordEvent RAII (profiler.h:81) for user code -> `trace.region`
+    (the XPlane trace annotation the framework's own regions use, so a
+    user's phases sit beside `gen.*` / `executor.*` on the profiler's
+    host plane) + native host-phase event (native/src/profiler.cc) +
+    monitor phase aggregate (monitor.phase: nested scopes accumulate
+    EXCLUSIVE time per phase), so the chrome trace merges framework
+    phases with the device timeline like the reference's host+CUPTI
+    merge (device_tracer.cc:58) and host_phase_stats() answers "where
+    does host step time go" without a trace viewer."""
     from .monitor import phase as _monitor_phase
     from .native import profiler_scope
-    with jax.profiler.TraceAnnotation(name), profiler_scope(name), \
-            _monitor_phase(name):
+    from .trace import region
+    with region(name), profiler_scope(name), _monitor_phase(name):
         yield
 
 

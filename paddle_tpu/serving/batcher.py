@@ -139,12 +139,17 @@ class BucketLadder:
 class _Response:
     """Future-ish handle returned by DynamicBatcher.submit."""
 
-    __slots__ = ("_event", "_value", "_error", "span")
+    __slots__ = ("_event", "_value", "_error", "span", "timings")
 
     def __init__(self):
         self._event = threading.Event()
         self._value = None
         self._error = None
+        # What the engine knows of the request so far, filled as it
+        # passes each boundary and readable before it finishes (the
+        # generation engine: queue_ms, cached_tokens, prefill_steps,
+        # ttft_ms).
+        self.timings = {}
         # Request span, completed in _complete — the one funnel every
         # success and failure path flows through, so the trace is
         # finished exactly once no matter which path filled us in.

@@ -100,6 +100,10 @@ class GenerationRequest:
     decode; None falls back to the engine default. `stream_cb(token_id)`
     fires from the engine thread after every generated token — the
     streaming hook (and the loadgen's TTFT/inter-token probe).
+    `logits_cb(row)` fires from the engine thread too, once for every
+    generated token and just before its `stream_cb`, with the logits row
+    that the token was sampled from: a view into the step's fetch, to be
+    copied by a callee that keeps it. None costs one attribute check.
     `spec_decode` opts this request in/out of speculative decoding
     (serving/spec_decode.py): None defers to the engine default
     (FLAGS_gen_spec_decode), False forces plain one-token decode, True
@@ -110,14 +114,15 @@ class GenerationRequest:
 
     __slots__ = ("prompt", "max_new_tokens", "temperature", "top_k",
                  "eos_id", "timeout_ms", "seed", "stream_cb",
-                 "spec_decode")
+                 "spec_decode", "logits_cb")
 
     def __init__(self, prompt: Sequence[int], max_new_tokens: int,
                  temperature: float = 0.0, top_k: int = 0,
                  eos_id: Optional[int] = None,
                  timeout_ms: Optional[float] = None, seed: int = 0,
                  stream_cb: Optional[Callable[[int], None]] = None,
-                 spec_decode: Optional[bool] = None):
+                 spec_decode: Optional[bool] = None,
+                 logits_cb: Optional[Callable[[np.ndarray], None]] = None):
         self.prompt = [int(t) for t in prompt]
         if not self.prompt:
             raise ValueError("GenerationRequest: prompt must be "
@@ -134,6 +139,7 @@ class GenerationRequest:
         self.stream_cb = stream_cb
         self.spec_decode = None if spec_decode is None \
             else bool(spec_decode)
+        self.logits_cb = logits_cb
 
 
 class SlotManager:
@@ -198,7 +204,8 @@ class _SlotState:
         # Tracing: the request span (carried over from _Queued — spans
         # cross the submit -> worker thread hand-off ON these objects),
         # the current lifecycle phase span (prefill, then decode), and
-        # accumulated fetch-block seconds from the steps this slot rode.
+        # accumulated fetch-block seconds from the steps this slot rode
+        # during that phase.
         self.span = None
         self.phase_span = None
         self.fetch_s = 0.0
@@ -542,7 +549,10 @@ class GenerationEngine:
     # -- request path ----------------------------------------------------
     def submit(self, req: GenerationRequest) -> _Response:
         """Enqueue; returns a future handle whose `.result()` blocks for
-        ``{"tokens", "finish_reason", "ttft_ms", "e2e_ms"}``."""
+        ``{"tokens", "finish_reason", "ttft_ms", "e2e_ms", "queue_ms",
+        "cached_tokens"}``; its `.timings` holds what is known of the
+        request before it finishes (`queue_ms`, `cached_tokens`,
+        `prefill_steps`, `ttft_ms`)."""
         need = len(req.prompt) + req.max_new_tokens - 1
         if self.paged:
             # block-aware admission: a request that can never fit is
@@ -579,12 +589,15 @@ class GenerationEngine:
         if trace.enabled():
             # Child of the caller's span (http.request, loadgen's
             # per-request root) when one is current, else a new root.
+            # Both begin at t_submit, where e2e, TTFT and queue_ms
+            # begin: queue + prefill + decode then tile the request.
             q.span = trace.start_span(
-                "gen.request",
+                "gen.request", perf0=now,
                 attrs={"prompt_tokens": len(req.prompt),
                        "max_new_tokens": req.max_new_tokens})
             resp.span = q.span
-            q.qspan = trace.start_span("queue", parent=q.span)
+            q.qspan = trace.start_span("queue", parent=q.span,
+                                       perf0=now)
         try:
             with self._cond:
                 if self._closed:
@@ -649,6 +662,8 @@ class GenerationEngine:
         return bid
 
     def _set_block_gauges(self):
+        """Once an iteration from `_publish_iteration`, and after an
+        export or adoption between iterations (serving/disagg.py)."""
         STAT_SET("serving.gen_kv_blocks_free", self._pool.free_count())
 
     def _adapt_spec_k(self, st: _SlotState, rate: float):
@@ -667,10 +682,13 @@ class GenerationEngine:
             STAT_ADD("serving.gen_spec_k_grows")
         STAT_SET("serving.gen_spec_k_effective", st.spec_k_cur)
 
-    def _admit_trace(self, st: _SlotState, q: "_Queued"):
-        """Queue -> prefill phase transition on the request's span tree
-        (admission happens on the worker thread — the span rode the
-        _Queued object across)."""
+    def _admitted(self, st: _SlotState, q: "_Queued"):
+        """The request has a slot: its `timings` get the queue wait, and
+        its span tree moves from queue to prefill (admission happens on
+        the worker thread — the span rode the _Queued object across)."""
+        st.response.timings.update(
+            queue_ms=(time.perf_counter() - q.t_submit) * 1e3,
+            cached_tokens=st.n_cached, prefill_steps=0)
         st.span = q.span
         trace.end_span(q.qspan)
         st.phase_span = trace.start_span("prefill", parent=st.span)
@@ -711,8 +729,7 @@ class GenerationEngine:
                 st.cur = prompt[n_cached]
                 STAT_ADD("serving.gen_prefix_hits" if n_cached
                          else "serving.gen_prefix_misses")
-                self._set_block_gauges()
-                self._admit_trace(st, q)
+                self._admitted(st, q)
                 if st.phase_span is not None and n_cached:
                     st.phase_span.set_attr("cached_tokens", n_cached)
                 self._state[slot] = st
@@ -722,9 +739,8 @@ class GenerationEngine:
             for bid in owned + shared:
                 self._pool.decref(bid)
             self._slots.release(slot)
-            self._set_block_gauges()
             return False
-        self._admit_trace(st, q)
+        self._admitted(st, q)
         self._state[slot] = st
         self._queue.pop(0)
         return True
@@ -738,7 +754,6 @@ class GenerationEngine:
             for bid in st.blocks:
                 self._pool.decref(bid)
             st.blocks = []
-            self._set_block_gauges()
         self._state[i] = None
         self._slots.release(i)
 
@@ -755,7 +770,6 @@ class GenerationEngine:
                                            bs)
         for j, h in enumerate(hashes):
             self._prefix.insert(h, st.blocks[j])
-        self._set_block_gauges()
 
     # -- worker ----------------------------------------------------------
     def _expire_queued_locked(self, now) -> List[_Queued]:
@@ -765,21 +779,27 @@ class GenerationEngine:
             self._queue = [q for q in self._queue if q not in dead]
         return dead
 
+    def _close_phase(self, st: _SlotState):
+        """End the slot's lifecycle phase span (prefill, then decode).
+        Aggregated device-sync attribution: one synthetic "fetch" child
+        of the phase carrying the summed fetch-block time of every step
+        the slot rode DURING it (NESTED, so the queue+prefill+decode
+        critical path doesn't double-count; by phase, so the child
+        always fits inside its parent)."""
+        if st.phase_span is not None and st.fetch_s > 0:
+            trace.record_span(
+                "fetch", st.phase_span.t_start,
+                st.phase_span.t_start + st.fetch_s, st.phase_span,
+                attrs={"aggregated": True,
+                       "fetch_ms": round(st.fetch_s * 1e3, 3)})
+        st.fetch_s = 0.0
+        trace.end_span(st.phase_span)
+
     def _finish(self, st: _SlotState, reason: str):
         now = time.perf_counter()
         e2e_ms = (now - st.t_submit) * 1e3
         if st.span is not None:
-            # Aggregated device-sync attribution: one synthetic "fetch"
-            # child of the decode phase carrying the summed fetch-block
-            # time of every step this slot rode (NESTED, so the
-            # queue+prefill+decode critical path doesn't double-count).
-            if st.phase_span is not None and st.fetch_s > 0:
-                trace.record_span(
-                    "fetch", st.phase_span.t_start,
-                    st.phase_span.t_start + st.fetch_s, st.phase_span,
-                    attrs={"aggregated": True,
-                           "fetch_ms": round(st.fetch_s * 1e3, 3)})
-            trace.end_span(st.phase_span)
+            self._close_phase(st)
             st.span.attrs.update({
                 "e2e_ms": round(e2e_ms, 3),
                 "ttft_ms": None if st.ttft_ms is None
@@ -792,6 +812,7 @@ class GenerationEngine:
             "finish_reason": reason,
             "ttft_ms": st.ttft_ms,
             "e2e_ms": e2e_ms,
+            "queue_ms": st.response.timings.get("queue_ms"),
             "cached_tokens": st.n_cached,
         })
         if _monitor_on():
@@ -800,15 +821,50 @@ class GenerationEngine:
                          exemplar=st.span.trace_id if st.span else None)
 
     def _worker_loop(self):
-        # deferred: paddle_tpu/__init__ imports serving before the
-        # models package exists, so this cannot be a module-level import
-        from ..models import sampling
+        total = self._pool.capacity() if self.paged else 0
+        alive = True
+        while alive:
+            rec = trace.begin_iteration(self.max_slots, self.block_size,
+                                        total)
+            with trace.region("gen.iteration"):
+                alive = self._turn(rec)
+                ran = self._publish_iteration(rec)
+            trace.end_iteration(rec, keep=ran)
+            if ran:
+                _goodput.gen_busy(rec.t_end - rec.t_start)
+            # no active slot = idle wait
+            _goodput.gen_idle(rec.host_s.get("gen.idle_wait", 0.0))
+
+    def _publish_iteration(self, rec) -> bool:
+        """The loop's one bookkeeping site: what the live slots hold at
+        the turn's end goes into its record, and the monitor's gauges
+        that are fields of the record are published from it. Still
+        under `gen.iteration`, so that on a profiler's trace no host
+        time of the loop lies between two turns. Returns whether the
+        turn ran a step (trace's ring keeps the record then)."""
+        live = [st for st in self._state if st is not None]
+        rec.kv_blocks_held = sum(len(st.blocks) for st in live)
+        rec.kv_tokens_resident = sum(st.fed for st in live)
+        STAT_SET("serving.gen_queue_depth", rec.queue_depth)
+        STAT_SET("serving.gen_active_slots", rec.active_slots)
+        if self.paged:
+            self._set_block_gauges()
+        if rec.decode_rows and _monitor_on():
+            STAT_OBSERVE("serving.gen_slot_occupancy",
+                         rec.decode_rows / float(rec.slots),
+                         buckets=FRACTION_BUCKETS)
+        return rec.prefill_rows + rec.decode_rows > 0
+
+    def _turn(self, rec) -> bool:
+        """One turn of the worker loop: admission, then one iteration
+        over the slots, or a wait where none is active. False ends the
+        loop."""
         B = self.max_slots
-        while True:
-            expired: List[_Queued] = []
-            failed: List[_Queued] = []
-            exit_loop = False
-            with self._cond:
+        expired: List[_Queued] = []
+        failed: List[_Queued] = []
+        exit_loop = False
+        with self._cond:
+            with trace.region("gen.admit"):
                 now = time.perf_counter()
                 expired = self._expire_queued_locked(now)
                 if self._closed and not self._draining:
@@ -823,100 +879,108 @@ class GenerationEngine:
                     pass
                 active_idx = [i for i in range(B)
                               if self._state[i] is not None]
-                STAT_SET("serving.gen_queue_depth", len(self._queue))
-                STAT_SET("serving.gen_active_slots", len(active_idx))
-                if not active_idx:
-                    if self._closed and not self._queue:
-                        exit_loop = True
-                    elif not (self._closed and not self._draining):
-                        # generation goodput: no active slot = idle wait
-                        t_idle0 = time.perf_counter()
-                        self._cond.wait(0.05)
-                        _goodput.gen_idle(time.perf_counter() - t_idle0)
-            for q in expired:
-                STAT_ADD("serving.gen_timeouts")
-                trace.end_span(q.qspan, error="DeadlineExceededError")
-                q.response._complete(error=DeadlineExceededError(
-                    "generation request waited past its deadline"))
-            for q in failed:
-                trace.end_span(q.qspan, error="EngineClosedError")
-                q.response._complete(error=EngineClosedError(
-                    "generation engine shut down before the request "
-                    "ran"))
-            if self._closed and not self._draining:
-                # fail whatever is mid-decode and exit
-                for i in range(B):
-                    st = self._state[i]
-                    if st is not None:
-                        st.response._complete(error=EngineClosedError(
-                            "generation engine shut down mid-decode"))
-                        self._release_slot(i)
-                break
-            if exit_loop:
-                break
+                rec.queue_depth = len(self._queue)
+                rec.active_slots = len(active_idx)
             if not active_idx:
-                continue
-            if self.paged:
-                t_busy0 = time.perf_counter()
-                # _kv_mutex: disagg export/adopt (serving/disagg.py)
-                # mutates the same pools/PrefixCache between iterations
-                with self._kv_mutex:
-                    self._paged_iteration()
-                _goodput.gen_busy(time.perf_counter() - t_busy0)
-                continue
-
-            # ---- one decode step over the full fixed-shape batch ----
-            now = time.perf_counter()
-            t_busy0 = now
-            tokens = np.zeros((B, 1), np.int64)
-            reset = np.zeros(B, np.float32)
-            active = np.zeros(B, np.float32)
-            stepped: List[int] = []
-            for i in active_idx:
+                if self._closed and not self._queue:
+                    exit_loop = True
+                elif not (self._closed and not self._draining):
+                    with trace.region("gen.idle_wait"):
+                        self._cond.wait(0.05)
+        for q in expired:
+            STAT_ADD("serving.gen_timeouts")
+            trace.end_span(q.qspan, error="DeadlineExceededError")
+            q.response._complete(error=DeadlineExceededError(
+                "generation request waited past its deadline"))
+        for q in failed:
+            trace.end_span(q.qspan, error="EngineClosedError")
+            q.response._complete(error=EngineClosedError(
+                "generation engine shut down before the request "
+                "ran"))
+        if self._closed and not self._draining:
+            # fail whatever is mid-decode and exit
+            for i in range(B):
                 st = self._state[i]
-                if st.deadline is not None and now >= st.deadline:
-                    STAT_ADD("serving.gen_timeouts")
-                    st.response._complete(
-                        error=DeadlineExceededError(
-                            "generation deadline passed mid-decode"))
-                    self._state[i] = None
-                    self._slots.release(i)
-                    continue
-                tokens[i, 0] = st.cur
-                reset[i] = 1.0 if st.needs_reset else 0.0
-                active[i] = 1.0
-                stepped.append(i)
-            if not stepped:
+                if st is not None:
+                    st.response._complete(error=EngineClosedError(
+                        "generation engine shut down mid-decode"))
+                    self._release_slot(i)
+            return False
+        if exit_loop:
+            return False
+        if not active_idx:
+            return True
+        if self.paged:
+            # _kv_mutex: disagg export/adopt (serving/disagg.py)
+            # mutates the same pools/PrefixCache between iterations
+            with self._kv_mutex:
+                self._paged_iteration(rec)
+        else:
+            self._slab_iteration(rec, active_idx)
+        return True
+
+    # -- slab iteration --------------------------------------------------
+    def _slab_iteration(self, rec, active_idx):
+        """One decode step over the full fixed-shape batch of the slab
+        (non-paged) engine; a prompt is stepped through the same graph
+        one token at a time."""
+        # deferred: paddle_tpu/__init__ imports serving before the
+        # models package exists, so this cannot be a module-level import
+        from ..models import sampling
+        B = self.max_slots
+        now = time.perf_counter()
+        tokens = np.zeros((B, 1), np.int64)
+        reset = np.zeros(B, np.float32)
+        active = np.zeros(B, np.float32)
+        stepped: List[int] = []
+        for i in active_idx:
+            st = self._state[i]
+            if st.deadline is not None and now >= st.deadline:
+                STAT_ADD("serving.gen_timeouts")
+                st.response._complete(
+                    error=DeadlineExceededError(
+                        "generation deadline passed mid-decode"))
+                self._state[i] = None
+                self._slots.release(i)
                 continue
+            tokens[i, 0] = st.cur
+            reset[i] = 1.0 if st.needs_reset else 0.0
+            active[i] = 1.0
+            stepped.append(i)
+        if not stepped:
+            return
+        rec.decode_rows = len(stepped)
 
-            def _attempt():
-                inj = _fault_injector()
-                if inj is not None:
-                    inj.pre_step("generation")
-                return self._run_step(tokens, reset, active)
+        def _attempt():
+            inj = _fault_injector()
+            if inj is not None:
+                inj.pre_step("generation")
+            return self._run_step(tokens, reset, active)
 
-            try:
-                # only the injector's pre-dispatch TransientFault is
-                # retryable: once the real step ran, the KV cache
-                # advanced and a replay would double-step the slots
+        try:
+            # only the injector's pre-dispatch TransientFault is
+            # retryable: once the real step ran, the KV cache
+            # advanced and a replay would double-step the slots
+            with trace.region("gen.decode.step"):
                 logits = self._step_retry.call(_attempt)
-            except Exception as e:  # noqa: BLE001 — worker must survive
-                if is_transient(e):
-                    self._breaker.record_failure()
-                STAT_ADD("resilience.gen_step_failures")
+        except Exception as e:  # noqa: BLE001 — worker must survive
+            if is_transient(e):
+                self._breaker.record_failure()
+            STAT_ADD("resilience.gen_step_failures")
+            for i in stepped:
+                st = self._state[i]
+                st.response._complete(error=RuntimeError(
+                    f"decode step failed: {e!r}"))
+                self._state[i] = None
+                self._slots.release(i)
+            return
+        self._breaker.record_success()
+        if trace.enabled():
+            lt = self.exe.last_step_timings
+            if lt is not None:
                 for i in stepped:
-                    st = self._state[i]
-                    st.response._complete(error=RuntimeError(
-                        f"decode step failed: {e!r}"))
-                    self._state[i] = None
-                    self._slots.release(i)
-                continue
-            self._breaker.record_success()
-            if trace.enabled():
-                lt = self.exe.last_step_timings
-                if lt is not None:
-                    for i in stepped:
-                        self._state[i].fetch_s += lt["fetch_s"]
+                    self._state[i].fetch_s += lt["fetch_s"]
+        with trace.region("gen.sample"):
             inj = _fault_injector()
             if inj is not None:
                 # step_nan at site=generation corrupts only the host
@@ -939,13 +1003,10 @@ class GenerationEngine:
                         self._state[i] = None
                         self._slots.release(i)
                     stepped = [i for i in stepped if i not in bad]
+                    rec.decode_rows = len(stepped)
                     if not stepped:
-                        continue
+                        return
             STAT_ADD("serving.gen_steps")
-            if _monitor_on():
-                STAT_OBSERVE("serving.gen_slot_occupancy",
-                             len(stepped) / float(B),
-                             buckets=FRACTION_BUCKETS)
 
             # ---- per-slot bookkeeping (sampling, streaming, finish) --
             t_step = time.perf_counter()
@@ -956,32 +1017,12 @@ class GenerationEngine:
                 prompt = st.req.prompt
                 if st.fed < len(prompt):
                     st.cur = prompt[st.fed]     # still prefilling
+                    st.response.timings["prefill_steps"] += 1
                     continue
                 tok = sampling.sample_token(
                     logits[i, 0], temperature=st.req.temperature,
                     top_k=st.req.top_k, rng=st.rng)
-                st.generated.append(tok)
-                STAT_ADD("serving.gen_tokens")
-                if len(st.generated) == 1:
-                    st.ttft_ms = (t_step - st.t_submit) * 1e3
-                    if _monitor_on():
-                        STAT_OBSERVE("serving.gen_ttft_ms", st.ttft_ms,
-                                     buckets=MS_BUCKETS)
-                    if st.span is not None:
-                        # prefill -> decode phase flip at first token
-                        trace.end_span(st.phase_span)
-                        st.phase_span = trace.start_span(
-                            "decode", parent=st.span)
-                elif _monitor_on() and st.t_prev_token is not None:
-                    STAT_OBSERVE("serving.gen_inter_token_ms",
-                                 (t_step - st.t_prev_token) * 1e3,
-                                 buckets=MS_BUCKETS)
-                st.t_prev_token = t_step
-                if st.req.stream_cb is not None:
-                    st.req.stream_cb(tok)
-                    if st.phase_span is not None:
-                        st.phase_span.add_event(
-                            "stream_flush", token_index=len(st.generated))
+                self._emit(rec, st, tok, logits[i, 0], t_step)
                 done_eos = (st.req.eos_id is not None
                             and tok == st.req.eos_id)
                 if done_eos or len(st.generated) >= \
@@ -991,10 +1032,46 @@ class GenerationEngine:
                     self._slots.release(i)
                 else:
                     st.cur = tok
-            _goodput.gen_busy(time.perf_counter() - t_busy0)
+
+    def _emit(self, rec, st: _SlotState, tok: int, row, t_step: float):
+        """Commit one sampled token of a slot: the iteration's and the
+        request's counters, the first token's time and the span tree's
+        prefill -> decode flip, then the request's hooks (`row` is the
+        logits row the token was sampled from)."""
+        st.generated.append(tok)
+        rec.tokens_emitted += 1
+        STAT_ADD("serving.gen_tokens")
+        if len(st.generated) == 1:
+            st.ttft_ms = (t_step - st.t_submit) * 1e3
+            st.response.timings["ttft_ms"] = st.ttft_ms
+            if _monitor_on():
+                STAT_OBSERVE("serving.gen_ttft_ms", st.ttft_ms,
+                             buckets=MS_BUCKETS)
+            if st.span is not None:
+                # prefill -> decode phase flip at first token
+                self._close_phase(st)
+                st.phase_span = trace.start_span(
+                    "decode", parent=st.span)
+            if self.paged and not st.registered:
+                # the whole prompt (every full block of it) is now
+                # resident and immutable — shareable from here on
+                self._register_prefix(st)
+                st.registered = True
+        elif _monitor_on() and st.t_prev_token is not None:
+            STAT_OBSERVE("serving.gen_inter_token_ms",
+                         (t_step - st.t_prev_token) * 1e3,
+                         buckets=MS_BUCKETS)
+        st.t_prev_token = t_step
+        if st.req.logits_cb is not None:
+            st.req.logits_cb(row)
+        if st.req.stream_cb is not None:
+            st.req.stream_cb(tok)
+            if st.phase_span is not None:
+                st.phase_span.add_event(
+                    "stream_flush", token_index=len(st.generated))
 
     # -- paged iteration -------------------------------------------------
-    def _paged_iteration(self):
+    def _paged_iteration(self, rec):
         """One scheduler iteration of the paged engine: (1) chunked
         prefill — every slot still consuming its prompt retires up to
         one BLOCK of tokens through the prefill executable; (2) one
@@ -1005,7 +1082,6 @@ class GenerationEngine:
         therefore interleave with decode at block granularity instead
         of stalling the batch for O(prompt) steps."""
         from ..core.flags import FLAGS
-        from ..models import sampling
         B = self.max_slots
         bs = self.block_size
         mb = self.step.max_blocks_per_slot
@@ -1066,23 +1142,26 @@ class GenerationEngine:
             i for i in range(B) if self._state[i] is not None
             and self._state[i].fed < len(self._state[i].req.prompt) - 1]
         if prefill_idx:
-            tokens = np.zeros((B, bs), np.int64)
-            table = np.zeros((B, mb), np.int64)
-            start = np.zeros(B, np.int64)
-            nvalid = np.zeros(B, np.int64)
-            chunk_n = {}
-            for i in prefill_idx:
-                st = self._state[i]
-                prompt = st.req.prompt
-                n = min(bs, len(prompt) - 1 - st.fed)
-                tokens[i, :n] = prompt[st.fed:st.fed + n]
-                fill_row(table, start, i, st)
-                nvalid[i] = n
-                chunk_n[i] = n
-            probe = run_guarded(self._prefill_prog, self.prefill_step,
-                                tokens, table, start, nvalid,
-                                prefill_idx, "prefill",
-                                site="gen_prefill")
+            with trace.region("gen.prefill.stage"):
+                tokens = np.zeros((B, bs), np.int64)
+                table = np.zeros((B, mb), np.int64)
+                start = np.zeros(B, np.int64)
+                nvalid = np.zeros(B, np.int64)
+                chunk_n = {}
+                for i in prefill_idx:
+                    st = self._state[i]
+                    prompt = st.req.prompt
+                    n = min(bs, len(prompt) - 1 - st.fed)
+                    tokens[i, :n] = prompt[st.fed:st.fed + n]
+                    fill_row(table, start, i, st)
+                    nvalid[i] = n
+                    chunk_n[i] = n
+            rec.prefill_rows = len(prefill_idx)
+            with trace.region("gen.prefill.step"):
+                probe = run_guarded(self._prefill_prog,
+                                    self.prefill_step, tokens, table,
+                                    start, nvalid, prefill_idx,
+                                    "prefill", site="gen_prefill")
             if probe is None:
                 return
             if FLAGS.serving_nan_guard:
@@ -1099,10 +1178,13 @@ class GenerationEngine:
                         self._release_slot(i)
                     prefill_idx = [i for i in prefill_idx
                                    if i not in bad]
+                    rec.prefill_rows = len(prefill_idx)
             for i in prefill_idx:
                 st = self._state[i]
                 st.fed += chunk_n[i]
                 st.cur = st.req.prompt[st.fed]
+                rec.prefill_tokens += chunk_n[i]
+                st.response.timings["prefill_steps"] += 1
                 STAT_ADD("serving.gen_chunked_prefills")
                 if st.phase_span is not None:
                     st.phase_span.add_event("prefill_chunk",
@@ -1124,50 +1206,69 @@ class GenerationEngine:
         # start(), so the per-iteration choice never costs a compile.
         drafts = {}
         if self._drafter is not None:
-            for i in decode_idx:
-                st = self._state[i]
-                if st.req.spec_decode is False:
-                    continue
-                # cap drafts to the blocks admission reserved (need-1
-                # is the slot's last writable position) and to the
-                # request's remaining token budget (the verify row
-                # already emits one token beyond the accepted drafts)
-                need = len(st.req.prompt) + st.req.max_new_tokens - 1
-                if st.spec_k_cur is None:
-                    st.spec_k_cur = self.spec_k
-                k_slot = st.spec_k_cur if self.spec_adaptive \
-                    else self.spec_k
-                cap = min(k_slot, need - 1 - st.fed,
-                          st.req.max_new_tokens - len(st.generated) - 1)
-                if cap < 1:
-                    continue
-                d = self._drafter.draft(st.req.prompt + st.generated,
-                                        cap)
-                if d:
-                    drafts[i] = d
+            with trace.region("gen.decode.draft"):
+                for i in decode_idx:
+                    st = self._state[i]
+                    if st.req.spec_decode is False:
+                        continue
+                    # cap drafts to the blocks admission reserved
+                    # (need-1 is the slot's last writable position) and
+                    # to the request's remaining token budget (the
+                    # verify row already emits one token beyond the
+                    # accepted drafts)
+                    need = len(st.req.prompt) + \
+                        st.req.max_new_tokens - 1
+                    if st.spec_k_cur is None:
+                        st.spec_k_cur = self.spec_k
+                    k_slot = st.spec_k_cur if self.spec_adaptive \
+                        else self.spec_k
+                    cap = min(k_slot, need - 1 - st.fed,
+                              st.req.max_new_tokens
+                              - len(st.generated) - 1)
+                    if cap < 1:
+                        continue
+                    d = self._drafter.draft(
+                        st.req.prompt + st.generated, cap)
+                    if d:
+                        drafts[i] = d
         use_spec = bool(drafts)
         prog = self._spec_prog if use_spec else self._prog
         step = self.spec_step if use_spec else self.step
         T = self.spec_k + 1 if use_spec else 1
-        tokens = np.zeros((B, T), np.int64)
-        table = np.zeros((B, mb), np.int64)
-        start = np.zeros(B, np.int64)
-        nvalid = np.zeros(B, np.int64)
-        n_draft = {}
-        for i in decode_idx:
-            st = self._state[i]
-            d = drafts.get(i, ())
-            n_draft[i] = len(d)
-            tokens[i, 0] = st.cur
-            if d:
-                tokens[i, 1:1 + len(d)] = d
-            fill_row(table, start, i, st)
-            nvalid[i] = 1 + len(d)
-        logits = run_guarded(prog, step, tokens, table, start, nvalid,
-                             decode_idx,
-                             "spec verify" if use_spec else "decode")
+        with trace.region("gen.decode.stage"):
+            tokens = np.zeros((B, T), np.int64)
+            table = np.zeros((B, mb), np.int64)
+            start = np.zeros(B, np.int64)
+            nvalid = np.zeros(B, np.int64)
+            n_draft = {}
+            for i in decode_idx:
+                st = self._state[i]
+                d = drafts.get(i, ())
+                n_draft[i] = len(d)
+                tokens[i, 0] = st.cur
+                if d:
+                    tokens[i, 1:1 + len(d)] = d
+                fill_row(table, start, i, st)
+                nvalid[i] = 1 + len(d)
+        rec.decode_rows = len(decode_idx)
+        with trace.region("gen.decode.step"):
+            logits = run_guarded(prog, step, tokens, table, start,
+                                 nvalid, decode_idx,
+                                 "spec verify" if use_spec else "decode")
         if logits is None:
             return
+        with trace.region("gen.sample"):
+            self._sample_paged(rec, logits, tokens, n_draft, decode_idx,
+                               use_spec)
+
+    def _sample_paged(self, rec, logits, tokens, n_draft, decode_idx,
+                      use_spec):
+        """What the host does with the logits of a decode (or verify)
+        step once they are fetched: the finiteness guard, then for every
+        slot sampling (or draft acceptance), the request's hooks, and
+        the finish, release and prefix registration that follow."""
+        from ..core.flags import FLAGS
+        from ..models import sampling
         inj = _fault_injector()
         if inj is not None:
             arrs = [logits]
@@ -1187,15 +1288,12 @@ class GenerationEngine:
                         "decode step)"))
                     self._release_slot(i)
                 decode_idx = [i for i in decode_idx if i not in bad]
+                rec.decode_rows = len(decode_idx)
                 if not decode_idx:
                     return
         STAT_ADD("serving.gen_steps")
         if use_spec:
             STAT_ADD("serving.gen_spec_steps")
-        if _monitor_on():
-            STAT_OBSERVE("serving.gen_slot_occupancy",
-                         len(decode_idx) / float(B),
-                         buckets=FRACTION_BUCKETS)
 
         t_step = time.perf_counter()
         for i in decode_idx:
@@ -1231,36 +1329,9 @@ class GenerationEngine:
                     top_k=st.req.top_k, rng=st.rng)]
                 st.fed += 1
             finished = False
-            for tok in emitted:
-                st.generated.append(tok)
-                STAT_ADD("serving.gen_tokens")
-                if len(st.generated) == 1:
-                    st.ttft_ms = (t_step - st.t_submit) * 1e3
-                    if _monitor_on():
-                        STAT_OBSERVE("serving.gen_ttft_ms", st.ttft_ms,
-                                     buckets=MS_BUCKETS)
-                    if st.span is not None:
-                        # prefill -> decode phase flip at first token
-                        trace.end_span(st.phase_span)
-                        st.phase_span = trace.start_span(
-                            "decode", parent=st.span)
-                    if not st.registered:
-                        # the whole prompt (every full block of it) is
-                        # now resident and immutable — shareable from
-                        # here on
-                        self._register_prefix(st)
-                        st.registered = True
-                elif _monitor_on() and st.t_prev_token is not None:
-                    STAT_OBSERVE("serving.gen_inter_token_ms",
-                                 (t_step - st.t_prev_token) * 1e3,
-                                 buckets=MS_BUCKETS)
-                st.t_prev_token = t_step
-                if st.req.stream_cb is not None:
-                    st.req.stream_cb(tok)
-                    if st.phase_span is not None:
-                        st.phase_span.add_event(
-                            "stream_flush",
-                            token_index=len(st.generated))
+            for j, tok in enumerate(emitted):
+                # emitted[j] was drawn from row j (accept_draft)
+                self._emit(rec, st, tok, logits[i, j], t_step)
                 done_eos = (st.req.eos_id is not None
                             and tok == st.req.eos_id)
                 if done_eos or len(st.generated) >= \

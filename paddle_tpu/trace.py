@@ -29,6 +29,15 @@ Near-zero cost when disabled: every entry point checks
 FLAGS_enable_trace through a cached flag handle (same discipline as
 monitor.enabled()) and returns None; all APIs tolerate None spans, so
 instrumented hot paths cost ~a function call when tracing is off.
+
+Two things here are ALWAYS on, whatever the flag says. `region(name)`
+marks a stretch of the program's own host code: a profiler TraceMe (so
+any `jax.profiler` trace shows it on the host plane, on the clock of
+the device's ops), its seconds in the iteration record that is open on
+this thread, and, under FLAGS_enable_trace, a child of the current
+span. The iteration ring keeps one `IterationRecord` for every turn of
+the generation engine's loop that ran a step (`iteration_records()`):
+what an operator has when no profiler is attached.
 """
 from __future__ import annotations
 
@@ -42,12 +51,16 @@ import time
 from collections import deque
 from typing import Dict, List, Optional, Tuple
 
+import jax
+
 from . import monitor
 from .monitor import STAT_ADD, STAT_SET
 
 __all__ = ["Span", "enabled", "start_span", "end_span", "record_span",
            "finish_trace", "is_root", "complete_request",
-           "use_span", "span", "current_span",
+           "use_span", "span", "region", "current_span",
+           "IterationRecord", "begin_iteration", "end_iteration",
+           "iteration_records",
            "current_trace_id", "parse_traceparent", "format_traceparent",
            "new_trace_id", "new_span_id", "ring_spans", "drain_spans",
            "export_jsonl", "export_chrome_tracing", "slow_threshold_ms",
@@ -95,8 +108,12 @@ class Span:
         self.span_id = span_id
         self.parent_id = parent_id
         self.name = name
-        self.t_start = time.time() if t_start is None else t_start
-        self._perf0 = time.perf_counter() if perf0 is None else perf0
+        now = time.perf_counter()
+        self._perf0 = now if perf0 is None else perf0
+        # a span that began at an earlier perf_counter reading began
+        # that much earlier on the wall clock too
+        self.t_start = time.time() - (now - self._perf0) \
+            if t_start is None else t_start
         self.dur_ms = None
         self.attrs: Dict[str, object] = {}
         self.events: List[dict] = []
@@ -175,13 +192,17 @@ def current_trace_id() -> Optional[str]:
 def start_span(name: str, parent: Optional[Span] = None,
                attrs: Optional[dict] = None,
                remote: Optional[Tuple[str, str]] = None,
-               t_start: Optional[float] = None) -> Optional[Span]:
+               t_start: Optional[float] = None,
+               perf0: Optional[float] = None) -> Optional[Span]:
     """Start a span. With no explicit parent, the contextvar current
     span is the parent; with neither, this starts a ROOT span (new
     trace) — the head-sampling decision is made here. `remote` is a
     (trace_id, parent_span_id) pair from an incoming traceparent header:
     the new span is a root locally (it owns finish_trace) but continues
-    the caller's trace id. Returns None when tracing is disabled."""
+    the caller's trace id. `perf0` is the `time.perf_counter()` reading
+    the span began at, where the caller took one before it got here (a
+    thread can lose the interpreter for milliseconds in between).
+    Returns None when tracing is disabled."""
     if not enabled():
         return None
     from .core.flags import FLAGS
@@ -189,7 +210,7 @@ def start_span(name: str, parent: Optional[Span] = None,
         parent = _CURRENT.get()
     if parent is not None:
         sp = Span(parent.trace_id, new_span_id(), parent.span_id, name,
-                  t_start=t_start)
+                  t_start=t_start, perf0=perf0)
         with _LOCK:
             tr = _ACTIVE.get(parent.trace_id)
             if tr is not None:
@@ -200,7 +221,7 @@ def start_span(name: str, parent: Optional[Span] = None,
         else:
             trace_id, parent_id = new_trace_id(), None
         sp = Span(trace_id, new_span_id(), parent_id, name,
-                  t_start=t_start)
+                  t_start=t_start, perf0=perf0)
         head = random.random() < FLAGS.trace_sample
         with _LOCK:
             tr = _ACTIVE.get(trace_id)
@@ -393,6 +414,120 @@ def span(name: str, attrs: Optional[dict] = None):
 
 
 # ---------------------------------------------------------------------------
+# Regions of the program's own host code, and the iteration record
+# ---------------------------------------------------------------------------
+
+ITERATION_RING = 4096    # some 18 minutes of 270 ms iterations
+
+_ITERATIONS: "deque" = deque(maxlen=ITERATION_RING)
+_ITERATION: "contextvars.ContextVar[Optional[IterationRecord]]" = \
+    contextvars.ContextVar("paddle_tpu_trace_iteration", default=None)
+
+
+class IterationRecord:
+    """One turn of the generation engine's loop that ran a step. Times
+    are `time.perf_counter()` seconds. `host_s[name]` is the time the
+    turn spent in region `name` ITSELF, nested regions taken out, so
+    the values add up to the stretch that `gen.iteration` covers and
+    `host_s["gen.iteration"]` is what lies under no other region."""
+
+    __slots__ = ("t_start", "t_end", "prefill_rows", "prefill_tokens",
+                 "decode_rows", "tokens_emitted", "queue_depth",
+                 "active_slots", "kv_blocks_held", "kv_tokens_resident",
+                 "kv_blocks_total", "slots", "block_size", "host_s",
+                 "_open")
+
+    def __init__(self, slots, block_size, kv_blocks_total):
+        self.t_start = time.perf_counter()
+        self.t_end = None
+        self.prefill_rows = self.prefill_tokens = 0
+        self.decode_rows = self.tokens_emitted = 0
+        self.queue_depth = self.active_slots = 0
+        self.kv_blocks_held = self.kv_tokens_resident = 0
+        self.kv_blocks_total = kv_blocks_total
+        self.slots = slots
+        self.block_size = block_size
+        self.host_s: Dict[str, float] = {}
+        self._open = None   # the innermost region open on this record
+
+    def to_dict(self) -> dict:
+        out = {k: getattr(self, k) for k in self.__slots__
+               if k != "_open"}
+        out["host_s"] = dict(self.host_s)
+        return out
+
+
+def begin_iteration(slots: int, block_size: int,
+                    kv_blocks_total: int) -> IterationRecord:
+    """Open a record on this thread; regions entered until
+    end_iteration() add their seconds to it."""
+    rec = IterationRecord(slots, block_size, kv_blocks_total)
+    _ITERATION.set(rec)
+    return rec
+
+
+def end_iteration(rec: IterationRecord, keep: bool = True):
+    """Close the thread's record; `keep` appends it to the ring (a turn
+    that only waited for work leaves none)."""
+    rec.t_end = time.perf_counter()
+    _ITERATION.set(None)
+    if keep:
+        _ITERATIONS.append(rec)
+
+
+def iteration_records() -> List[dict]:
+    """Point-in-time copy of the iteration ring (oldest first), every
+    engine of the process together."""
+    # deque.copy() runs under the GIL in one piece: a worker that
+    # appends meanwhile cannot break the iteration
+    return [r.to_dict() for r in _ITERATIONS.copy()]
+
+
+class region:
+    """`with trace.region("gen.sample"):` around a stretch of the
+    program's own host code. Names are constants: nothing is formatted
+    on the hot path. Costs about a microsecond with no profiler
+    session, no flag and no record open."""
+
+    __slots__ = ("name", "_ann", "_t0", "_rec", "_outer", "_inner_s",
+                 "_span", "_tok")
+
+    def __init__(self, name: str):
+        self.name = name
+
+    def __enter__(self):
+        self._ann = jax.profiler.TraceAnnotation(self.name)
+        self._ann.__enter__()
+        rec = self._rec = _ITERATION.get()
+        if rec is not None:
+            self._outer, rec._open = rec._open, self
+            self._inner_s = 0.0
+        self._span = None
+        if enabled():
+            cur = _CURRENT.get()
+            if cur is not None:
+                self._span = start_span(self.name, parent=cur)
+                self._tok = _CURRENT.set(self._span)
+        self._t0 = time.perf_counter()
+
+    def __exit__(self, exc_type, exc, tb):
+        dt = time.perf_counter() - self._t0
+        if self._span is not None:
+            _CURRENT.reset(self._tok)
+            end_span(self._span, error=None if exc is None
+                     else f"{exc_type.__name__}: {exc}")
+        rec = self._rec
+        if rec is not None:
+            outer = rec._open = self._outer
+            if outer is not None:
+                outer._inner_s += dt
+            rec.host_s[self.name] = \
+                rec.host_s.get(self.name, 0.0) + dt - self._inner_s
+        self._ann.__exit__(exc_type, exc, tb)
+        return False
+
+
+# ---------------------------------------------------------------------------
 # W3C traceparent (00-<trace_id>-<span_id>-<flags>)
 # ---------------------------------------------------------------------------
 
@@ -507,9 +642,10 @@ def export_chrome_tracing(path: str,
 
 
 def reset():
-    """Drop every in-flight trace, the kept ring, and the rolling
-    latency window (tests)."""
+    """Drop every in-flight trace, the kept ring, the rolling latency
+    window and the iteration ring (tests)."""
     with _LOCK:
         _ACTIVE.clear()
         _RING.clear()
         _LAT_WINDOW.clear()
+    _ITERATIONS.clear()

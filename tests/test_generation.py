@@ -379,6 +379,217 @@ def test_paged_pool_decouples_planner_kv_from_slots(trained):
 # HTTP front end: /v1/generate
 # ---------------------------------------------------------------------------
 
+# ---------------------------------------------------------------------------
+# The iteration record, the request's own timings, the logits hook
+# ---------------------------------------------------------------------------
+
+GEN_REGIONS = {"gen.iteration", "gen.admit", "gen.idle_wait",
+               "gen.prefill.stage", "gen.prefill.step",
+               "gen.decode.draft", "gen.decode.stage", "gen.decode.step",
+               "gen.sample", "executor.resolve", "executor.feed",
+               "executor.compile", "executor.dispatch", "executor.fetch"}
+
+
+def _records_since(t0):
+    from paddle_tpu import trace
+    return [r for r in trace.iteration_records() if r["t_start"] >= t0]
+
+
+def test_paged_engine_leaves_one_record_an_iteration(trained):
+    """With no flag set and no profiler attached: one record for every
+    turn of the loop that ran a step, whose fields add up and whose
+    gauges are the ones the monitor publishes."""
+    import time
+
+    from paddle_tpu import monitor
+    cfg, scope, exe = trained
+    prompts = [([0, 1, 2, 3, 4, 5], 4), ([5, 6], 5), ([1, 2, 3, 4], 4),
+               ([7], 6), ([3, 4, 5, 6, 7], 3)]
+    streamed = []
+    prev = fluid.FLAGS.enable_monitor
+    fluid.set_flags({"FLAGS_enable_monitor": True})
+    monitor.reset_stats()
+    try:
+        eng = GenerationEngine(cfg, scope, exe=fluid.Executor(),
+                               max_slots=2, max_seq=SEQ, block_size=4)
+        eng.start()
+        t0 = time.perf_counter()
+        try:
+            resps = [eng.submit(GenerationRequest(
+                p, n, stream_cb=streamed.append)) for p, n in prompts]
+            outs = [r.result(timeout=60.0) for r in resps]
+        finally:
+            eng.stop()
+        snap = monitor.get_stats_snapshot()
+    finally:
+        monitor.reset_stats()
+        fluid.set_flags({"FLAGS_enable_monitor": prev})
+    recs = _records_since(t0)
+    assert recs and all(r["t_end"] > r["t_start"] for r in recs)
+    # turns do not overlap, and each ran a step
+    for a, b in zip(recs, recs[1:]):
+        assert a["t_end"] <= b["t_start"]
+    total = eng.kv_block_stats()["blocks_total"]
+    for r in recs:
+        assert r["prefill_rows"] + r["decode_rows"] > 0
+        assert r["decode_rows"] <= r["slots"] == 2
+        assert r["prefill_rows"] <= r["active_slots"] <= r["slots"]
+        assert r["block_size"] == 4 and r["kv_blocks_total"] == total
+        assert r["prefill_tokens"] <= r["prefill_rows"] * r["block_size"]
+        assert (r["prefill_tokens"] > 0) == (r["prefill_rows"] > 0)
+        assert r["kv_tokens_resident"] <= \
+            r["kv_blocks_held"] * r["block_size"]
+        assert r["tokens_emitted"] <= r["decode_rows"]
+        assert set(r["host_s"]) <= GEN_REGIONS, sorted(r["host_s"])
+        assert {"gen.iteration", "gen.admit"} <= set(r["host_s"])
+        assert "gen.idle_wait" not in r["host_s"]
+        assert sum(r["host_s"].values()) <= r["t_end"] - r["t_start"]
+        if r["decode_rows"]:
+            assert {"gen.decode.stage", "gen.decode.step", "gen.sample",
+                    "executor.dispatch", "executor.fetch"} \
+                <= set(r["host_s"])
+    n_out = sum(len(o["tokens"]) for o in outs)
+    assert sum(r["tokens_emitted"] for r in recs) == len(streamed) \
+        == n_out == sum(n for _, n in prompts)
+    # every prompt token but a prompt's last goes through a chunk
+    assert sum(r["prefill_tokens"] for r in recs) == \
+        sum(len(p) - 1 for p, _ in prompts)
+    assert max(r["queue_depth"] for r in recs) >= 1   # 5 requests, 2 slots
+    assert "executor.compile" not in {k for r in recs for k in r["host_s"]}
+    # the monitor's gauges and goodput seconds are the records' own
+    occ = snap["histograms"]["serving.gen_slot_occupancy"]
+    assert occ["count"] == sum(1 for r in recs if r["decode_rows"])
+    assert snap["gauges"]["serving.gen_active_slots"] == 0
+    assert snap["gauges"]["serving.gen_kv_blocks_free"] <= total
+
+
+def test_slab_engine_records_its_three_regions(trained):
+    import time
+    cfg, scope, _ = trained
+    eng = GenerationEngine(cfg, scope, exe=fluid.Executor(), max_slots=2,
+                           max_seq=SEQ, paged=False)
+    eng.start()
+    t0 = time.perf_counter()
+    try:
+        out = eng.generate([0, 1, 2], 4)
+        early = eng.submit(GenerationRequest([4, 5], 2))
+        early.result(timeout=60.0)
+    finally:
+        eng.stop()
+    recs = _records_since(t0)
+    # a prompt is stepped through the decode graph a token at a time
+    assert len(recs) >= 3 + 4 - 1
+    assert sum(r["tokens_emitted"] for r in recs) == 4 + 2
+    for r in recs:
+        assert r["prefill_rows"] == 0 and r["block_size"] == 0
+        assert 1 <= r["decode_rows"] <= 2
+        assert {k for k in r["host_s"] if k.startswith("gen.")} == \
+            {"gen.iteration", "gen.admit", "gen.decode.step", "gen.sample"}
+        assert sum(r["host_s"].values()) <= r["t_end"] - r["t_start"]
+    assert out["queue_ms"] >= 0.0
+    assert early.timings["prefill_steps"] == 1
+
+
+def test_timings_are_readable_before_the_request_finishes(trained):
+    """`timings` fills as the request passes each boundary; a request
+    submitted to a full engine waits in the queue for a slot, and its
+    `queue_ms` says so."""
+    import threading
+    cfg, scope, _ = trained
+    eng = GenerationEngine(cfg, scope, exe=fluid.Executor(), max_slots=1,
+                           max_seq=SEQ, block_size=4)
+    eng.start()
+    seen, sent, gate = {}, threading.Event(), threading.Event()
+
+    def first_token(tok):
+        # on the engine's thread, mid-request: the response is not done
+        if not seen:
+            sent.wait(10.0)
+            seen.update(first.timings, done=first.done())
+            gate.wait(10.0)
+
+    try:
+        first = eng.submit(GenerationRequest([0, 1, 2, 3, 4, 5], 4,
+                                             stream_cb=first_token))
+        sent.set()
+        second = eng.submit(GenerationRequest([0, 1, 2, 3, 4, 6], 2))
+        assert second.timings == {}      # still queued: no slot is free
+        gate.set()
+        out1 = first.result(timeout=60.0)
+        out2 = second.result(timeout=60.0)
+    finally:
+        gate.set()
+        eng.stop()
+    assert seen["done"] is False
+    assert seen["queue_ms"] >= 0.0 and seen["ttft_ms"] >= seen["queue_ms"]
+    assert seen["cached_tokens"] == 0
+    # 5 prompt tokens but the last go through chunks of 4
+    assert seen["prefill_steps"] == 2
+    assert first.timings["ttft_ms"] == out1["ttft_ms"]
+    assert out1["queue_ms"] == first.timings["queue_ms"]
+    # the second waited for the first to finish, and found its first
+    # block in the prefix cache
+    assert second.timings["queue_ms"] > first.timings["queue_ms"]
+    assert second.timings["queue_ms"] >= \
+        out1["e2e_ms"] - first.timings["queue_ms"] - 50.0
+    assert second.timings["cached_tokens"] == out2["cached_tokens"] == 4
+    assert second.timings["prefill_steps"] == 1
+    assert out2["queue_ms"] == second.timings["queue_ms"]
+
+
+@pytest.mark.parametrize("spec", [False, True])
+def test_logits_cb_gets_the_rows_the_step_call_returned(trained, spec):
+    """`logits_cb` hands over, token by token, the logits row each of
+    the request's tokens was sampled from: the very rows of
+    `_run_paged`'s return (the call the benchmark taps today), under
+    plain decode and under speculative verify."""
+    cfg, scope, exe = trained
+    dec_main, step = _serial_decode(cfg)
+    want = _kv(exe, scope, dec_main, step, [3, 4, 5, 3], 6)
+    eng = GenerationEngine(cfg, scope, exe=fluid.Executor(), max_slots=2,
+                           max_seq=SEQ, block_size=4, spec_decode=spec,
+                           spec_k=2)
+    fetched = []
+    inner = eng._run_paged
+
+    def tap(prog, step, tokens, table, start, nvalid):
+        out = inner(prog, step, tokens, table, start, nvalid)
+        if prog is not eng._prefill_prog:
+            fetched.append(out)
+        return out
+
+    eng._run_paged = tap
+    rows, toks, other = [], [], []
+    eng.start()
+    try:
+        # the repeated 3 lets the n-gram drafter propose [4, 5]
+        a = eng.submit(GenerationRequest([3, 4, 5, 3], 6,
+                                         logits_cb=rows.append,
+                                         stream_cb=toks.append))
+        b = eng.submit(GenerationRequest([5, 6, 7, 8, 9], 3,
+                                         logits_cb=other.append))
+        out = a.result(timeout=60.0)
+        b.result(timeout=60.0)
+    finally:
+        eng.stop()
+    assert out["tokens"] == toks == want
+    assert len(rows) == 6 and len(other) == 3
+    assert all(r.shape == (VOCAB,) for r in rows)
+    # greedy: each token is the argmax of the row it came with
+    assert [int(r.argmax()) for r in rows] == toks
+    # and each row is one of slot 0's rows of a step's own return,
+    # in the order the steps ran
+    flat = [f[0, j] for f in fetched for j in range(f.shape[1])]
+    at = 0
+    for r in rows:
+        while not np.array_equal(flat[at], r):
+            at += 1
+            assert at < len(flat), "a row that no step returned"
+        at += 1
+    if spec:
+        assert any(f.shape[1] == 3 for f in fetched)
+
+
 def _post(url, obj):
     req = urllib.request.Request(
         url, data=json.dumps(obj).encode(),

@@ -197,9 +197,14 @@ def test_starved_smoke_input_wait_dominates():
                         label="t_starved")
     assert goodput.check_invariant(snap, tol=0.05)
     cats = snap["categories"]
-    top = max(cats, key=lambda k: cats[k])
+    # the smoke's first step compiles inside the ledger's window, and
+    # how long that takes is the machine's load, not the reader's: the
+    # blame is judged over the wall clock that is not compile
+    steady = {k: v for k, v in cats.items() if k != "compile"}
+    top = max(steady, key=lambda k: steady[k])
     assert top == "input_wait", cats
-    assert cats["input_wait"] >= 0.5 * snap["wall_s"]
+    assert cats["input_wait"] >= \
+        0.5 * (snap["wall_s"] - cats["compile"]), cats
     assert snap["starved_steps"] == 8
     # the waterfall records carry the per-step wait for the report
     waits = [r["input_wait_s"] for r in snap["step_records"]]

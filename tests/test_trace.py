@@ -509,3 +509,186 @@ def test_loadgen_trace_mode_end_to_end(tmp_path, capsys):
     assert trp.main([spans_out, "--out", rep_out, "--strict"]) == 0
     capsys.readouterr()
     assert v.validate_file(rep_out) == []
+
+
+# ---------------------------------------------------------------------------
+# Regions of the program's own host code and the iteration record
+# ---------------------------------------------------------------------------
+
+def _small_step():
+    from paddle_tpu import layers
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        x = layers.data("x", shape=[-1, 4], dtype="float32",
+                        append_batch_size=False)
+        out = layers.fc(x, size=3)
+    return main, startup, out
+
+
+def test_region_seconds_go_to_the_open_iteration_exclusively():
+    """A region adds its own seconds, nested regions taken out, to the
+    record open on this thread: the values add up to what the outermost
+    region covers. With no record open it adds nothing anywhere."""
+    trace.reset()
+    with trace.region("gen.sample"):
+        pass
+    assert trace.iteration_records() == []
+    rec = trace.begin_iteration(slots=4, block_size=8, kv_blocks_total=9)
+    with trace.region("gen.iteration"):
+        with trace.region("gen.decode.step"):
+            with trace.region("executor.dispatch"):
+                time.sleep(0.02)
+            time.sleep(0.01)
+        with trace.region("gen.sample"):
+            time.sleep(0.01)
+        with trace.region("gen.sample"):
+            pass
+    rec.decode_rows = 2
+    trace.end_iteration(rec)
+    got, = trace.iteration_records()
+    host = got["host_s"]
+    assert set(host) == {"gen.iteration", "gen.decode.step",
+                         "executor.dispatch", "gen.sample"}
+    assert host["executor.dispatch"] >= 0.02
+    assert host["gen.decode.step"] >= 0.01
+    assert host["gen.sample"] >= 0.01
+    # durations that included their nested regions would add up to
+    # more than twice the turn
+    assert 0.04 <= sum(host.values()) <= got["t_end"] - got["t_start"]
+    assert (got["slots"], got["block_size"], got["kv_blocks_total"],
+            got["decode_rows"]) == (4, 8, 9, 2)
+    assert "_open" not in got
+    # a region entered after the record closed leaves it alone
+    with trace.region("gen.admit"):
+        pass
+    assert set(trace.iteration_records()[0]["host_s"]) == set(host)
+    trace.reset()
+
+
+def test_iteration_ring_is_bounded_and_drops_turns_that_only_waited():
+    trace.reset()
+    rec = trace.begin_iteration(1, 0, 0)
+    trace.end_iteration(rec, keep=False)
+    assert trace.iteration_records() == []
+    for i in range(trace.ITERATION_RING + 5):
+        rec = trace.begin_iteration(1, 0, 0)
+        rec.tokens_emitted = i
+        trace.end_iteration(rec)
+    recs = trace.iteration_records()
+    assert len(recs) == trace.ITERATION_RING
+    assert recs[0]["tokens_emitted"] == 5
+    assert recs[-1]["tokens_emitted"] == trace.ITERATION_RING + 4
+    trace.reset()
+    assert trace.iteration_records() == []
+
+
+def test_executor_regions_are_children_of_the_current_span():
+    """Under FLAGS_enable_trace the Executor's regions close children
+    of whatever span is current, as they run and nested as they run:
+    executor.feed (and, on a miss, executor.compile) inside
+    executor.resolve. `profiler.record_event` is the same primitive."""
+    import numpy as np
+    from paddle_tpu import profiler
+    main, startup, out = _small_step()
+    scope, exe = fluid.Scope(), fluid.Executor()
+    feed = {"x": np.ones((2, 4), np.float32)}
+    with fluid.scope_guard(scope):
+        exe.run(startup)
+        # tracing off: the regions run and record no span
+        exe.run(main, feed=feed, fetch_list=[out])
+        with _trace_on(sample=1.0):
+            root = trace.start_span("step")
+            with trace.use_span(root):
+                exe.run(main, feed=feed, fetch_list=[out])
+                with profiler.record_event("user.phase"):
+                    pass
+                assert trace.current_span() is root
+            trace.finish_trace(root)
+            spans = trace.drain_spans()
+    by_name = {s["name"]: s for s in spans}
+    assert set(by_name) == {"step", "executor.resolve", "executor.feed",
+                            "executor.dispatch", "executor.fetch",
+                            "user.phase"}
+    rid = by_name["step"]["span_id"]
+    for name in ("executor.resolve", "executor.dispatch",
+                 "executor.fetch", "user.phase"):
+        assert by_name[name]["parent_id"] == rid, name
+    assert by_name["executor.feed"]["parent_id"] == \
+        by_name["executor.resolve"]["span_id"]
+    assert all(s["dur_ms"] is not None and s["status"] == "ok"
+               for s in spans)
+    # a new feed shape misses the cache: the region that says so
+    with fluid.scope_guard(scope), _trace_on(sample=1.0):
+        root = trace.start_span("step")
+        with trace.use_span(root):
+            exe.run(main, feed={"x": np.ones((3, 4), np.float32)},
+                    fetch_list=[out])
+        trace.finish_trace(root)
+        spans = {s["name"]: s for s in trace.drain_spans()}
+    assert spans["executor.compile"]["parent_id"] == \
+        spans["executor.resolve"]["span_id"]
+
+
+def _host_events(path):
+    """(name, start_ns, end_ns) of every event on the trace's host
+    planes."""
+    import jax
+    out = []
+    data = jax.profiler.ProfileData.from_file(path)
+    for plane in data.planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                out.append((ev.name, ev.start_ns,
+                            ev.start_ns + ev.duration_ns))
+    return out
+
+
+def test_regions_land_on_the_profilers_host_plane_nested(tmp_path):
+    """Any jax.profiler trace shows the engine's and the Executor's
+    regions on the host plane, on the device trace's clock, nested as
+    the code nests them, with no flag set."""
+    import glob
+
+    import jax
+    eng = _fresh_engine()
+    assert eng.paged
+    eng.start()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        out = eng.submit(GenerationRequest([0, 1, 2, 3], 4)) \
+            .result(timeout=60.0)
+    finally:
+        # the worker first: a region still open when the session stops
+        # is not in the trace
+        eng.stop()
+        jax.profiler.stop_trace()
+    assert len(out["tokens"]) == 4
+    path, = glob.glob(os.path.join(str(tmp_path), "plugins", "profile",
+                                   "*", "*.xplane.pb"))
+    events = _host_events(path)
+    by_name = {}
+    for name, t0, t1 in events:
+        by_name.setdefault(name, []).append((t0, t1))
+    for name in ("gen.iteration", "gen.admit", "gen.prefill.stage",
+                 "gen.prefill.step", "gen.decode.stage",
+                 "gen.decode.step", "gen.sample", "executor.resolve",
+                 "executor.feed", "executor.dispatch", "executor.fetch"):
+        assert name in by_name, (name, sorted(by_name)[:40])
+    assert "executor.compile" not in by_name   # nothing recompiled
+
+    def inside(child, parent):
+        return all(any(p0 <= c0 and c1 <= p1
+                       for p0, p1 in by_name[parent])
+                   for c0, c1 in by_name[child])
+
+    assert inside("gen.sample", "gen.iteration")
+    assert inside("gen.decode.step", "gen.iteration")
+    assert inside("executor.feed", "executor.resolve")
+    # every Executor region of the window ran inside a step of the loop
+    steps = by_name["gen.decode.step"] + by_name["gen.prefill.step"]
+    for name in ("executor.resolve", "executor.dispatch",
+                 "executor.fetch"):
+        assert all(any(p0 <= c0 and c1 <= p1 for p0, p1 in steps)
+                   for c0, c1 in by_name[name]), name
