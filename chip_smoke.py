@@ -360,12 +360,15 @@ def phase_train(smoke):
     exe.close()
 
 
+def _mosaic_calls(hlo, kernel):
+    """How many Mosaic custom calls of `kernel` the compiled HLO holds
+    (the kernels carry their pallas_call names)."""
+    return sum(kernel in ln for ln in hlo.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in ln)
+
+
 def _mosaic_kernels(hlo):
-    """How many Mosaic custom calls of each flash kernel the compiled
-    HLO holds (the kernels carry their pallas_call names)."""
-    calls = [ln for ln in hlo.splitlines()
-             if 'custom_call_target="tpu_custom_call"' in ln]
-    return {k: sum(f"flash_attention_{k}" in ln for ln in calls)
+    return {k: _mosaic_calls(hlo, f"flash_attention_{k}")
             for k in ("fwd", "bwd_dq", "bwd_dkv")}
 
 
@@ -510,13 +513,25 @@ def phase_serve(smoke):
         # the same shapes fed as device arrays (int64 on the host is
         # int32 on the device, x64 being off) hit the same executables
         triples = []
+        paged_kernels = {}
         for name, prog, feed, fetch in engine.executables():
             dev_feed = {k: jax.device_put(v) for k, v in feed.items()}
             engine.exe.run(prog, feed=dev_feed, fetch_list=[fetch],
                            scope=scope)
-            _, triple = smoke.executable(f"gpt_paged_{name}", engine.exe,
-                                         prog, feed, [fetch], scope)
+            compiled, triple = smoke.executable(
+                f"gpt_paged_{name}", engine.exe, prog, feed, [fetch],
+                scope)
             triples.append(triple)
+            # paged_attention's read is a Mosaic kernel in the
+            # executables that served the requests above: one a layer
+            # on the chip, none where the rehearsal interprets it
+            paged_kernels[name] = _mosaic_calls(compiled.as_text(),
+                                                "paged_attention_read")
+            _check(paged_kernels[name] ==
+                   (0 if smoke.rehearsal else cfg.n_layers),
+                   f"gpt_paged_{name}: {paged_kernels[name]} Mosaic "
+                   f"paged_attention_read calls in the compiled HLO for "
+                   f"{cfg.n_layers} layers on {smoke.stamp['platform']}")
         _check(engine.post_warmup_compiles() == 0,
                f"{engine.post_warmup_compiles()} compiles after warmup "
                f"(requests, then device-array feeds of the same shapes)")
@@ -577,6 +592,9 @@ def phase_serve(smoke):
                  vocab_size=cfg.vocab_size, max_seq=cfg.max_seq_len,
                  slots=size["slots"], block_size=engine.block_size,
                  kv_pool_bytes=engine.kv_pool_bytes(),
+                 paged_attention_read="interpreted" if smoke.rehearsal
+                 else "compiled (Mosaic)",
+                 paged_attention_mosaic_calls=paged_kernels,
                  requests=len(prompts), concurrency=size["concurrency"],
                  prompt_lens=list(size["prompt_lens"]), new_tokens=new,
                  smoke_warmup_s=round(warm_s, 2),

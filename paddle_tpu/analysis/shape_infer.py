@@ -165,6 +165,58 @@ def _eval_op(op, in_specs: Dict[str, Spec]) -> Dict[str, Spec]:
     return specs
 
 
+def _freeze(value):
+    """A hashable stand-in for an attr value made of plain data; raises
+    TypeError for anything else (a Block, an array, a callable)."""
+    if value is None or isinstance(value, (bool, int, float, str, bytes)):
+        return (type(value).__name__, value)
+    if isinstance(value, (list, tuple)):
+        return (type(value).__name__, tuple(_freeze(v) for v in value))
+    if isinstance(value, dict):
+        return ("dict", tuple(sorted((str(k), _freeze(v))
+                                     for k, v in value.items())))
+    if isinstance(value, np.generic):
+        return (type(value).__name__, value.item())
+    raise TypeError(type(value).__name__)
+
+
+def _memo_key(op, in_specs):
+    """What `_eval_op`'s answer depends on, names left out: the op's
+    type and attrs, the spec at each input position, and which output
+    positions are named and which input each aliases (an in-place
+    output reads back its input's spec where the lowering sets none).
+    None where an attr is not plain data: such an op is evaluated
+    every time."""
+    try:
+        attrs = _freeze(op.attrs)
+    except TypeError:
+        return None
+    in_names = op.input_names()
+    ins = tuple((slot, tuple(in_specs.get(n) for n in names))
+                for slot, names in sorted(op.inputs.items()))
+    outs = tuple((slot, tuple(in_names.index(n) if n in in_names
+                              else (-1 if n else None) for n in names))
+                 for slot, names in sorted(op.outputs.items()))
+    return op.type, attrs, ins, outs
+
+
+def _eval_op_memo(op, in_specs, memo):
+    """`_eval_op`, once for the ops of one program that differ in their
+    variables' names only (the layers of a model: a 24-layer decoder has
+    4,000 ops and some 60 distinct ones). The answer is kept by output
+    position and handed back under this op's own names."""
+    key = _memo_key(op, in_specs)
+    if key is None:
+        return _eval_op(op, in_specs)
+    if key not in memo:
+        out = _eval_op(op, in_specs)
+        memo[key] = {(slot, i): out[n]
+                     for slot, names in op.outputs.items()
+                     for i, n in enumerate(names) if n in out}
+    return {op.outputs[slot][i]: spec
+            for (slot, i), spec in memo[key].items()}
+
+
 def infer_program_specs(program, result, check=True,
                         seed: Optional[Dict[str, Spec]] = None
                         ) -> Dict[str, Spec]:
@@ -176,6 +228,7 @@ def infer_program_specs(program, result, check=True,
     shapes here so dynamic (-1/_DYN_DIM) dims resolve downstream
     instead of poisoning size arithmetic (Spec.nbytes)."""
     envs: Dict[int, Dict[str, Spec]] = {}
+    memo: Dict[tuple, dict] = {}     # this call's only: _eval_op_memo
     for block in program.blocks:
         parent = envs.get(block.parent_idx, {}) \
             if block.parent_idx >= 0 else {}
@@ -186,7 +239,7 @@ def infer_program_specs(program, result, check=True,
                                       str(spec[1]))
         envs[block.idx] = env
         for op_idx, op in enumerate(block.ops):
-            _infer_op(op, op_idx, block, env, result, check)
+            _infer_op(op, op_idx, block, env, result, check, memo)
     return envs.get(0, {})
 
 
@@ -200,7 +253,7 @@ def _seed_outputs_from_decl(op, block, env):
             env[name] = spec
 
 
-def _infer_op(op, op_idx, block, env, result, check):
+def _infer_op(op, op_idx, block, env, result, check, memo):
     opdef = REGISTRY._ops.get(op.type)
     if opdef is None or op.type in OPAQUE_OPS:
         # unregistered is the verifier's PTV001; opaque is by design —
@@ -245,7 +298,7 @@ def _infer_op(op, op_idx, block, env, result, check):
         return
 
     try:
-        out = _eval_op(op, in_specs)
+        out = _eval_op_memo(op, in_specs, memo)
     except Exception as e:  # noqa: BLE001 — the whole point: any crash
         # inside the lowering under eval_shape means this program cannot
         # lower, reported with op provenance instead of a jnp traceback

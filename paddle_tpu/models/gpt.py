@@ -370,6 +370,7 @@ def build_paged_decode_step(cfg, batch, max_seq, block_size, num_blocks,
     from ..framework import ParamAttr
     from ..initializer import Normal
     from ..layer_helper import LayerHelper
+    from ..ops.pallas.paged_attention import pool_lanes
     import math as _math
 
     d, h = cfg.d_model, cfg.n_heads
@@ -405,6 +406,15 @@ def build_paged_decode_step(cfg, batch, max_seq, block_size, num_blocks,
     def dense(z, size, name, act=None):
         return transformer._dense(z, size, name, cfg, act=act)
 
+    # A pool is [blocks, block_size, lanes]: a token's d_model numbers
+    # side by side, heads in order. The heads are no dimension of their
+    # own because of where the TPU puts a [.., heads, head_dim] array
+    # whose head_dim is under a lane tile: it lays the BLOCK index on
+    # the lanes, a page is then strided through the whole pool, and
+    # every step relays the pool out (four whole-pool copies a layer)
+    # before it can scatter into it or gather from it.
+    pool_shape = [num_blocks, block_size, pool_lanes(d)]
+
     for i in range(cfg.n_layers):
         pre = f"layer_{i}"
         q = dense(x, d, f"{pre}.att.q")
@@ -417,10 +427,10 @@ def build_paged_decode_step(cfg, batch, max_seq, block_size, num_blocks,
         q, k, v = heads(q), heads(k), heads(v)
 
         ckp = layers.create_global_var(
-            [num_blocks, block_size, h, hd], 0.0, "float32",
+            pool_shape, 0.0, "float32",
             persistable=True, name=f"{state_prefix}{pre}.kv_pool_k")
         cvp = layers.create_global_var(
-            [num_blocks, block_size, h, hd], 0.0, "float32",
+            pool_shape, 0.0, "float32",
             persistable=True, name=f"{state_prefix}{pre}.kv_pool_v")
         cache_names += [ckp.name, cvp.name]
 

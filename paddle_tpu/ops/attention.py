@@ -3,9 +3,13 @@
 Reference analogue: operators/fused/multihead_matmul (the fused attention
 target of the multihead fusion pass). Here fusion is explicit: one op, one
 Pallas kernel, with custom-vjp backward. `paged_attention` is the
-serving-side sibling: gather-based incremental attention over a
-block-table paged KV pool (vLLM's PagedAttention model), exact on CPU
-so tier-1 parity tests hold bit-for-bit against the contiguous path.
+serving-side sibling: incremental attention over a block-table paged KV
+pool (vLLM's PagedAttention model). Its write is an XLA scatter, its
+read a Pallas kernel (ops/pallas/paged_attention.py) that follows each
+row's own length; on the CPU the kernel runs interpreted, so tier-1
+runs the code the chip runs. Parity with the contiguous path is
+token-exact (tests/test_generation.py), not bit-for-bit: the kernel
+sums in another order and multiplies at full float32 precision.
 """
 from __future__ import annotations
 
@@ -14,6 +18,7 @@ import jax.numpy as jnp
 
 from ..core.registry import register_op
 from .pallas.flash_attention import flash_attention, reference_attention
+from .pallas.paged_attention import pad_lanes, paged_attention_read
 
 # use_flash="auto" crossover (models/transformer.py consults this):
 # enable the tiled kernel only at max_seq_len >= this many tokens.
@@ -62,12 +67,17 @@ def _paged_attention_op(ctx, ins, attrs):
     """Incremental attention over a block-table paged KV pool.
 
     One call both WRITES this step's new K/V into the physical pool and
-    READS the row's whole logical history back out of it:
+    READS each row's own history back out of it:
 
       Q/K/V        [B, H, T, hd]   T new tokens per row (decode: T=1,
-                                   chunked prefill: T=block_size)
-      CacheK/V     [nb, bs, H, hd] the physical pool (block-major, so a
-                                   later int8 leg only rescales blocks)
+                                   chunked prefill: T=block_size,
+                                   spec verify: T=k+1)
+      CacheK/V     [nb, bs, lanes] the physical pool: a page is bs
+                                   tokens, a token's H*hd numbers side
+                                   by side, heads in order, in
+                                   pool_lanes(H*hd) lanes (why the
+                                   heads are no dimension of their own:
+                                   models/gpt.build_paged_decode_step)
       BlockTable   [B, max_blocks] logical block j of row b lives in
                                    physical block BlockTable[b, j]
       StartPos     [B]             position of the row's first new token
@@ -77,21 +87,22 @@ def _paged_attention_op(ctx, ins, attrs):
     Invalid (beyond-NValid) positions write to physical block 0 — the
     engine-reserved scratch block that no table ever maps — so the op
     is total over the fixed shape and the scheduler never needs a
-    second executable for partial chunks. Reads gather each row's
-    blocks in logical order, so key position j*bs+o carries the row's
-    j-th block at offset o; the causal mask (key_pos <= query_pos) uses
-    the slab path's exact 0/-1e30 additive form, keeping padded lanes
-    bit-identical zeros after softmax.
+    second executable for partial chunks. The read is a Pallas kernel
+    (ops/pallas/paged_attention.py) that walks each row's table as far
+    as the row's length StartPos + NValid and no further: key position
+    j*bs+o is the row's j-th block at offset o, query t sits at
+    StartPos + t and sees the keys at positions <= its own. Table
+    entries past the row's pages are never read; a muted row reads
+    nothing and returns zeros; rows t >= NValid are don't-care but
+    finite.
     """
     q, k, v = ins["Q"][0], ins["K"][0], ins["V"][0]
     cache_k, cache_v = ins["CacheK"][0], ins["CacheV"][0]
     table = ins["BlockTable"][0].astype(jnp.int32)
     start = ins["StartPos"][0].astype(jnp.int32)
     nvalid = ins["NValid"][0].astype(jnp.int32)
-    nb, bs, nh, hd = cache_k.shape
-    B, H, T, _ = q.shape
-    max_blocks = table.shape[1]
-    max_t = max_blocks * bs
+    nb, bs, d = cache_k.shape
+    B, H, T, hd = q.shape
     sm_scale = attrs.get("sm_scale") or float(hd) ** -0.5
 
     steps = jnp.arange(T, dtype=jnp.int32)
@@ -101,27 +112,13 @@ def _paged_attention_op(ctx, ins, attrs):
     flat_idx = jnp.where(valid, phys * bs + qpos % bs, 0)
 
     def write(pool, new):                                # new [B,H,T,hd]
-        flat = pool.reshape(nb * bs, nh, hd)
-        rows = new.transpose(0, 2, 1, 3).reshape(B * T, nh, hd)
-        return flat.at[flat_idx.reshape(-1)].set(rows).reshape(
-            nb, bs, nh, hd)
+        flat = pool.reshape(nb * bs, d)
+        rows = pad_lanes(
+            new.transpose(0, 2, 1, 3).reshape(B * T, H * hd), d)
+        return flat.at[flat_idx.reshape(-1)].set(rows).reshape(nb, bs, d)
 
     ck_new = write(cache_k, k)
     cv_new = write(cache_v, v)
-
-    # gather each row's logical history: [B, max_blocks, bs, H, hd]
-    # -> [B, H, max_t, hd]; entries past qpos are stale/scratch and die
-    # under the mask below
-    def history(pool):
-        g = jnp.take(pool, table, axis=0)
-        return g.reshape(B, max_t, nh, hd).transpose(0, 2, 1, 3)
-
-    keys, vals = history(ck_new), history(cv_new)
-    scores = jnp.einsum("bhtd,bhsd->bhts", q, keys) * sm_scale
-    kpos = jnp.arange(max_t, dtype=jnp.int32)
-    keep = (kpos[None, None, :] <= qpos[:, :, None]).astype(scores.dtype)
-    scores = scores + (keep * 1e30 - 1e30)[:, None, :, :]
-    probs = jax.nn.softmax(scores, axis=-1)  # same lowering as the
-    # slab path's softmax op (ops/nn_ops.py) — parity to the bit
-    out = jnp.einsum("bhts,bhsd->bhtd", probs, vals)
+    out = paged_attention_read(q, ck_new, cv_new, table, start, nvalid,
+                               sm_scale=float(sm_scale))
     return {"Out": [out], "CacheKOut": [ck_new], "CacheVOut": [cv_new]}

@@ -1,0 +1,204 @@
+"""The read half of `paged_attention` as one Pallas TPU kernel.
+
+One program per row of the batch. The row's block table and its length
+(`StartPos + NValid`) are scalar-prefetched; the program walks the
+`ceil(len / block_size)` table entries the row holds, copies those
+pages (and no other) from the pool in HBM into VMEM, `PAGES` at a time
+and double-buffered, and folds each chunk of keys into a running
+(online) softmax. A page past the row's length costs no DMA, and a row
+of length 0 reads nothing, computes nothing and returns exact zeros.
+
+A page of the pool `[nb, bs, H*hd]` is `[bs, H*hd]`, all heads side by
+side on the lanes. The heads are kept apart by laying the
+row's queries out block-diagonally: query (h, t) is a row of
+`[H*T, H*hd]` that is zero outside head h's lanes, so ONE product with
+a chunk of keys gives every head's scores, and the product of the
+probabilities with the values carries head h's output in head h's
+lanes of row (h, t). The products are float32 at full precision; max,
+sum and accumulator are float32.
+
+Runs interpreted on the CPU backend, as `flash_attention.py` does.
+"""
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from .flash_attention import NEG_INF, _interpret
+
+# pages copied and folded per turn of the walk: 8 pages of 16 tokens are
+# 128 keys, one lane tile of scores
+PAGES = 8
+LANES = 128
+
+
+def pool_lanes(d_model):
+    """The lane width of a pool whose tokens are `d_model` wide: whole
+    lane tiles, because a page is copied by DMA and Mosaic copies whole
+    tiles (at a model's real width, a multiple of 128, nothing is
+    added). The lanes past `d_model` stay zero and belong to no head."""
+    return -(-int(d_model) // LANES) * LANES
+
+
+def pad_lanes(x, lanes):
+    """`x` [..., d] zero-padded on its last axis to `lanes`."""
+    extra = lanes - x.shape[-1]
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, extra)]) \
+        if extra else x
+
+
+def _kernel(table_ref, start_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
+            kbuf, vbuf, sem, qbd_s, qpos_s, m_s, l_s, acc_s, *,
+            sm_scale, n_heads, head_dim, max_blocks):
+    b = pl.program_id(0)
+    _, pages, bs, d = kbuf.shape
+    t = q_ref.shape[1]
+    span = pages * bs
+    length = len_ref[b]
+    start = start_ref[b]
+    n_pages = (length + bs - 1) // bs
+    n_chunks = (n_pages + pages - 1) // pages
+
+    def for_held_pages(chunk, slot, act):
+        """`act` on the K and the V copy of each page of the chunk that
+        the row holds: a copy is started and waited for under the same
+        guard, and a page past the row's length costs none."""
+        for p in range(pages):
+            page = chunk * pages + p
+
+            @pl.when(page < n_pages)
+            def _():
+                block = table_ref[b * max_blocks + page]
+                for which, (hbm, buf) in enumerate(((k_hbm, kbuf),
+                                                    (v_hbm, vbuf))):
+                    act(pltpu.make_async_copy(
+                        hbm.at[block], buf.at[slot, p],
+                        sem.at[which, slot, p]))
+
+    def start_chunk(chunk, slot):
+        for_held_pages(chunk, slot, lambda dma: dma.start())
+
+    def wait_chunk(chunk, slot):
+        for_held_pages(chunk, slot, lambda dma: dma.wait())
+
+    @pl.when(length == 0)
+    def _muted():
+        o_ref[0] = jnp.zeros((t, d), jnp.float32)
+
+    @pl.when(length > 0)
+    def _attend():
+        start_chunk(0, 0)
+        # the row's queries, block-diagonal: row h*t + i is query i of
+        # head h, zero outside that head's lanes; its position beside it
+        lane_head = jax.lax.broadcasted_iota(
+            jnp.int32, (t, d), 1) // head_dim
+        steps = jax.lax.broadcasted_iota(jnp.int32, (t, 1), 0)
+        q = q_ref[0]
+        for h in range(n_heads):
+            qbd_s[pl.ds(h * t, t), :] = jnp.where(lane_head == h, q, 0.0)
+            qpos_s[pl.ds(h * t, t), :] = start + steps
+        m_s[...] = jnp.full(m_s.shape, NEG_INF, jnp.float32)
+        l_s[...] = jnp.zeros(l_s.shape, jnp.float32)
+        acc_s[...] = jnp.zeros(acc_s.shape, jnp.float32)
+
+        def fold(chunk, carry):
+            slot = chunk % 2
+
+            @pl.when(chunk + 1 < n_chunks)
+            def _next():
+                start_chunk(chunk + 1, 1 - slot)
+
+            wait_chunk(chunk, slot)
+            k = kbuf[slot].reshape(span, d)
+            v = vbuf[slot].reshape(span, d)
+            # a page that was not copied holds whatever the buffer held:
+            # its scores go under the mask, its values must not reach
+            # the product (0 * NaN)
+            held = chunk * span + jax.lax.broadcasted_iota(
+                jnp.int32, (span, 1), 0) < length
+            v = jnp.where(held, v, 0.0)
+            s = jax.lax.dot_general(
+                qbd_s[...], k, (((1,), (1,)), ((), ())),
+                precision=jax.lax.Precision.HIGHEST,
+                preferred_element_type=jnp.float32) * sm_scale
+            kpos = chunk * span + jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 1)
+            keep = jnp.logical_and(kpos <= qpos_s[...], kpos < length)
+            s = jnp.where(keep, s, NEG_INF)
+            m = m_s[...]
+            m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m - m_new)
+            l_s[...] = alpha * l_s[...] + jnp.sum(p, axis=1, keepdims=True)
+            acc_s[...] = alpha * acc_s[...] + jax.lax.dot_general(
+                p, v, (((1,), (0,)), ((), ())),
+                precision=jax.lax.Precision.HIGHEST,
+                preferred_element_type=jnp.float32)
+            m_s[...] = m_new
+            return carry
+
+        jax.lax.fori_loop(0, n_chunks, fold, 0)
+
+        # head h's output is head h's lanes of its own rows; every query
+        # sees key 0, so no sum is 0
+        out = jnp.zeros((t, d), jnp.float32)
+        for h in range(n_heads):
+            rows = pl.ds(h * t, t)
+            out = out + jnp.where(lane_head == h,
+                                  acc_s[rows, :] / l_s[rows, :], 0.0)
+        o_ref[0] = out
+
+
+@functools.partial(jax.jit, static_argnames=("sm_scale",))
+def paged_attention_read(q, pool_k, pool_v, table, start, nvalid, *,
+                         sm_scale):
+    """Attention of `q` [B, H, T, hd] over each row's own history in the
+    paged pools [nb, bs, H*hd]: logical block j of row b is physical
+    block `table[b, j]`, query t of row b sits at position
+    `start[b] + t` and sees the keys at positions <= its own and below
+    the row's length `start[b] + nvalid[b]` (`table`, `start`, `nvalid`
+    int32). Rows with `nvalid == 0` return zeros. Returns [B, H, T, hd].
+
+    Jitted, so that the layers of one program (same shapes) share one
+    trace and one lowering of the kernel."""
+    B, H, T, hd = q.shape
+    nb, bs, d = pool_k.shape
+    max_blocks = table.shape[1]
+    length = jnp.where(nvalid > 0, start + nvalid, 0)
+    q_rows = pad_lanes(q.transpose(0, 2, 1, 3).reshape(B, T, H * hd), d)
+    kernel = functools.partial(_kernel, sm_scale=float(sm_scale),
+                               n_heads=H, head_dim=hd,
+                               max_blocks=max_blocks)
+    vmem = functools.partial(pl.BlockSpec, memory_space=pltpu.VMEM)
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(B,),
+            in_specs=[
+                vmem((1, T, d), lambda b, *_: (b, 0, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=vmem((1, T, d), lambda b, *_: (b, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((2, PAGES, bs, d), jnp.float32),
+                pltpu.VMEM((2, PAGES, bs, d), jnp.float32),
+                pltpu.SemaphoreType.DMA((2, 2, PAGES)),
+                pltpu.VMEM((H * T, d), jnp.float32),
+                pltpu.VMEM((H * T, 1), jnp.int32),
+                pltpu.VMEM((H * T, 1), jnp.float32),
+                pltpu.VMEM((H * T, 1), jnp.float32),
+                pltpu.VMEM((H * T, d), jnp.float32),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((B, T, d), jnp.float32),
+        interpret=_interpret(),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=(pltpu.GridDimensionSemantics.ARBITRARY,)),
+        name="paged_attention_read",
+    )(table.reshape(-1), start, length, q_rows, pool_k, pool_v)
+    return out[..., :H * hd].reshape(B, T, H, hd).transpose(0, 2, 1, 3)

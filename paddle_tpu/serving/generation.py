@@ -30,8 +30,11 @@ shutdown, `_Response` future handles.
 Paged KV (FLAGS_gen_paged_kv, the default): instead of one contiguous
 `[max_slots, max_seq]` slab per layer, K/V lives in per-layer physical
 POOLS of fixed-size blocks (`serving/kv_blocks.py`), addressed through
-per-slot block tables fed to the `paged_attention` op every step. Peak
-KV HBM becomes `num_blocks x block_bytes` — budget-derived and
+per-slot block tables fed to the `paged_attention` op every step; the
+op reads a slot's table only as far as the slot's own length (a Pallas
+kernel, ops/pallas/paged_attention.py), so a step costs what the slots
+hold and not `max_seq`, and its outputs agree with the slab path token
+for token. Peak KV HBM becomes `num_blocks x block_bytes` — budget-derived and
 decoupled from the longest POSSIBLE sequence — and three scheduler
 moves fall out of the indirection: admission gates on free BLOCKS
 (actual tokens) rather than slots alone; a slot "reset" is just
@@ -373,11 +376,14 @@ class GenerationEngine:
     # -- paged-pool sizing ----------------------------------------------
     def kv_block_bytes(self) -> int:
         """HBM bytes one block occupies across every layer's K+V pool
-        (float32 today; the int8 KV leg only changes this number)."""
+        (float32 today; the int8 KV leg only changes this number). A
+        token takes whole lane tiles there (`pool_lanes`): d_model
+        itself at a real model's width."""
         if not self.paged:
             return 0
+        from ..ops.pallas.paged_attention import pool_lanes
         return 2 * self.cfg.n_layers * self.block_size * \
-            self.cfg.d_model * 4
+            pool_lanes(self.cfg.d_model) * 4
 
     def kv_pool_bytes(self) -> int:
         """Total K/V pool HBM across layers — what the static memory
@@ -402,9 +408,7 @@ class GenerationEngine:
         if FLAGS.gen_kv_pool_blocks > 0:
             n = int(FLAGS.gen_kv_pool_blocks)
         elif FLAGS.gen_kv_pool_bytes > 0:
-            block_bytes = 2 * self.cfg.n_layers * self.block_size * \
-                self.cfg.d_model * 4
-            n = int(FLAGS.gen_kv_pool_bytes) // block_bytes
+            n = int(FLAGS.gen_kv_pool_bytes) // self.kv_block_bytes()
         else:
             n = self.max_slots * per_slot + 1
         # floor: scratch + one slot's worth, or nothing ever admits
@@ -1128,6 +1132,13 @@ class GenerationEngine:
                     self._release_slot(i)
                 return None
             self._breaker.record_success()
+            # what the step read of the pool, by the rule the kernel
+            # follows: a row the pages its length start + n_valid
+            # covers, a muted row none
+            rec.kv_pages_read += sum(
+                blocks_for_tokens(s + n, bs)
+                for s, n in zip(start.tolist(), nvalid.tolist()) if n)
+            rec.kv_pages_table += table.size
             if trace.enabled():
                 lt = self.exe.last_step_timings
                 if lt is not None:

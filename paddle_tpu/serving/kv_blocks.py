@@ -4,9 +4,10 @@ Reference: the reference framework's memory layer is built around a
 pluggable block allocator (memory/allocation/allocator_facade.cc); this
 module is its serving-side analogue, applied to KV-cache HBM the way
 vLLM's PagedAttention applies OS paging to attention state. The device
-side holds ONE physical pool per layer — `[num_blocks, block_size, h,
-hd]` persistable tensors built by `models/gpt.build_paged_decode_step`
-— and this module owns the host-side metadata:
+side holds ONE physical pool per layer — `[num_blocks, block_size,
+lanes]` persistable tensors built by `models/gpt.build_paged_decode_step`
+(a token's d_model numbers side by side, in whole lane tiles) — and
+this module owns the host-side metadata:
 
 * `BlockPool` — free-list allocator over the physical block ids with
   per-block refcounts. Physical block 0 is reserved as the SCRATCH

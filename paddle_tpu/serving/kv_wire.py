@@ -20,7 +20,7 @@ from typing import Dict, List, Sequence, Tuple
 
 import numpy as np
 
-WIRE_VERSION = 1
+WIRE_VERSION = 2   # 2: rows are [n_blocks, block_size, lanes]
 
 
 def _resolve_dtype(name: str) -> np.dtype:
@@ -44,12 +44,13 @@ def _dtype_name(dt: np.dtype) -> str:
 @dataclass
 class KVShipment:
     """Decoded wire payload: per-layer (k, v) row stacks of shape
-    [n_blocks, block_size, n_heads, head_dim]."""
+    [n_blocks, block_size, lanes] (a pool's rows as the pool holds
+    them: ops/attention.py:paged_attention)."""
     version: int
     block_size: int
     n_tokens: int
     dtype: np.dtype
-    shape: Tuple[int, int, int, int]
+    shape: Tuple[int, int, int]
     chain_hashes: List[str]
     layers: List[Tuple[np.ndarray, np.ndarray]]
 
@@ -86,7 +87,7 @@ def pack_blocks(scope, cache_names: Sequence[str],
             dtype = rows.dtype
         layers.append(base64.b64encode(rows.tobytes()).decode("ascii"))
     if shape is None:
-        shape = (len(ids), block_size, 0, 0)
+        shape = (len(ids), block_size, 0)
         dtype = np.dtype("float32")
     payload = {
         "kind": "kv_shipment",
@@ -116,7 +117,7 @@ def unpack_blocks(payload: dict) -> KVShipment:
             f"kv_shipment version {payload.get('version')!r}, "
             f"expected {WIRE_VERSION}")
     shape = tuple(int(d) for d in payload["shape"])
-    if len(shape) != 4:
+    if len(shape) != 3:
         raise ValueError(f"bad shipment shape {shape}")
     dtype = _resolve_dtype(str(payload["dtype"]))
     hashes = [str(h) for h in payload["chain_hashes"]]
@@ -149,7 +150,7 @@ def payload_bytes(payload: dict) -> int:
     """Raw KV bytes carried by a packed shipment (excludes base64 and
     JSON overhead): n_layers * 2 pools * prod(shape) * itemsize."""
     shape = [int(d) for d in payload.get("shape", ())]
-    if len(shape) != 4:
+    if len(shape) != 3:
         return 0
     dtype = _resolve_dtype(str(payload.get("dtype", "float32")))
     per_pool = int(np.prod(shape)) * dtype.itemsize

@@ -434,8 +434,8 @@ class IterationRecord:
     __slots__ = ("t_start", "t_end", "prefill_rows", "prefill_tokens",
                  "decode_rows", "tokens_emitted", "queue_depth",
                  "active_slots", "kv_blocks_held", "kv_tokens_resident",
-                 "kv_blocks_total", "slots", "block_size", "host_s",
-                 "_open")
+                 "kv_blocks_total", "kv_pages_read", "kv_pages_table",
+                 "slots", "block_size", "host_s", "_open")
 
     def __init__(self, slots, block_size, kv_blocks_total):
         self.t_start = time.perf_counter()
@@ -445,6 +445,10 @@ class IterationRecord:
         self.queue_depth = self.active_slots = 0
         self.kv_blocks_held = self.kv_tokens_resident = 0
         self.kv_blocks_total = kv_blocks_total
+        # over the paged steps the turn ran: the pages that the fed
+        # rows' lengths cover (what paged_attention reads), and rows x
+        # table width (what reading every table entry would take)
+        self.kv_pages_read = self.kv_pages_table = 0
         self.slots = slots
         self.block_size = block_size
         self.host_s: Dict[str, float] = {}

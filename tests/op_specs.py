@@ -461,8 +461,10 @@ for _op in ["save", "save_combine", "load", "load_combine"]:
     skip(_op, "host IO op; covered by tests/test_models.py save/load and "
               "test_jit_and_extras.py")
 skip("paged_attention", "stateful decode op over externally-allocated "
-     "KV block pools + block table; token-exact parity vs the slab "
-     "path is covered in tests/test_generation.py and the allocator in "
+     "KV block pools + block table; the op itself (Pallas read against "
+     "a gather reference, scatter write) is covered in "
+     "tests/test_paged_attention_op.py, token-exact parity vs the slab "
+     "path in tests/test_generation.py and the allocator in "
      "tests/test_kv_blocks.py")
 skip("print", "host-side debug print (io_callback); side-effect only")
 skip("py_func", "wraps arbitrary user Python; covered in "

@@ -183,6 +183,57 @@ def test_ptv021_dtype_mismatch():
         and "int32" in hits[0].message
 
 
+def _counting_eval(monkeypatch):
+    from paddle_tpu.analysis import shape_infer
+    calls = []
+    inner = shape_infer._eval_op
+
+    def counted(op, in_specs):
+        calls.append(op.type)
+        return inner(op, in_specs)
+
+    monkeypatch.setattr(shape_infer, "_eval_op", counted)
+    return calls
+
+
+def test_shape_inference_evaluates_a_repeated_op_once(monkeypatch):
+    """Ops of one program that differ in their variables' names only
+    (a model's layers) are abstract-evaluated once; every one of them
+    is still checked against its own declaration."""
+    calls = _counting_eval(monkeypatch)
+    prog = _raw_program(
+        [("a", dict(is_data=True, **_F32_23)),
+         ("b", dict(**_F32_23)), ("c", dict(**_F32_23)),
+         ("d", dict(shape=[9, 9], dtype="float32")),
+         ("e", dict(shape=[2, 3], dtype="float32"))],
+        [("relu", {"X": ["a"]}, {"Out": ["b"]}, {}),
+         ("relu", {"X": ["b"]}, {"Out": ["c"]}, {}),
+         ("relu", {"X": ["c"]}, {"Out": ["d"]}, {}),       # PTV020
+         ("scale", {"X": ["c"]}, {"Out": ["e"]}, {"scale": 2.0}),
+         ("scale", {"X": ["e"]}, {"Out": ["e"]}, {"scale": 3.0})])
+    res = verify_program(prog)
+    assert calls == ["relu", "scale", "scale"]   # another attr: again
+    hits = [d for d in res.findings if d.rule == "PTV020"]
+    assert len(hits) == 1 and hits[0].var == "d" and hits[0].op_idx == 2
+
+
+@pytest.mark.parametrize("attrs,evaluated", [
+    ({"scale": 2.0}, 1), ({"scale": 2.0, "bias": np.float32(1.0)}, 1),
+    ({"scale": 2.0, "note": np.zeros(3)}, 2)],
+    ids=["plain", "numpy_scalar", "array_attr_is_not_memoised"])
+def test_shape_inference_memo_key_takes_plain_attrs_only(
+        monkeypatch, attrs, evaluated):
+    calls = _counting_eval(monkeypatch)
+    prog = _raw_program(
+        [("a", dict(is_data=True, **_F32_23)),
+         ("b", dict(**_F32_23)), ("c", dict(**_F32_23))],
+        [("scale", {"X": ["a"]}, {"Out": ["b"]}, dict(attrs)),
+         ("scale", {"X": ["b"]}, {"Out": ["c"]}, dict(attrs))])
+    res = verify_program(prog)
+    assert not [d for d in res.findings if d.rule.startswith("PTV02")]
+    assert len(calls) == evaluated
+
+
 def test_ptv022_abstract_eval_failure():
     opdef = REGISTRY.get("relu")
     assert opdef.abstract_eval is None

@@ -84,10 +84,10 @@ class _FakeScope:
         return self._pools[name]
 
 
-def _fake_pools(dtype, n_blocks=6, h=2, hd=3):
+def _fake_pools(dtype, n_blocks=6, lanes=6):
     rng = np.random.RandomState(0)
     names = ["k0", "v0", "k1", "v1"]
-    pools = {n: rng.randn(n_blocks, BLOCK, h, hd).astype(dtype)
+    pools = {n: rng.randn(n_blocks, BLOCK, lanes).astype(dtype)
              for n in names}
     return _FakeScope(pools), names, pools
 
@@ -98,9 +98,9 @@ def test_kv_wire_roundtrip_fp32_byte_exact():
     payload = pack_blocks(scope, names, ids, hashes, BLOCK)
     assert payload["kind"] == "kv_shipment"
     assert payload["n_blocks"] == 2 and payload["n_tokens"] == 2 * BLOCK
-    assert payload["shape"] == [2, BLOCK, 2, 3]
+    assert payload["shape"] == [2, BLOCK, 6]
     # raw-bytes accounting: 2 pools/layer x 2 layers x rows x fp32
-    assert payload_bytes(payload) == 2 * 2 * (2 * BLOCK * 2 * 3) * 4
+    assert payload_bytes(payload) == 2 * 2 * (2 * BLOCK * 6) * 4
 
     ship = unpack_blocks(payload)
     assert ship.chain_hashes == hashes

@@ -351,6 +351,7 @@ def test_paged_pool_decouples_planner_kv_from_slots(trained):
     program pins max_slots x max_seq — the planner-visibility
     acceptance of the paged subsystem."""
     from paddle_tpu.analysis import analyze_program_memory
+    from paddle_tpu.ops.pallas.paged_attention import pool_lanes
     cfg, _, _ = trained
     block_size, num_blocks, slots = 4, 5, 4
 
@@ -368,11 +369,14 @@ def test_paged_pool_decouples_planner_kv_from_slots(trained):
     assert kv_paged["layout"] == "paged"
     assert kv_slab["layout"] == "slab"
     elem = 2 * cfg.n_layers * cfg.d_model * 4        # K+V, fp32
-    assert kv_paged["kv_bytes"] == num_blocks * block_size * elem
+    # a pool's token takes whole lane tiles (ops/pallas/paged_attention)
+    lanes = pool_lanes(cfg.d_model) // cfg.d_model
+    assert kv_paged["kv_bytes"] == num_blocks * block_size * elem * lanes
     assert kv_slab["kv_bytes"] == slots * SEQ * elem
-    # the tight pool above is smaller than the slab bound — the whole
-    # point: pool size is budget-derived, not slots x max_seq
-    assert kv_paged["kv_bytes"] < kv_slab["kv_bytes"]
+    # the tight pool above holds fewer tokens than the slab bound — the
+    # whole point: pool size is budget-derived, not slots x max_seq
+    # (token for token: this toy width is a quarter of a lane tile)
+    assert kv_paged["kv_bytes"] // lanes < kv_slab["kv_bytes"]
 
 
 # ---------------------------------------------------------------------------
@@ -461,6 +465,55 @@ def test_paged_engine_leaves_one_record_an_iteration(trained):
     assert occ["count"] == sum(1 for r in recs if r["decode_rows"])
     assert snap["gauges"]["serving.gen_active_slots"] == 0
     assert snap["gauges"]["serving.gen_kv_blocks_free"] <= total
+
+
+def test_iteration_record_counts_the_pages_the_fed_rows_hold(trained):
+    """`kv_pages_read` / `kv_pages_table` of a turn are what the
+    `start` / `n_valid` arrays of its step calls imply: a row reads
+    the pages its length covers, a muted row none; the table has
+    slots x max_blocks entries a step."""
+    import time
+
+    from paddle_tpu.serving.kv_blocks import blocks_for_tokens
+    cfg, scope, exe = trained
+    prompts = [([0, 1, 2, 3, 4, 5, 6, 7, 0], 3), ([5, 6], 5), ([7], 6)]
+    eng = GenerationEngine(cfg, scope, exe=fluid.Executor(),
+                           max_slots=2, max_seq=SEQ, block_size=4)
+    eng.start()
+    calls = []
+    inner = eng._run_paged
+
+    def tap(prog, step, tokens, table, start, nvalid):
+        calls.append((time.perf_counter(), table.shape, start.copy(),
+                      nvalid.copy()))
+        return inner(prog, step, tokens, table, start, nvalid)
+
+    eng._run_paged = tap
+    t0 = time.perf_counter()
+    try:
+        for r in [eng.submit(GenerationRequest(p, n)) for p, n in prompts]:
+            r.result(timeout=60.0)
+    finally:
+        eng.stop()
+    recs = _records_since(t0)
+    assert recs and calls
+    max_blocks = eng.step.max_blocks_per_slot
+    seen = 0
+    for r in recs:
+        mine = [c for c in calls if r["t_start"] <= c[0] <= r["t_end"]]
+        seen += len(mine)
+        assert len(mine) == (r["prefill_rows"] > 0) + (r["decode_rows"] > 0)
+        assert r["kv_pages_table"] == len(mine) * 2 * max_blocks
+        assert all(shape == (2, max_blocks) for _, shape, _, _ in mine)
+        assert r["kv_pages_read"] == sum(
+            blocks_for_tokens(int(s) + int(n), 4)
+            for _, _, start, nvalid in mine
+            for s, n in zip(start, nvalid) if n)
+        assert 0 < r["kv_pages_read"] <= r["kv_pages_table"]
+    assert seen == len(calls)
+    # the rows of this run hold far less than their tables name
+    assert sum(r["kv_pages_read"] for r in recs) < \
+        sum(r["kv_pages_table"] for r in recs) // 2
 
 
 def test_slab_engine_records_its_three_regions(trained):

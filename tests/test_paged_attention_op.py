@@ -1,0 +1,239 @@
+"""The paged_attention op: its Pallas read (ops/pallas/paged_attention.py,
+interpreted here as on every CPU run) against a plain gather-and-softmax
+over the whole table, and its write against a plain scatter.
+
+The reference is the formulation the op itself used until PR 26: gather
+every table entry, mask by position, one softmax over max_seq. The
+kernel must agree with it wherever the reference is defined, and must
+not touch what a row does not hold: table entries past a row's pages
+name pool blocks poisoned with NaN here.
+"""
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from paddle_tpu.ops import attention
+from paddle_tpu.ops.pallas import paged_attention as kernel
+
+BS, H, HD, MB = 4, 4, 8, 6          # the tests' toy page; max_seq 24
+MAX_SEQ = BS * MB
+SPEC_K = 2
+B = 4
+
+
+def reference_read(q, pool_k, pool_v, table, start, sm_scale):
+    """jnp.take of every table entry, the [B, T, max_t] mask, one
+    softmax: what the op did before the kernel."""
+    nb, bs, lanes = pool_k.shape
+    b, h, t, hd = q.shape
+    max_t = table.shape[1] * bs
+
+    def history(pool):
+        g = jnp.take(pool[..., :h * hd], table, axis=0)
+        return g.reshape(b, max_t, h, hd).transpose(0, 2, 1, 3)
+
+    keys, vals = history(pool_k), history(pool_v)
+    qpos = start[:, None] + jnp.arange(t)[None, :]
+    scores = jnp.einsum("bhtd,bhsd->bhts", q, keys,
+                        precision="highest") * sm_scale
+    keep = jnp.arange(max_t)[None, None, :] <= qpos[:, :, None]
+    scores = jnp.where(keep[:, None], scores, -1e30)
+    probs = jax.nn.softmax(scores, axis=-1)
+    return jnp.einsum("bhts,bhsd->bhtd", probs, vals, precision="highest")
+
+
+def reference_write(pool, new, table, start, nvalid):
+    """Token t of row b goes to block table[b, pos // bs], offset
+    pos % bs; nothing else changes (block 0 takes the invalid ones)."""
+    pool = np.array(pool)
+    b, h, t, hd = new.shape
+    bs = pool.shape[1]
+    for i in range(b):
+        for j in range(int(nvalid[i])):
+            pos = int(start[i]) + j
+            pool[table[i, pos // bs], pos % bs, :h * hd] = \
+                np.asarray(new[i, :, j, :]).reshape(-1)
+    return pool
+
+
+def run_op(q, k, v, pool_k, pool_v, table, start, nvalid):
+    out = attention._paged_attention_op(
+        None,
+        {"Q": [q], "K": [k], "V": [v], "CacheK": [pool_k],
+         "CacheV": [pool_v], "BlockTable": [jnp.asarray(table)],
+         "StartPos": [jnp.asarray(start)], "NValid": [jnp.asarray(nvalid)]},
+        {"sm_scale": float(q.shape[-1]) ** -0.5})
+    return out["Out"][0], out["CacheKOut"][0], out["CacheVOut"][0]
+
+
+class Batch:
+    """Pools whose blocks 1..nb-2 hold finite numbers and whose last
+    block is NaN; every table entry past a row's pages names the NaN
+    block. `rows` is [(start, nvalid)]; `share` lets two rows name one
+    physical block as their first (a prefix both hold)."""
+
+    def __init__(self, t, rows, bs=BS, h=H, hd=HD, mb=MB, share=None,
+                 seed=0):
+        rng = np.random.default_rng(seed)
+        b = len(rows)
+        lanes = kernel.pool_lanes(h * hd)
+        self.nb = nb = b * mb + 2
+        self.poison = nb - 1
+        pools = rng.normal(size=(2, nb, bs, lanes)).astype(np.float32)
+        pools[..., h * hd:] = 0.0
+        pools[:, self.poison] = np.nan
+        self.pool_k, self.pool_v = jnp.asarray(pools[0]), jnp.asarray(pools[1])
+        self.q, self.k, self.v = (
+            jnp.asarray(rng.normal(size=(b, h, t, hd)).astype(np.float32))
+            for _ in range(3))
+        self.start = np.array([s for s, _ in rows], np.int32)
+        self.nvalid = np.array([n for _, n in rows], np.int32)
+        self.table = np.full((b, mb), self.poison, np.int32)
+        free = list(rng.permutation(np.arange(1, nb - 1)))
+        for i, (s, n) in enumerate(rows):
+            pages = -(-(s + n) // bs) if n else 0
+            self.table[i, :pages] = [free.pop() for _ in range(pages)]
+        if share is not None:
+            i, j = share
+            self.table[j, 0] = self.table[i, 0]
+
+    def check(self):
+        out, ck, cv = run_op(self.q, self.k, self.v, self.pool_k,
+                             self.pool_v, self.table, self.start,
+                             self.nvalid)
+        out = np.asarray(out)
+        assert np.isfinite(out).all()     # don't-care rows included
+        want_k = reference_write(self.pool_k, self.k, self.table,
+                                 self.start, self.nvalid)
+        want_v = reference_write(self.pool_v, self.v, self.table,
+                                 self.start, self.nvalid)
+        # bit for bit (NaN == NaN here); block 0 is the scratch block
+        np.testing.assert_array_equal(np.asarray(ck)[1:], want_k[1:])
+        np.testing.assert_array_equal(np.asarray(cv)[1:], want_v[1:])
+        # the reference gathers every entry: give it a table that names
+        # the scratch block where the kernel must not look, and pools
+        # without the poison
+        clean = np.where(self.table == self.poison, 0, self.table)
+        ref = np.asarray(reference_read(
+            self.q, jnp.nan_to_num(jnp.asarray(want_k)),
+            jnp.nan_to_num(jnp.asarray(want_v)), jnp.asarray(clean),
+            jnp.asarray(self.start), float(self.q.shape[-1]) ** -0.5))
+        for i, n in enumerate(self.nvalid):
+            if n == 0:
+                assert (out[i] == 0.0).all()   # exact zeros, never NaN
+            else:
+                np.testing.assert_allclose(out[i, :, :n], ref[i, :, :n],
+                                           rtol=2e-5, atol=2e-6)
+        return out
+
+
+def rows_for(kind, t):
+    """Four rows; row 0 is the one the case is named for."""
+    other = [(0, 0), (5, min(t, 2)), (BS, t)]
+    return {
+        "muted": [(7, 0)] + other,
+        "ends_mid_page": [(BS + 1, t)] + other,       # length bs + 1 + t
+        "ends_on_page_boundary": [(2 * BS - t, t)] + other,
+        "ends_at_max_seq": [(MAX_SEQ - t, t)] + other,
+        "start_pos_0": [(0, t)] + other,
+        "one_valid_token": [(9, 1)] + other,
+        "shares_a_block": [(BS, t), (0, 0), (5, min(t, 2)), (2 * BS, t)],
+    }[kind]
+
+
+KINDS = ["muted", "ends_mid_page", "ends_on_page_boundary",
+         "ends_at_max_seq", "start_pos_0", "one_valid_token",
+         "shares_a_block"]
+
+
+@pytest.mark.parametrize("kind", KINDS)
+@pytest.mark.parametrize("t", [1, BS, SPEC_K + 1],
+                         ids=["decode", "chunk_prefill", "spec_verify"])
+def test_kernel_matches_gather_reference(t, kind):
+    share = (0, 3) if kind == "shares_a_block" else None
+    Batch(t, rows_for(kind, t), share=share,
+          seed=KINDS.index(kind)).check()
+
+
+def test_all_rows_muted_return_zeros():
+    out = Batch(BS, [(0, 0)] * B).check()
+    assert (out == 0.0).all()
+
+
+def test_long_row_walks_several_chunks():
+    """More pages than the kernel folds at a time, and a last chunk it
+    holds only partly."""
+    mb = 2 * kernel.PAGES + 3
+    length = BS * (2 * kernel.PAGES + 1) + 2
+    Batch(1, [(length - 1, 1), (0, 0), (3, 1), (BS * kernel.PAGES - 1, 1)],
+          mb=mb).check()
+
+
+def test_cell_page_shape():
+    """The serving cells' page: 16 tokens of 16 heads x 64."""
+    Batch(1, [(36, 1), (0, 0)], bs=16, h=16, hd=64, mb=4).check()
+    Batch(16, [(32, 16), (16, 5)], bs=16, h=16, hd=64, mb=4).check()
+
+
+def test_pool_lanes_are_whole_tiles():
+    assert kernel.pool_lanes(32) == 128
+    assert kernel.pool_lanes(1024) == 1024
+    assert kernel.pool_lanes(768) == 768
+    x = jnp.ones((2, 3, 5))
+    assert kernel.pad_lanes(x, 5) is x
+    padded = kernel.pad_lanes(x, 8)
+    assert padded.shape == (2, 3, 8) and float(padded[..., 5:].sum()) == 0.0
+
+
+# -- the kernel, compiled for the chip (no chip needed) ---------------------
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """A described v5e, as the on-chip-measurement guide sets out: only
+    inside a fixture, so that every worker collects the same tests."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("t,h,hd,slots,mb", [
+    (1, 16, 64, 32, 64), (16, 16, 64, 32, 64), (9, 16, 64, 32, 64),
+    (1, 4, 8, 8, 8), (16, 4, 8, 8, 8)],
+    ids=["cell_decode", "cell_chunk_prefill", "cell_spec_verify_k8",
+         "chip_smoke_decode", "chip_smoke_chunk_prefill"])
+def test_kernel_compiles_for_v5e(one_chip, monkeypatch, t, h, hd, slots, mb):
+    """Mosaic takes the kernel at the sizes the repo runs on the chip,
+    and the pools reach it with no copy (what a [.., H, hd] pool cost:
+    PERF.md section 6, PR 26)."""
+    monkeypatch.setattr(kernel, "_interpret", lambda: False)
+    bs, lanes = 16, kernel.pool_lanes(h * hd)
+    nb = slots * mb + 1
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    read = jax.jit(lambda *a: kernel.paged_attention_read.__wrapped__(
+        *a, sm_scale=hd ** -0.5))
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        text = read.lower(
+            arg((slots, h, t, hd), jnp.float32),
+            arg((nb, bs, lanes), jnp.float32),
+            arg((nb, bs, lanes), jnp.float32),
+            arg((slots, mb), jnp.int32), arg((slots,), jnp.int32),
+            arg((slots,), jnp.int32)).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", prev)
+    assert "tpu_custom_call" in text
+    pool = f"f32[{nb},{bs},{lanes}]"
+    copies = [ln for ln in text.splitlines()
+              if f"= {pool}" in ln and " copy(" in ln]
+    assert not copies, copies

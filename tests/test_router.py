@@ -656,7 +656,15 @@ def test_traceparent_crosses_router_to_replica_hop(model_dir):
             code, body, hdrs = _post(srv.url + "/v1/predict",
                                      {"inputs": {"x": xb.tolist()}})
             assert code == 200, body
-            spans = trace.drain_spans()
+            # the router's handler closes its root span after it has
+            # written the response: the client can be back here first
+            spans, deadline = [], time.monotonic() + 10.0
+            while time.monotonic() < deadline:
+                spans += trace.drain_spans()
+                if any(s["name"] == "http.request"
+                       and s["parent_id"] is None for s in spans):
+                    break
+                time.sleep(0.01)
         finally:
             if srv is not None:
                 srv.close()
