@@ -92,6 +92,7 @@ def main(argv=None):
         return 0
 
     from benchmark import run, serve_window, weights
+    from paddle_tpu import trace
     sizes = family.sizes(cfg)
     cell = family.build(cfg, mix, chips, seeds[0])
     cell.warm()
@@ -100,20 +101,26 @@ def main(argv=None):
         for k, rate in enumerate(rates):
             # a seed of its own for every rate: the same token ids twice
             # would be served from the prefix cache
-            swept = {**mix, "rate_per_s": rate}
+            swept = {**mix, "rate_per_s": rate,
+                     "requests": max(int(mix["requests"]),
+                                     int(2 * rate * args.seconds))}
             requests = serve_window.make_requests(
                 swept, seeds[0] + k, sizes["vocab_size"], args.seconds)
-            t0, sent = serve_window.run(cell, swept, requests, args.seconds)
+            t0, sent, _ = serve_window.run(cell, swept, requests,
+                                           args.seconds)
             ttft = serve_window.ttft_ms(t0, sent)
-            third = max(1, len(sent) // 3)
             t_last = max(r.stamps[-1] for r in sent if r.stamps)
+            live = [r["active_slots"] for r in trace.iteration_records()
+                    if t0 <= r["t_end"] <= t0 + args.seconds]
             say(rate=rate, sent=len(sent),
                 ttft_p50_ms=serve_window.percentile(ttft, 50),
                 ttft_p90_ms=serve_window.percentile(ttft, 90),
-                ttft_first_third_mean_ms=sum(ttft[:third]) / third,
-                ttft_last_third_mean_ms=sum(ttft[-third:]) / third,
+                **serve_window.ttft_thirds(ttft),
                 gap_p95_ms=serve_window.percentile(
                     serve_window.gaps_ms(sent), 95),
+                active_slots_mean=sum(live) / max(1, len(live)),
+                iterations=len(live),
+                late_p95_ms=serve_window.late_ms(t0, sent, 95),
                 drained_after_s=t_last - t0 - args.seconds,
                 failed=sum(r.failed() for r in sent))
             while cell.load():
@@ -126,7 +133,7 @@ def main(argv=None):
                 cell.scope.set(name, arr)
             requests = serve_window.make_requests(
                 mix, seed, sizes["vocab_size"], args.seconds)
-            _, sent = serve_window.run(cell, mix, requests, args.seconds)
+            _, sent, _ = serve_window.run(cell, mix, requests, args.seconds)
             # wait for the sample as a run does for its answers, then
             # empty the engine for the next seed
             want = min(int(mix["check_requests"]),

@@ -281,7 +281,8 @@ def drive_serve(args, family, cfg, mix, chips, limits, meter, t, devices):
     phase("setup", T_PROCESS, **setup, compile_s=round(compile_s, 3))
 
     try:
-        t0, sent = serve_window.run(cell, mix, requests, args.seconds, log)
+        t0, sent, t_end = serve_window.run(cell, mix, requests, args.seconds,
+                                           log)
     except BaseException:
         cell.stop()
         raise
@@ -289,21 +290,30 @@ def drive_serve(args, family, cfg, mix, chips, limits, meter, t, devices):
         if log is not None:
             log.close()
     compiles = cell.post_warmup_compiles()
-    t = phase("window", t, sent=len(sent), post_warmup_compiles=compiles,
-              longest_pause_ms=serve_window.longest_pause_ms(sent))
     metrics = {"setup_s": setup_s}
     failed = sum(1 for r in sent if r.failed())
     if mix["arrival"] == "backlog":
-        t_end, stamps = serve_window.window_end(t0, args.seconds, sent)
-        n_tok = int(np.searchsorted(stamps, t_end, side="right"))
-        metrics["serve_out_tok_s"] = n_tok / (t_end - t0)
         window_s = t_end - t0
+        metrics["serve_out_tok_s"] = \
+            serve_window.tokens_until(t_end, sent) / window_s
+        said = {}
+        if 2 * len(sent) > len(requests):
+            said["warning"] = "over half of the backlog's trace was sent: " \
+                              "raise `requests` in the mix"
+            print(f"warning: {said['warning']}", file=sys.stderr)
     else:
-        metrics["ttft_p90_ms"] = serve_window.percentile(
-            serve_window.ttft_ms(t0, sent), 90)
+        ttft = serve_window.ttft_ms(t0, sent)
+        metrics["ttft_p90_ms"] = serve_window.percentile(ttft, 90)
         metrics["gap_p95_ms"] = serve_window.percentile(
             serve_window.gaps_ms(sent), 95)
         window_s = max(r.stamps[-1] for r in sent if r.stamps) - t0
+        # whether the queue grew through the window (the knee's test),
+        # and how late the generator ran
+        said = {**serve_window.ttft_thirds(ttft),
+                "late_p95_ms": serve_window.late_ms(t0, sent, 95)}
+    t = phase("window", t, sent=len(sent), requests=int(mix["requests"]),
+              window_s=window_s, post_warmup_compiles=compiles,
+              longest_pause_ms=serve_window.longest_pause_ms(sent), **said)
     picked = serve_window.check_sample(mix, args.seed, requests)
     cell.stop()
     peak = finish(cell, meter, devices)

@@ -10,27 +10,24 @@ import numpy as np
 
 from benchmark import traffic
 
-BURST_GAP_S = 0.02   # tokens of one iteration arrive within this
+GRACE_S = 5.0   # past `seconds` with no iteration boundary: an error
 
 
 class Request:
     __slots__ = ("index", "due", "prompt", "n_out", "sent", "stamps",
-                 "tokens", "response", "latest", "logits")
+                 "tokens", "response", "logits")
 
-    def __init__(self, index, due, prompt, n_out, latest):
+    def __init__(self, index, due, prompt, n_out):
         self.index, self.due, self.prompt, self.n_out = \
             index, due, prompt, n_out
         self.sent = None
         self.stamps, self.tokens = [], []
         self.response = None
-        self.latest = latest     # one cell shared by the run's requests
         self.logits = None       # a list where `correct` may sample it
 
     def on_token(self, tok):
-        now = time.perf_counter()
-        self.stamps.append(now)
+        self.stamps.append(time.perf_counter())
         self.tokens.append(int(tok))
-        self.latest[0] = now
 
     def finished(self):
         return self.response is not None and self.response.done() \
@@ -91,11 +88,9 @@ def make_requests(mix, seed, vocab_size, seconds):
         if len(trace) == int(mix["requests"]):
             raise RuntimeError("the trace is shorter than the window: "
                                "raise `requests` in the mix")
-    latest = [0.0]
     requests = [Request(i, due,
                         traffic.prompt_tokens(seed, i, p,
-                                              vocab_size).tolist(),
-                        o, latest)
+                                              vocab_size).tolist(), o)
                 for i, (due, p, o) in enumerate(trace)]
     for r in check_candidates(mix, seed, requests):
         r.logits = []
@@ -127,12 +122,31 @@ def check_sample(mix, seed, requests):
     return done[:int(mix["check_requests"])]
 
 
-def run(cell, mix, requests, seconds, log=None):
+def iteration_ends():
+    """When each turn of the engine's loop that ran a step ended, on
+    `perf_counter`'s clock (the stamps' clock), oldest first: the
+    program's own iteration records, a ring of `trace.ITERATION_RING`
+    in the process. Only the ring's tail is ever asked for here."""
+    from paddle_tpu import trace
+    return [r["t_end"] for r in trace.iteration_records()]
+
+
+def window_end(t0, seconds, ends):
+    """The first iteration boundary past `seconds`: the `t_end` of the
+    first of the engine's records that ends after `t0 + seconds`; None
+    while no iteration has ended there yet."""
+    return next((float(e) for e in ends if e > t0 + seconds), None)
+
+
+def run(cell, mix, requests, seconds, log=None, ends=iteration_ends):
     """Offer the trace for `seconds`. Open loop: send each request when
     it is due, then keep the engine stepping until every request sent
     has its first token or has failed. Backlog: keep `queue_depth`
-    requests queued beyond the slots until an iteration has ended past
-    `seconds`. Returns the window's start on the host's clock."""
+    requests queued beyond the slots until the first iteration has
+    ended past `seconds` (`ends()` says when iterations ended); one
+    that has not ended `GRACE_S` later is an error. Returns the
+    window's start on the host's clock, the requests sent and, for a
+    backlog, the boundary the window closed on."""
     backlog = mix["arrival"] == "backlog"
     t0 = time.perf_counter()
     if log is not None:
@@ -153,11 +167,14 @@ def run(cell, mix, requests, seconds, log=None):
                         "`requests` in the mix")
                 send(requests[sent])
                 sent += 1
-            now = time.perf_counter()
-            last = requests[0].latest[0]
-            if last - t0 > seconds and (now - last > 2 * BURST_GAP_S
-                                        or now - t0 > seconds + 5.0):
-                break
+            if time.perf_counter() - t0 > seconds:
+                t_end = window_end(t0, seconds, ends())
+                if t_end is not None:
+                    return t0, requests[:sent], t_end
+                if time.perf_counter() - t0 > seconds + GRACE_S:
+                    raise RuntimeError(
+                        f"no iteration ended within {GRACE_S} s past the "
+                        "window: the engine stalled or keeps no records")
             time.sleep(0.005)
     else:
         for r in requests:
@@ -171,7 +188,7 @@ def run(cell, mix, requests, seconds, log=None):
             if all(r.tokens or r.failed() for r in requests):
                 break
             time.sleep(0.005)
-    return t0, requests[:sent]
+    return t0, requests[:sent], None
 
 
 # -- what the client saw -----------------------------------------------------
@@ -181,19 +198,10 @@ def _all_stamps(sent):
         [np.asarray(r.stamps) for r in sent if r.stamps] or [np.zeros(0)]))
 
 
-def window_end(t0, seconds, sent):
-    """The first iteration boundary after `seconds`: the last stamp of
-    the first burst of tokens that ends past it."""
-    stamps = _all_stamps(sent)
-    after = stamps[stamps > t0 + seconds]
-    if not len(after):
-        raise RuntimeError("no iteration ended after the window")
-    end = after[0]
-    for s in after[1:]:
-        if s - end > BURST_GAP_S:
-            break
-        end = s
-    return float(end), stamps
+def tokens_until(t_end, sent):
+    """Output tokens stamped up to `t_end`: an iteration stamps its
+    tokens before its record ends."""
+    return int(np.searchsorted(_all_stamps(sent), t_end, side="right"))
 
 
 def longest_pause_ms(sent):
@@ -216,6 +224,23 @@ def ttft_ms(t0, sent):
     every percentile."""
     return [((r.stamps[0] - (t0 + r.due)) * 1e3) if r.stamps
             else float("inf") for r in sent]
+
+
+def ttft_thirds(ttft):
+    """Mean TTFT of the first and of the last third of the requests,
+    in the order they were due: a queue that grows through the window
+    (a rate past the knee) shows as the last over the first."""
+    third = max(1, len(ttft) // 3)
+    return {"ttft_first_third_mean_ms": sum(ttft[:third]) / third,
+            "ttft_last_third_mean_ms": sum(ttft[-third:]) / third}
+
+
+def late_ms(t0, sent, q):
+    """How late the load generator ran: sent minus due, a percentile
+    over every request sent."""
+    late = [(r.sent - (t0 + r.due)) * 1e3 for r in sent
+            if r.sent is not None]
+    return float(np.percentile(late, q)) if late else None
 
 
 def gaps_ms(sent):

@@ -69,8 +69,8 @@ def test_records_with_and_without_the_fields_mix(monkeypatch):
 
 
 @pytest.mark.parametrize("name,moves,cell,args", [
-    ("kv_read_share.batch", "serve_out_tok_s", "gpt2_medium.batch_gen", {}),
-    ("kv_read_share.gap", "gap_p95_ms", "gpt2_medium.long_in_open",
+    ("kv_read_share.batch", "serve_out_tok_s", "gpt2_medium.batch_gen_v2", {}),
+    ("kv_read_share.gap", "gap_p95_ms", "gpt2_medium.long_in_open_v2",
      {"before_slice": True})])
 def test_the_metrics_files_and_entries(name, moves, cell, args):
     listed = {m["name"]: m for m in manifest.benchmark_json()["per_layer"]}
@@ -81,8 +81,8 @@ def test_the_metrics_files_and_entries(name, moves, cell, args):
     assert manifest.metric_file(name) == {"reader": "kv_read_share",
                                           "args": args}
     assert name in {m["name"] for m in manifest.metrics_of(cell, "per_layer")}
-    other = "gpt2_medium.long_in_open" if "batch" in name \
-        else "gpt2_medium.batch_gen"
+    other = "gpt2_medium.long_in_open_v2" if "batch" in name \
+        else "gpt2_medium.batch_gen_v2"
     assert name not in {m["name"]
                         for m in manifest.metrics_of(other, "per_layer")}
     assert name not in {m["name"] for m in manifest.metrics_of(

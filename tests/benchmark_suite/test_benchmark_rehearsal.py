@@ -16,7 +16,8 @@ from benchmark import reference_layers as rl
 from benchmark import run as bench_run
 
 ROOT = manifest.ROOT
-TRAIN, SERVE = "bert_base_nodropout.pretrain", "gpt2_medium.batch_gen"
+TRAIN, SERVE = "bert_base_nodropout.pretrain", "gpt2_medium.batch_gen_v2"
+OPEN = "gpt2_medium.long_in_open_v2"
 
 
 def command(workload, *more):
@@ -40,7 +41,7 @@ def test_without_a_chip_no_result_line():
 
 
 @pytest.mark.parametrize("workload,trace", [(TRAIN, "1"), (SERVE, "0"),
-                                            ("gpt2_medium.long_in_open", "1")])
+                                            (OPEN, "1"), (OPEN, "0")])
 def test_rehearsal_end_to_end(workload, trace):
     p = command(workload, "--rehearsal", "--trace", trace)
     assert p.returncode == 0, p.stderr[-2000:]
@@ -57,6 +58,19 @@ def test_rehearsal_end_to_end(workload, trace):
         assert all(v["value"] > 0 for v in line["metrics"].values())
     for name, row in line["compared"].items():
         assert f"compared {name}: " in p.stderr
+    if workload != TRAIN:
+        # the window's own line: room in the trace, said out loud, and
+        # a backlog closed on the first boundary past --seconds
+        window = next(ph for ph in map(json.loads, p.stdout.splitlines()[:-1])
+                      if ph.get("phase") == "window")
+        assert 0 < window["sent"] <= window["requests"]
+        if workload == SERVE:
+            # a turn or two of a loaded CPU past --seconds, never the
+            # grace of 5 s that only an error may reach
+            assert 1.0 < window["window_s"] < 3.5
+            assert "warning" not in window
+        else:
+            assert window["ttft_last_third_mean_ms"] > 0
 
 
 def tiny(workload):
@@ -122,10 +136,13 @@ def test_controls_come_out_not_correct():
     assert not correct.judge(correct.train_numbers(low, ref)[0],
                              limits(TRAIN))[0]
 
-    # serving: the reference in bfloat16 throughout (and in int8) stands
-    # in the program's place over the same prompts and tokens, and its
-    # logits rows are judged as the program's are
-    cfg, mix = tiny(SERVE)
+
+@pytest.mark.parametrize("workload", [SERVE, OPEN])
+def test_serving_controls_come_out_not_correct(workload):
+    """The reference in bfloat16 throughout (and in int8) stands in the
+    program's place over the same prompts and tokens, and its logits
+    rows are judged as the program's are, by the cell's own limits."""
+    cfg, mix = tiny(workload)
 
     class Served:
         def __init__(self, i):
@@ -135,13 +152,13 @@ def test_controls_come_out_not_correct():
     for prec in (rl.BFLOAT16, rl.INT8):
         low = bench_run.served_numbers(cfg, 9, picked, control=prec)
         ok, rows = correct.judge({**low, "compiles_in_window": 0.0,
-                                  "requests_failed": 0.0}, limits(SERVE))
+                                  "requests_failed": 0.0}, limits(workload))
         assert not ok, (type(prec).__name__, rows)
-        assert low["logit_gap_var"] > limits(SERVE)["logit_gap_var"], rows
+        assert low["logit_gap_var"] > limits(workload)["logit_gap_var"], rows
     # no rows, or not one a token: no number, and not correct
     picked[0].logits = []
     assert bench_run.served_numbers(cfg, 9, picked) == {}
-    assert not correct.judge({}, limits(SERVE))[0]
+    assert not correct.judge({}, limits(workload))[0]
 
 
 def run_in_process(monkeypatch, capsys, workload):

@@ -218,7 +218,7 @@ def test_iteration_record_finds_nothing_to_read(ring, monkeypatch):
 
 
 def sent(first_token, timings):
-    r = serve_window.Request(0, 0.0, [1], 1, [0.0])
+    r = serve_window.Request(0, 0.0, [1], 1)
     r.stamps = [] if first_token is None else [first_token]
     r.response = types.SimpleNamespace(timings=timings)
     return r
