@@ -319,11 +319,17 @@ class PagedDecodeStep:
     K/V pool persistables — the SAME names for the 1-token decode
     program and the block-sized chunked-prefill program, so both
     executables update one physical pool in the shared scope.
+    `state_names` are a second kind of per-slot state, not paged:
+    `[batch, ...]` persistables of recurrent layers, row b slot b's
+    (models/hybrid.py; none here). `probe_var`, where a model has one,
+    is a few int32 that the decode step fetches beside its logits.
     """
 
     def __init__(self, token_var, logits_var, cache_names, table_var,
                  start_var, nvalid_var, batch, max_seq, block_size,
                  num_blocks, seq_tokens, state_prefix):
+        self.state_names = []
+        self.probe_var = None
         self.token_var = token_var
         self.logits_var = logits_var
         self.cache_names = cache_names
@@ -340,6 +346,13 @@ class PagedDecodeStep:
 
     def __iter__(self):
         return iter((self.token_var, self.logits_var, self.cache_names))
+
+    @property
+    def fetch_vars(self):
+        """What one run of the step fetches: the logits (or the health
+        probe), and the model's int32 side-fetch where it has one."""
+        return [self.logits_var] + \
+            ([self.probe_var] if self.probe_var is not None else [])
 
 
 def build_paged_decode_step(cfg, batch, max_seq, block_size, num_blocks,

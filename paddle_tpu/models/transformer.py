@@ -68,6 +68,24 @@ class TransformerConfig:
         self.tp_axis = tp_axis
         self.sp_axis = sp_axis
 
+    # What `serving.GenerationEngine` asks of a model's configuration
+    # (models/hybrid.HybridConfig answers the same three).
+    def build_paged_step(self, **kw):
+        """The paged decode / chunk-prefill program of this model
+        (`gpt.build_paged_decode_step`)."""
+        from . import gpt
+        return gpt.build_paged_decode_step(self, **kw)
+
+    def kv_token_bytes(self):
+        """Bytes a token holds in the paged pools, all layers, K and V:
+        float32, in whole lane tiles (`pool_lanes`)."""
+        from ..ops.pallas.paged_attention import pool_lanes
+        return 2 * self.n_layers * pool_lanes(self.d_model) * 4
+
+    def state_slot_bytes(self):
+        """No recurrent state beside the KV."""
+        return 0
+
 
 def bert_base(**kw):
     return TransformerConfig(**kw)

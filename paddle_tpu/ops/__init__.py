@@ -31,6 +31,7 @@ from . import detection_extra  # noqa: F401
 from . import parity_final  # noqa: F401
 from . import straggler_ops  # noqa: F401
 from . import fused  # noqa: F401
+from . import state_space  # noqa: F401
 
 
 def registered_types():

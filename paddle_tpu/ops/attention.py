@@ -69,15 +69,21 @@ def _paged_attention_op(ctx, ins, attrs):
     One call both WRITES this step's new K/V into the physical pool and
     READS each row's own history back out of it:
 
-      Q/K/V        [B, H, T, hd]   T new tokens per row (decode: T=1,
+      Q            [B, H, T, hd]   T new tokens per row (decode: T=1,
                                    chunked prefill: T=block_size,
                                    spec verify: T=k+1)
+      K/V          [B, KV, T, hd]  KV == H, or grouped: KV divides H and
+                                   KV head g serves query heads
+                                   [g H/KV, (g+1) H/KV)
       CacheK/V     [nb, bs, lanes] the physical pool: a page is bs
-                                   tokens, a token's H*hd numbers side
+                                   tokens, a token's KV*hd numbers side
                                    by side, heads in order, in
-                                   pool_lanes(H*hd) lanes (why the
+                                   pool_lanes(KV*hd) lanes (why the
                                    heads are no dimension of their own:
-                                   models/gpt.build_paged_decode_step)
+                                   models/gpt.build_paged_decode_step),
+                                   float32 or bfloat16: new K/V are
+                                   rounded to the pool's type as they
+                                   are written
       BlockTable   [B, max_blocks] logical block j of row b lives in
                                    physical block BlockTable[b, j]
       StartPos     [B]             position of the row's first new token
@@ -103,6 +109,7 @@ def _paged_attention_op(ctx, ins, attrs):
     nvalid = ins["NValid"][0].astype(jnp.int32)
     nb, bs, d = cache_k.shape
     B, H, T, hd = q.shape
+    KV = k.shape[1]
     sm_scale = attrs.get("sm_scale") or float(hd) ** -0.5
 
     steps = jnp.arange(T, dtype=jnp.int32)
@@ -111,14 +118,17 @@ def _paged_attention_op(ctx, ins, attrs):
     phys = jnp.take_along_axis(table, qpos // bs, axis=1)
     flat_idx = jnp.where(valid, phys * bs + qpos % bs, 0)
 
-    def write(pool, new):                                # new [B,H,T,hd]
+    def write(pool, new):                                # new [B,KV,T,hd]
         flat = pool.reshape(nb * bs, d)
         rows = pad_lanes(
-            new.transpose(0, 2, 1, 3).reshape(B * T, H * hd), d)
-        return flat.at[flat_idx.reshape(-1)].set(rows).reshape(nb, bs, d)
+            new.transpose(0, 2, 1, 3).reshape(B * T, KV * hd), d)
+        return flat.at[flat_idx.reshape(-1)].set(
+            rows.astype(pool.dtype)).reshape(nb, bs, d)
 
     ck_new = write(cache_k, k)
     cv_new = write(cache_v, v)
     out = paged_attention_read(q, ck_new, cv_new, table, start, nvalid,
-                               sm_scale=float(sm_scale))
-    return {"Out": [out], "CacheKOut": [ck_new], "CacheVOut": [cv_new]}
+                               sm_scale=float(sm_scale),
+                               kv_heads=None if KV == H else KV)
+    return {"Out": [out.astype(q.dtype)], "CacheKOut": [ck_new],
+            "CacheVOut": [cv_new]}

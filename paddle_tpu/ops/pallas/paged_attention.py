@@ -51,22 +51,15 @@ def pad_lanes(x, lanes):
         if extra else x
 
 
-def _kernel(table_ref, start_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
-            kbuf, vbuf, sem, qbd_s, qpos_s, m_s, l_s, acc_s, *,
-            sm_scale, n_heads, head_dim, max_blocks):
-    b = pl.program_id(0)
-    _, pages, bs, d = kbuf.shape
-    t = q_ref.shape[1]
-    span = pages * bs
-    length = len_ref[b]
-    start = start_ref[b]
-    n_pages = (length + bs - 1) // bs
-    n_chunks = (n_pages + pages - 1) // pages
+def _page_walk(table_ref, b, n_pages, k_hbm, v_hbm, kbuf, vbuf, sem,
+               max_blocks):
+    """(start_chunk, wait_chunk) over row `b`'s pages: chunk c is pages
+    [c PAGES, (c + 1) PAGES) of the row's table, copied into buffer
+    slot `slot`. A copy is started and waited for under the same guard,
+    and a page past the row's length costs none."""
+    pages = kbuf.shape[1]
 
     def for_held_pages(chunk, slot, act):
-        """`act` on the K and the V copy of each page of the chunk that
-        the row holds: a copy is started and waited for under the same
-        guard, and a page past the row's length costs none."""
         for p in range(pages):
             page = chunk * pages + p
 
@@ -84,6 +77,24 @@ def _kernel(table_ref, start_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
 
     def wait_chunk(chunk, slot):
         for_held_pages(chunk, slot, lambda dma: dma.wait())
+
+    return start_chunk, wait_chunk
+
+
+def _kernel(table_ref, start_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
+            kbuf, vbuf, sem, qbd_s, qpos_s, m_s, l_s, acc_s, *,
+            sm_scale, n_heads, head_dim, max_blocks):
+    b = pl.program_id(0)
+    _, pages, bs, d = kbuf.shape
+    t = q_ref.shape[1]
+    span = pages * bs
+    length = len_ref[b]
+    start = start_ref[b]
+    n_pages = (length + bs - 1) // bs
+    n_chunks = (n_pages + pages - 1) // pages
+
+    start_chunk, wait_chunk = _page_walk(
+        table_ref, b, n_pages, k_hbm, v_hbm, kbuf, vbuf, sem, max_blocks)
 
     @pl.when(length == 0)
     def _muted():
@@ -153,15 +164,132 @@ def _kernel(table_ref, start_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
         o_ref[0] = out
 
 
-@functools.partial(jax.jit, static_argnames=("sm_scale",))
+def _kernel_grouped(table_ref, start_ref, len_ref, q_ref, k_hbm, v_hbm,
+                    o_ref, kbuf, vbuf, sem, m_s, l_s, acc_s, *, sm_scale,
+                    kv_heads, head_dim, tokens, max_blocks):
+    """Grouped KV heads, and a pool of any float type: KV head g's
+    `head_dim` lanes of a page serve the H / KV query heads of group g,
+    whose queries come as the rows (head in group, token) of
+    `q_ref[0, g]`. One product a group and chunk; no block-diagonal
+    zeros. The pool's numbers are widened to float32 as they are read."""
+    b = pl.program_id(0)
+    _, pages, bs, d = kbuf.shape
+    rows = q_ref.shape[2]
+    span = pages * bs
+    length = len_ref[b]
+    start = start_ref[b]
+    n_pages = (length + bs - 1) // bs
+    n_chunks = (n_pages + pages - 1) // pages
+    start_chunk, wait_chunk = _page_walk(
+        table_ref, b, n_pages, k_hbm, v_hbm, kbuf, vbuf, sem, max_blocks)
+
+    @pl.when(length == 0)
+    def _muted():
+        o_ref[0] = jnp.zeros(o_ref.shape[1:], jnp.float32)
+
+    @pl.when(length > 0)
+    def _attend():
+        start_chunk(0, 0)
+        # row r of a group is token r % tokens of one of its heads
+        qpos = start + jax.lax.rem(
+            jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0), tokens)
+        m_s[...] = jnp.full(m_s.shape, NEG_INF, jnp.float32)
+        l_s[...] = jnp.zeros(l_s.shape, jnp.float32)
+        acc_s[...] = jnp.zeros(acc_s.shape, jnp.float32)
+
+        def fold(chunk, carry):
+            slot = chunk % 2
+
+            @pl.when(chunk + 1 < n_chunks)
+            def _next():
+                start_chunk(chunk + 1, 1 - slot)
+
+            wait_chunk(chunk, slot)
+            k = kbuf[slot].reshape(span, d).astype(jnp.float32)
+            v = vbuf[slot].reshape(span, d).astype(jnp.float32)
+            held = chunk * span + jax.lax.broadcasted_iota(
+                jnp.int32, (span, 1), 0) < length
+            v = jnp.where(held, v, 0.0)
+            kpos = chunk * span + jax.lax.broadcasted_iota(
+                jnp.int32, (rows, span), 1)
+            keep = jnp.logical_and(kpos <= qpos, kpos < length)
+            for g in range(kv_heads):
+                lanes = slice(g * head_dim, (g + 1) * head_dim)
+                s = jax.lax.dot_general(
+                    q_ref[0, g], k[:, lanes], (((1,), (1,)), ((), ())),
+                    precision=jax.lax.Precision.HIGHEST,
+                    preferred_element_type=jnp.float32) * sm_scale
+                s = jnp.where(keep, s, NEG_INF)
+                m = m_s[g]
+                m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+                p = jnp.exp(s - m_new)
+                alpha = jnp.exp(m - m_new)
+                l_s[g] = alpha * l_s[g] + jnp.sum(p, axis=1, keepdims=True)
+                acc_s[g] = alpha * acc_s[g] + jax.lax.dot_general(
+                    p, v[:, lanes], (((1,), (0,)), ((), ())),
+                    precision=jax.lax.Precision.HIGHEST,
+                    preferred_element_type=jnp.float32)
+                m_s[g] = m_new
+            return carry
+
+        jax.lax.fori_loop(0, n_chunks, fold, 0)
+        for g in range(kv_heads):
+            o_ref[0, g] = acc_s[g] / l_s[g]
+
+
+def _read_grouped(q, pool_k, pool_v, table, start, length, sm_scale,
+                  kv_heads):
+    B, H, T, hd = q.shape
+    nb, bs, d = pool_k.shape
+    rep = H // kv_heads
+    rows = rep * T
+    q_rows = q.astype(jnp.float32).reshape(B, kv_heads, rows, hd)
+    kernel = functools.partial(
+        _kernel_grouped, sm_scale=float(sm_scale), kv_heads=kv_heads,
+        head_dim=hd, tokens=T, max_blocks=table.shape[1])
+    vmem = functools.partial(pl.BlockSpec, memory_space=pltpu.VMEM)
+    block = vmem((1, kv_heads, rows, hd), lambda b, *_: (b, 0, 0, 0))
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(B,),
+            in_specs=[block,
+                      pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=block,
+            scratch_shapes=[
+                pltpu.VMEM((2, PAGES, bs, d), pool_k.dtype),
+                pltpu.VMEM((2, PAGES, bs, d), pool_v.dtype),
+                pltpu.SemaphoreType.DMA((2, 2, PAGES)),
+                pltpu.VMEM((kv_heads, rows, 1), jnp.float32),
+                pltpu.VMEM((kv_heads, rows, 1), jnp.float32),
+                pltpu.VMEM((kv_heads, rows, hd), jnp.float32),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((B, kv_heads, rows, hd),
+                                       jnp.float32),
+        interpret=_interpret(),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=(pltpu.GridDimensionSemantics.ARBITRARY,)),
+        name="paged_attention_read_grouped",
+    )(table.reshape(-1), start, length, q_rows, pool_k, pool_v)
+    return out.reshape(B, H, T, hd)
+
+
+@functools.partial(jax.jit, static_argnames=("sm_scale", "kv_heads"))
 def paged_attention_read(q, pool_k, pool_v, table, start, nvalid, *,
-                         sm_scale):
+                         sm_scale, kv_heads=None):
     """Attention of `q` [B, H, T, hd] over each row's own history in the
     paged pools [nb, bs, H*hd]: logical block j of row b is physical
     block `table[b, j]`, query t of row b sits at position
     `start[b] + t` and sees the keys at positions <= its own and below
     the row's length `start[b] + nvalid[b]` (`table`, `start`, `nvalid`
-    int32). Rows with `nvalid == 0` return zeros. Returns [B, H, T, hd].
+    int32). Rows with `nvalid == 0` return zeros. Returns [B, H, T, hd]
+    in float32. With `kv_heads` < H the pools are [nb, bs, KV*hd] and
+    KV head g serves query heads [g H/KV, (g+1) H/KV); that case, and a
+    pool that is not float32, take the grouped kernel, chosen here from
+    the static shapes and types: H == KV over float32 pools compiles to
+    the one kernel it always did.
 
     Jitted, so that the layers of one program (same shapes) share one
     trace and one lowering of the kernel."""
@@ -169,6 +297,9 @@ def paged_attention_read(q, pool_k, pool_v, table, start, nvalid, *,
     nb, bs, d = pool_k.shape
     max_blocks = table.shape[1]
     length = jnp.where(nvalid > 0, start + nvalid, 0)
+    if (kv_heads or H) != H or pool_k.dtype != jnp.float32:
+        return _read_grouped(q, pool_k, pool_v, table, start, length,
+                             sm_scale, kv_heads or H)
     q_rows = pad_lanes(q.transpose(0, 2, 1, 3).reshape(B, T, H * hd), d)
     kernel = functools.partial(_kernel, sm_scale=float(sm_scale),
                                n_heads=H, head_dim=hd,

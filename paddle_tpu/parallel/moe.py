@@ -1,18 +1,32 @@
-"""Expert parallelism: a switch-style MoE FFN sharded over the `ep`
+"""Expert parallelism: mixture-of-experts layers sharded over the `ep`
 mesh axis.
 
 Absent from the 2019 reference (its scale story was PS sharding +
 NCCL data parallelism); here expert parallelism is a first-class mesh
 axis alongside dp/tp/pp/sp. Expert weights live sharded over `ep`
-(each device holds E/ep experts); every device computes its local
-experts' contribution for all tokens and a psum over `ep` combines
-them — the dense-dispatch formulation, exact and static-shape. The
-capacity-based sparse all-to-all dispatch is the optimization on top;
-at equal expert count it changes cost, not numerics.
+(each device holds E/ep experts). Three formulations share one router
+(`route_top_k`) and, where tokens are grouped by expert, one evaluation
+of the held experts (`experts_apply`):
 
-Gating is top-1 (Switch Transformer): the selected expert's output is
-scaled by its softmax probability, so the router is trained through
-the prob factor while the hard selection is a stop-gradient mask.
+* `moe_ffn`: dense dispatch. Every device computes its local experts
+  for ALL tokens and a psum over `ep` combines them: exact, static
+  shapes, cost tokens x experts. Top-1 (Switch Transformer): the
+  selected expert's output is scaled by its softmax probability, so the
+  router is trained through the prob factor while the hard selection is
+  a stop-gradient mask.
+* `moe_ffn_sparse`: top-1 with per-expert capacity buffers exchanged by
+  two all-to-alls. Tokens past an expert's capacity are dropped: a
+  trade of THAT formulation for static buffers, not of routed experts
+  as such.
+* `latent_moe`: top-k over a router of the model's full width with
+  sigmoid scores, a selection bias and normalised, scaled weights;
+  experts in a latent space beside a shared expert. The layer is TOLD
+  which experts it holds (`share`), routes over all, sorts the
+  selections that fell on its own experts by expert and evaluates them
+  with grouped products whose cost follows the tokens routed: no
+  capacity, and no token is ever dropped. What the experts of other
+  shares would add is theirs to add: under an `ep` mesh axis a psum
+  sums the shares, on one chip nothing stands in for them.
 """
 from __future__ import annotations
 
@@ -23,7 +37,8 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 __all__ = ["moe_ffn", "moe_ffn_sharded", "moe_ffn_sparse",
-           "moe_ffn_sparse_sharded", "init_moe_params"]
+           "moe_ffn_sparse_sharded", "init_moe_params", "route_top_k",
+           "experts_apply", "latent_moe", "latent_moe_sharded"]
 
 
 def init_moe_params(rng, n_experts, d_model, d_ff, dtype=jnp.float32):
@@ -46,16 +61,59 @@ def init_moe_params(rng, n_experts, d_model, d_ff, dtype=jnp.float32):
     }
 
 
+def route_top_k(x, gate_w, k, score="softmax", select_bias=None,
+                normalise=False, scale=1.0):
+    """The router of every formulation. x [N, d], gate_w [d, E] ->
+    (scores [N, E] float32, selected experts [N, k] int32, their
+    weights [N, k] float32). `score` is softmax or sigmoid over the
+    float32 logits; `select_bias` [E] is added for the SELECTION only
+    and does not weigh; `normalise` divides the selected scores by
+    their sum; `scale` multiplies the weights. The selection is
+    discrete: gradients reach the router through the weights."""
+    logits = jnp.matmul(x, gate_w, preferred_element_type=jnp.float32)
+    scores = jax.nn.sigmoid(logits) if score == "sigmoid" \
+        else jax.nn.softmax(logits, axis=-1)
+    pick = scores if select_bias is None \
+        else scores + select_bias.astype(jnp.float32)
+    _, sel = jax.lax.top_k(jax.lax.stop_gradient(pick), k)
+    w = jnp.take_along_axis(scores, sel, axis=-1)
+    if normalise:
+        w = w / jnp.sum(w, axis=-1, keepdims=True)
+    return scores, sel, w * scale
+
+
+def experts_apply(rows, group_sizes, w1, w2, act, b1=None, b2=None):
+    """The held experts over rows grouped by expert: `rows` [M, d_in]
+    hold expert 0's rows first, then expert 1's, `group_sizes` [E_held]
+    of each; rows past the groups' sum belong to no expert and their
+    result is undefined (the caller masks it). w1 [E_held, d_in, f],
+    w2 [E_held, f, d_out], optional biases [E_held, f] / [E_held,
+    d_out]. Grouped products (`jax.lax.ragged_dot`): what they cost
+    follows the rows, not rows x experts. Returns [M, d_out] float32."""
+    m = rows.shape[0]
+
+    def bias(b):
+        return jnp.repeat(b, group_sizes, axis=0, total_repeat_length=m)
+
+    h = jax.lax.ragged_dot(rows, w1, group_sizes,
+                           preferred_element_type=jnp.float32)
+    if b1 is not None:
+        h = h + bias(b1)
+    h = act(h).astype(rows.dtype)
+    out = jax.lax.ragged_dot(h, w2, group_sizes,
+                             preferred_element_type=jnp.float32)
+    return out if b2 is None else out + bias(b2)
+
+
 def _route_top1(x, gate_w, e_global):
-    """Top-1 switch routing, shared by every formulation: returns
-    (probs [.., E], coef [.., E] = prob on the selected expert under a
-    stop-grad mask, load = mean top-1 prob)."""
-    logits = jnp.einsum("btd,de->bte", x, gate_w)
-    probs = jax.nn.softmax(logits, axis=-1)
-    mask = jax.nn.one_hot(jnp.argmax(probs, -1), e_global,
-                          dtype=probs.dtype)
-    coef = probs * jax.lax.stop_gradient(mask)
-    return probs, coef, jnp.mean(jnp.max(probs, axis=-1))
+    """Top-1 switch routing of the dense formulations: returns
+    (probs [.., E], coef [.., E] = prob on the selected expert, zero
+    elsewhere, load = mean top-1 prob)."""
+    b, t, d = x.shape
+    probs, sel, w = route_top_k(x.reshape(b * t, d), gate_w, 1)
+    coef = jax.nn.one_hot(sel[:, 0], e_global, dtype=probs.dtype) * w
+    return (probs.reshape(b, t, e_global), coef.reshape(b, t, e_global),
+            jnp.mean(w))
 
 
 def _expert_eval_all(x, params):
@@ -159,10 +217,8 @@ def moe_ffn_sparse(x, params, axis_name="ep", capacity=None,
         capacity = max(1, (2 * n + e_global - 1) // e_global)
 
     xt = x.reshape(n, d)
-    logits = xt @ gate_w                                # [N, E]
-    probs = jax.nn.softmax(logits, axis=-1)
-    top = jnp.argmax(probs, axis=-1)                    # [N]
-    coef = jnp.take_along_axis(probs, top[:, None], axis=-1)[:, 0]
+    probs, sel, w = route_top_k(xt, gate_w, 1)
+    top, coef = sel[:, 0], w[:, 0]                      # [N]
 
     onehot = jax.nn.one_hot(top, e_global, dtype=jnp.int32)  # [N, E]
     pos = jnp.cumsum(onehot, axis=0) * onehot - 1       # [N, E]
@@ -183,9 +239,11 @@ def moe_ffn_sparse(x, params, axis_name="ep", capacity=None,
                               concat_axis=2, tiled=True)
     recv = recv.reshape(e_local, n_shards * capacity, d)
 
-    h = jax.nn.gelu(jnp.einsum("ecd,edf->ecf", recv, w1)
-                    + b1[:, None, :])
-    out = jnp.einsum("ecf,efd->ecd", h, w2) + b2[:, None, :]
+    # every buffer whole: padding rows are computed and then unread
+    out = experts_apply(
+        recv.reshape(e_local * n_shards * capacity, d),
+        jnp.full((e_local,), n_shards * capacity, jnp.int32),
+        w1, w2, jax.nn.gelu, b1, b2).astype(x.dtype)
 
     # exchange back: [E_local, ep, C, d] -> [E(=ep*E_local), C, d]
     out = out.reshape(e_local, n_shards, capacity, d)
@@ -207,6 +265,93 @@ def moe_ffn_sparse_sharded(x, params, mesh, ep_axis="ep", capacity=None,
     moe_ffn_sharded)."""
     return _moe_shard_map(moe_ffn_sparse, x, params, mesh, ep_axis,
                           batch_axis, seq_axis=seq_axis, capacity=capacity)
+
+
+# ---------------------------------------------------------------------------
+# latent experts: top-k over the full router, the held share computed
+# ---------------------------------------------------------------------------
+
+def _relu2(h):
+    return jnp.square(jax.nn.relu(h))
+
+
+def latent_moe(x, p, top_k, scale, share=0, n_valid=None, axis_name=None):
+    """x [B, T, d]; p: router_w [d, E], router_bias [E], down [d, L],
+    w1 [E_held, L, f], w2 [E_held, f, L], up [L, d], shared_w1 [d, s],
+    shared_w2 [s, d]. This shard holds experts [share E_held,
+    (share + 1) E_held) of the router's E. `n_valid` [B] leaves tokens
+    t >= n_valid[b] out (routed nowhere, counted nowhere; their output
+    is the shared expert's and is don't-care). Inside shard_map,
+    `axis_name` sums the shares' routed parts.
+
+    Returns (out [B, T, d], probe [4] int32: selections made, those
+    that fell on held experts, held experts with at least one token,
+    tokens on the busiest held expert)."""
+    b, t, d = x.shape
+    n = b * t
+    eh = p["w1"].shape[0]
+    x2 = x.reshape(n, d)
+    _, sel, w = route_top_k(x2, p["router_w"], top_k, score="sigmoid",
+                            select_bias=p["router_bias"], normalise=True,
+                            scale=scale)
+    valid = jnp.ones((n,), bool) if n_valid is None else (
+        jnp.arange(t, dtype=jnp.int32)[None, :]
+        < n_valid.astype(jnp.int32)[:, None]).reshape(n)
+    local = sel - share * eh
+    held = (local >= 0) & (local < eh) & valid[:, None]
+    # selections sorted by held expert; those of other shares go last,
+    # in a group of their own that no product visits
+    eid = jnp.where(held, local, eh).reshape(n * top_k)
+    order = jnp.argsort(eid, stable=True)
+    sizes = jnp.zeros((eh + 1,), jnp.int32).at[eid].add(1)[:eh]
+    lat = jnp.matmul(x2, p["down"],
+                     preferred_element_type=jnp.float32).astype(x.dtype)
+    f = experts_apply(lat[order // top_k], sizes, p["w1"], p["w2"], _relu2)
+    wf = jnp.where(held, w, 0.0).reshape(n * top_k)[order]
+    f = jnp.where((eid[order] < eh)[:, None], f * wf[:, None], 0.0)
+    # back to token order: a gather by the inverse permutation, then
+    # each token's k selections summed
+    back = jnp.zeros((n * top_k,), jnp.int32).at[order].set(
+        jnp.arange(n * top_k, dtype=jnp.int32))
+    routed = f[back].reshape(n, top_k, -1).sum(axis=1)
+    hit = jnp.sum(sizes > 0).astype(jnp.int32)
+    busiest = jnp.max(sizes)
+    n_held = jnp.sum(sizes)
+    if axis_name is not None:
+        routed = jax.lax.psum(routed, axis_name)
+        hit = jax.lax.psum(hit, axis_name)
+        n_held = jax.lax.psum(n_held, axis_name)
+        busiest = jax.lax.pmax(busiest, axis_name)
+    shared = jnp.matmul(
+        _relu2(jnp.matmul(x2, p["shared_w1"],
+                          preferred_element_type=jnp.float32)
+               ).astype(x.dtype),
+        p["shared_w2"], preferred_element_type=jnp.float32)
+    out = jnp.matmul(routed.astype(x.dtype), p["up"],
+                     preferred_element_type=jnp.float32) + shared
+    probe = jnp.stack([jnp.sum(valid).astype(jnp.int32) * top_k,
+                       n_held, hit, busiest])
+    return out.astype(x.dtype).reshape(b, t, d), probe
+
+
+def latent_moe_sharded(x, p, mesh, top_k, scale, ep_axis="ep",
+                       n_valid=None):
+    """Global arrays -> shard_map: w1 / w2 sharded on their expert
+    dimension over `ep_axis`, everything else replicated; shard i is
+    share i, and a psum over the axis sums the shares."""
+    specs = {k: P() for k in p}
+    specs["w1"] = specs["w2"] = P(ep_axis, None, None)
+
+    def inner(x, p, n_valid):
+        return latent_moe(x, p, top_k, scale,
+                          share=jax.lax.axis_index(ep_axis),
+                          n_valid=n_valid, axis_name=ep_axis)
+
+    if n_valid is None:
+        n_valid = jnp.full((x.shape[0],), x.shape[1], jnp.int32)
+    return jax.shard_map(inner, mesh=mesh, in_specs=(P(), specs, P()),
+                         out_specs=(P(), P()), check_vma=False)(
+        x, p, n_valid)
 
 
 # ---------------------------------------------------------------------------
@@ -244,9 +389,34 @@ def _moe_ffn_op(ctx, ins, attrs):
     return {"Out": [y], "Load": [load]}
 
 
+def _latent_moe_op(ctx, ins, attrs):
+    """Program-IR face of `latent_moe`: X [B, T, d], RouterW,
+    RouterBias, Down, W1, W2, Up, SharedW1, SharedW2, optional NValid
+    [B]; attrs top_k, scale, share, ep_axis. Outputs Out and Probe [4]
+    int32. With the `ep` axis in the mesh the experts are sharded over
+    it and the shares summed; otherwise this chip's `share` alone."""
+    p = {"router_w": ins["RouterW"][0], "router_bias": ins["RouterBias"][0],
+         "down": ins["Down"][0], "w1": ins["W1"][0], "w2": ins["W2"][0],
+         "up": ins["Up"][0], "shared_w1": ins["SharedW1"][0],
+         "shared_w2": ins["SharedW2"][0]}
+    n_valid = ins["NValid"][0] if ins.get("NValid") else None
+    top_k, scale = int(attrs["top_k"]), float(attrs.get("scale", 1.0))
+    ep_axis = attrs.get("ep_axis", "ep")
+    if ctx.mesh is not None and ep_axis in ctx.mesh.axis_names:
+        out, probe = latent_moe_sharded(ins["X"][0], p, ctx.mesh, top_k,
+                                        scale, ep_axis, n_valid)
+    else:
+        out, probe = latent_moe(ins["X"][0], p, top_k, scale,
+                                share=int(attrs.get("share", 0)),
+                                n_valid=n_valid)
+    return {"Out": [out], "Probe": [probe]}
+
+
 def _register():
     from ..core.registry import register_op
     register_op("moe_ffn", nondiff_outputs=("Load",))(_moe_ffn_op)
+    register_op("latent_moe", nondiff_inputs=("NValid",),
+                nondiff_outputs=("Probe",))(_latent_moe_op)
 
 
 _register()

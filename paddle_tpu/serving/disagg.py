@@ -46,6 +46,13 @@ def _require_paged(engine):
         raise ValueError(
             "disaggregated KV transfer needs a paged engine "
             "(FLAGS_gen_paged_kv / paged=True)")
+    if getattr(engine, "recurrent", False):
+        raise ValueError(
+            f"disaggregated KV transfer cannot serve "
+            f"{type(engine.cfg).__name__}: a shipped KV block carries "
+            "none of its recurrent layers' state, and a slot that "
+            "adopted it would decode from a state that has not seen "
+            "those tokens")
 
 
 def _full_hashes(engine, prompt: Sequence[int]) -> List[str]:
@@ -101,7 +108,7 @@ def export_prefix(engine, prompt: Sequence[int],
                 prompt[:len(ids) * engine.block_size], engine.block_size)
             payload = kv_wire.pack_blocks(
                 engine.scope, engine.step.cache_names, ids, hashes,
-                engine.block_size)
+                engine.block_size, engine.step.state_names)
         finally:
             for bid in ids:
                 engine._pool.decref(bid)

@@ -260,6 +260,11 @@ class GenerationEngine:
         from ..core.flags import FLAGS
         from ..models import gpt
 
+        # The model's configuration says how it is served: it builds
+        # the paged programs (`build_paged_step`) and prices a token of
+        # KV and a slot of recurrent state (`kv_token_bytes`,
+        # `state_slot_bytes`): models/transformer.TransformerConfig,
+        # models/hybrid.HybridConfig.
         self.cfg = cfg
         self.scope = scope
         self.exe = exe if exe is not None else fluid.Executor()
@@ -288,8 +293,8 @@ class GenerationEngine:
                     else FLAGS.gen_kv_block_size, self.max_seq))
             self.num_blocks = self._resolve_pool_blocks(kv_pool_blocks)
             with fluid.program_guard(self._prog, self._startup):
-                self.step = gpt.build_paged_decode_step(
-                    cfg, batch=self.max_slots, max_seq=self.max_seq,
+                self.step = cfg.build_paged_step(
+                    batch=self.max_slots, max_seq=self.max_seq,
                     block_size=self.block_size,
                     num_blocks=self.num_blocks, seq_tokens=1,
                     state_prefix=state_prefix)
@@ -299,8 +304,8 @@ class GenerationEngine:
             self._prefill_startup = fluid.Program()
             with fluid.program_guard(self._prefill_prog,
                                      self._prefill_startup):
-                self.prefill_step = gpt.build_paged_decode_step(
-                    cfg, batch=self.max_slots, max_seq=self.max_seq,
+                self.prefill_step = cfg.build_paged_step(
+                    batch=self.max_slots, max_seq=self.max_seq,
                     block_size=self.block_size,
                     num_blocks=self.num_blocks,
                     seq_tokens=self.block_size,
@@ -308,6 +313,11 @@ class GenerationEngine:
             self._pool = BlockPool(self.num_blocks, self.block_size)
             self._prefix = PrefixCache(self._pool)
         else:
+            if cfg.state_slot_bytes():
+                raise ValueError(
+                    "a model with recurrent layers is served paged: the "
+                    "slab decode graph carries no per-slot state "
+                    "(paged=True)")
             spec_decode = False  # the slab graph has no verify substrate
             self.block_size = 0
             self.num_blocks = 0
@@ -327,6 +337,16 @@ class GenerationEngine:
         self._spec_prog = None
         self.spec_step = None
         self._drafter = None
+        # Recurrent layers (a model whose step names `state_names`):
+        # their per-slot state moves forward only. A verify step would
+        # advance it through drafts that are then rejected, and nothing
+        # rolls it back; a cached KV block carries none of it.
+        self.recurrent = bool(self.paged and self.step.state_names)
+        if self.recurrent and self.spec_decode and self.spec_k >= 1:
+            raise ValueError(
+                f"speculative decoding (spec_k={self.spec_k}) cannot "
+                f"serve {type(cfg).__name__}: its recurrent layers' "
+                "state cannot be rolled back past a rejected draft")
         if self.spec_decode and self.spec_k >= 1:
             from .spec_decode import NgramDrafter
             self._spec_prog = fluid.Program()
@@ -364,6 +384,8 @@ class GenerationEngine:
         self._worker: Optional[threading.Thread] = None
         self._ready = threading.Event()
         self._warm_misses: Optional[int] = None
+        # the side-fetch of the last paged step, where the model has one
+        self._probe: Optional[np.ndarray] = None
         # resilience: a failed decode step fails the requests that were
         # mid-step (their KV state is unreplayable) but never the
         # worker; repeated failures trip the breaker and submissions
@@ -375,15 +397,19 @@ class GenerationEngine:
 
     # -- paged-pool sizing ----------------------------------------------
     def kv_block_bytes(self) -> int:
-        """HBM bytes one block occupies across every layer's K+V pool
-        (float32 today; the int8 KV leg only changes this number). A
-        token takes whole lane tiles there (`pool_lanes`): d_model
-        itself at a real model's width."""
+        """HBM bytes one block occupies across every layer's K+V pool,
+        as the model's configuration prices a token (`kv_token_bytes`:
+        the layers that attend, their KV heads, the pool's type, in
+        whole lane tiles)."""
         if not self.paged:
             return 0
-        from ..ops.pallas.paged_attention import pool_lanes
-        return 2 * self.cfg.n_layers * self.block_size * \
-            pool_lanes(self.cfg.d_model) * 4
+        return self.block_size * self.cfg.kv_token_bytes()
+
+    def state_bytes(self) -> int:
+        """HBM bytes of per-slot recurrent state, every slot (0 for a
+        model without recurrent layers): pinned beside the pools and
+        priced the same way, as persistables of the programs."""
+        return self.max_slots * self.cfg.state_slot_bytes()
 
     def kv_pool_bytes(self) -> int:
         """Total K/V pool HBM across layers — what the static memory
@@ -408,7 +434,10 @@ class GenerationEngine:
         if FLAGS.gen_kv_pool_blocks > 0:
             n = int(FLAGS.gen_kv_pool_blocks)
         elif FLAGS.gen_kv_pool_bytes > 0:
-            n = int(FLAGS.gen_kv_pool_bytes) // self.kv_block_bytes()
+            # the budget is for all per-slot state: what the recurrent
+            # layers pin comes off it before it is cut into blocks
+            n = max(int(FLAGS.gen_kv_pool_bytes) - self.state_bytes(), 0) \
+                // self.kv_block_bytes()
         else:
             n = self.max_slots * per_slot + 1
         # floor: scratch + one slot's worth, or nothing ever admits
@@ -441,12 +470,6 @@ class GenerationEngine:
                       self.step.active_var.name:
                       np.zeros(B, np.float32)},
                      self.step.logits_var)]
-        cells = [("decode", self._prog, self.step, 1),
-                 ("prefill", self._prefill_prog, self.prefill_step,
-                  self.block_size)]
-        if self.spec_step is not None:
-            cells.append(("spec_verify", self._spec_prog, self.spec_step,
-                          self.spec_k + 1))
         mb = self.step.max_blocks_per_slot
         return [(name, prog,
                  {step.token_var.name: np.zeros((B, t), np.int64),
@@ -454,7 +477,30 @@ class GenerationEngine:
                   step.start_var.name: np.zeros(B, np.int64),
                   step.nvalid_var.name: np.zeros(B, np.int64)},
                  step.logits_var)
-                for name, prog, step, t in cells]
+                for name, prog, step, t in self._paged_cells()]
+
+    def _paged_cells(self):
+        """(name, program, step handle, tokens a row) of the paged
+        engine's executables."""
+        cells = [("decode", self._prog, self.step, 1),
+                 ("prefill", self._prefill_prog, self.prefill_step,
+                  self.block_size)]
+        if self.spec_step is not None:
+            cells.append(("spec_verify", self._spec_prog, self.spec_step,
+                          self.spec_k + 1))
+        return cells
+
+    def fetch_list(self, prog):
+        """What a run of `prog` fetches: the step's logits (or probe
+        row) and, in the same fetch, the few int32 a model with a
+        `probe_var` counts in its decode step (`step.fetch_vars`).
+        `executables()` names the first; a caller that compiles or
+        re-runs an executable passes this list to get the one the
+        engine runs."""
+        if not self.paged:
+            return [self.step.logits_var]
+        return next(step.fetch_vars
+                    for _, p, step, _ in self._paged_cells() if p is prog)
 
     def start(self):
         """Seed the decode state, run one warmup step per executable
@@ -464,9 +510,11 @@ class GenerationEngine:
             return self
         from ..models import gpt
         blk = self._prog.global_block()
-        gpt._ensure_decode_state(self.scope, blk, self.step.cache_names)
-        for _, prog, feed, fetch in self.executables():
-            self.exe.run(prog, feed=feed, fetch_list=[fetch],
+        gpt._ensure_decode_state(
+            self.scope, blk, self.step.cache_names
+            + (self.step.state_names if self.paged else []))
+        for _, prog, feed, _ in self.executables():
+            self.exe.run(prog, feed=feed, fetch_list=self.fetch_list(prog),
                          scope=self.scope)
         if self.paged:
             STAT_SET("serving.gen_kv_blocks_total",
@@ -643,14 +691,18 @@ class GenerationEngine:
         return np.asarray(out)
 
     def _run_paged(self, prog, step, tokens, table, start, nvalid):
-        out, = self.exe.run(
+        """One run of a paged executable; returns its logits (or probe
+        row). A model's side-fetch comes back in the same fetch and is
+        left in `_probe` for the iteration's record."""
+        out, *probe = self.exe.run(
             prog,
             feed={step.token_var.name: tokens,
                   step.table_var.name: table,
                   step.start_var.name: start,
                   step.nvalid_var.name: nvalid},
-            fetch_list=[step.logits_var],
+            fetch_list=step.fetch_vars,
             scope=self.scope)
+        self._probe = np.asarray(probe[0]) if probe else None
         return np.asarray(out)
 
     # -- paged-KV bookkeeping (worker thread only) -----------------------
@@ -697,7 +749,7 @@ class GenerationEngine:
         trace.end_span(q.qspan)
         st.phase_span = trace.start_span("prefill", parent=st.span)
 
-    def _admit_locked(self) -> bool:
+    def _admit_locked(self, rec) -> bool:
         """Move the queue head into a free slot. Paged mode additionally
         gates on block availability: shared prefix blocks come from the
         PrefixCache (refcounted, zero prefill cost), the rest are
@@ -716,8 +768,16 @@ class GenerationEngine:
             # the last prompt position must stay writable (its KV is
             # written by this slot's first decode step), so the prefix
             # match is capped one token short of the prompt
-            n_cached, shared = self._prefix.lookup(
-                prompt, max_tokens=len(prompt) - 1)
+            if self.recurrent:
+                # a cached KV block carries no recurrent state: a slot
+                # that skipped its tokens would decode from a state
+                # that has not seen them. Nothing is adopted.
+                n_cached, shared = 0, []
+                rec.prefix_skipped_recurrent += 1
+                STAT_ADD("serving.gen_prefix_skipped_recurrent")
+            else:
+                n_cached, shared = self._prefix.lookup(
+                    prompt, max_tokens=len(prompt) - 1)
             owned: List[int] = []
             missing = blocks_for_tokens(need, self.block_size) - \
                 len(shared)
@@ -768,7 +828,7 @@ class GenerationEngine:
         prefix skips its prefill."""
         bs = self.block_size
         n_full = len(st.req.prompt) // bs
-        if n_full == 0:
+        if n_full == 0 or self.recurrent:   # nothing would adopt them
             return
         hashes = self._prefix.chunk_hashes(st.req.prompt[:n_full * bs],
                                            bs)
@@ -853,6 +913,16 @@ class GenerationEngine:
         STAT_SET("serving.gen_active_slots", rec.active_slots)
         if self.paged:
             self._set_block_gauges()
+        if self.recurrent:
+            rec.state_slots_live = len(live)
+            rec.state_bytes = len(live) * self.cfg.state_slot_bytes()
+            STAT_SET("serving.gen_state_slots_live", rec.state_slots_live)
+            STAT_SET("serving.gen_state_bytes", rec.state_bytes)
+        if rec.moe_selected:
+            STAT_SET("serving.gen_moe_held_share",
+                     rec.moe_selected_held / rec.moe_selected)
+            STAT_SET("serving.gen_moe_experts_hit", rec.moe_experts_hit)
+            STAT_SET("serving.gen_moe_load_max", rec.moe_load_max)
         if rec.decode_rows and _monitor_on():
             STAT_OBSERVE("serving.gen_slot_occupancy",
                          rec.decode_rows / float(rec.slots),
@@ -879,7 +949,7 @@ class GenerationEngine:
                 # — and in paged mode its KV blocks — freed by the
                 # previous step is reusable right now)
                 while self._queue and self._slots.free_count() \
-                        and self._admit_locked():
+                        and self._admit_locked(rec):
                     pass
                 active_idx = [i for i in range(B)
                               if self._state[i] is not None]
@@ -1139,6 +1209,14 @@ class GenerationEngine:
                 blocks_for_tokens(s + n, bs)
                 for s, n in zip(start.tolist(), nvalid.tolist()) if n)
             rec.kv_pages_table += table.size
+            if self._probe is not None:
+                # a row an expert layer: selections made, those on held
+                # experts, held experts hit, the busiest one's tokens
+                sel, held, hit, busiest = self._probe.sum(axis=0).tolist()
+                rec.moe_selected += sel
+                rec.moe_selected_held += held
+                rec.moe_experts_hit += hit
+                rec.moe_load_max += busiest
             if trace.enabled():
                 lt = self.exe.last_step_timings
                 if lt is not None:

@@ -62,13 +62,21 @@ class KVShipment:
 def pack_blocks(scope, cache_names: Sequence[str],
                 block_ids: Sequence[int],
                 chain_hashes: Sequence[str],
-                block_size: int) -> dict:
+                block_size: int,
+                state_names: Sequence[str] = ()) -> dict:
     """Serialize pool rows `block_ids` from every paged KV pool in
     `cache_names` (alternating k, v per layer) into a JSON-safe dict.
 
     `chain_hashes[i]` must be the content hash of the tokens stored in
     `block_ids[i]`; the adopting side keys its PrefixCache on them.
+    `state_names` are the step's recurrent state variables: a model
+    that has any is refused, because the format ships KV blocks only.
     """
+    if state_names:
+        raise ValueError(
+            f"kv_wire ships paged KV blocks only; this model also "
+            f"carries per-slot recurrent state ({state_names[0]}, ...) "
+            f"that a block does not hold")
     if len(cache_names) % 2 != 0:
         raise ValueError(
             f"cache_names must alternate k/v pools, got {len(cache_names)}")

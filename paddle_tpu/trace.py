@@ -435,6 +435,9 @@ class IterationRecord:
                  "decode_rows", "tokens_emitted", "queue_depth",
                  "active_slots", "kv_blocks_held", "kv_tokens_resident",
                  "kv_blocks_total", "kv_pages_read", "kv_pages_table",
+                 "state_slots_live", "state_bytes",
+                 "prefix_skipped_recurrent", "moe_selected",
+                 "moe_selected_held", "moe_experts_hit", "moe_load_max",
                  "slots", "block_size", "host_s", "_open")
 
     def __init__(self, slots, block_size, kv_blocks_total):
@@ -449,6 +452,17 @@ class IterationRecord:
         # rows' lengths cover (what paged_attention reads), and rows x
         # table width (what reading every table entry would take)
         self.kv_pages_read = self.kv_pages_table = 0
+        # a model with recurrent layers: the slots whose state is live
+        # at the turn's end and the bytes it takes (not paged: a row a
+        # slot), and the admissions of the turn for which the prefix
+        # cache was not asked (a cached block carries no state). A model with routed experts, over the decode steps the
+        # turn ran and summed over its expert layers: selections made,
+        # those that fell on experts held here, held experts with at
+        # least one token, tokens on the busiest held expert
+        self.state_slots_live = self.state_bytes = 0
+        self.prefix_skipped_recurrent = 0
+        self.moe_selected = self.moe_selected_held = 0
+        self.moe_experts_hit = self.moe_load_max = 0
         self.slots = slots
         self.block_size = block_size
         self.host_s: Dict[str, float] = {}

@@ -42,6 +42,16 @@ def spec(op, ins=None, attrs=None, grad=(), exact=True, expect=None,
     rng.seed(zlib.crc32(op.encode()) & 0x7FFFFFFF)
 
 
+def _rms_norm_ref(i, a):
+    """Gated, grouped RMSNorm in numpy: x silu(gate) over each group's
+    root mean square, times the scale."""
+    x = i["X"] * i["Gate"] / (1.0 + np.exp(-i["Gate"]))
+    g = a["groups"]
+    xg = x.reshape(x.shape[:-1] + (g, x.shape[-1] // g))
+    xg = xg / np.sqrt((xg * xg).mean(-1, keepdims=True) + a["epsilon"])
+    return xg.reshape(x.shape) * i["Scale"]
+
+
 def skip(op, reason):
     assert op not in SKIPS, op
     SKIPS[op] = reason
@@ -466,6 +476,14 @@ skip("paged_attention", "stateful decode op over externally-allocated "
      "tests/test_paged_attention_op.py, token-exact parity vs the slab "
      "path in tests/test_generation.py and the allocator in "
      "tests/test_kv_blocks.py")
+skip("mamba2_mixer", "stateful serving op over per-slot convolution and "
+     "SSM state with start/n_valid feeds; chunks, single steps, muted "
+     "and partly valid rows against the plain full-sequence scan in "
+     "tests/test_hybrid_model.py")
+skip("latent_moe", "top-k routed experts with a held share and an int32 "
+     "probe; against the plain reference layer, the four shares adding "
+     "up (also over an `ep` mesh axis) and a one-expert router in "
+     "tests/test_hybrid_model.py")
 skip("print", "host-side debug print (io_callback); side-effect only")
 skip("py_func", "wraps arbitrary user Python; covered in "
                 "test_jit_and_extras.py")
@@ -546,6 +564,9 @@ _BN = dict(ins={"X": f32(2, 3, 4, 4), "Scale": pos(3), "Bias": f32(3),
                 "Mean": f32(3), "Variance": pos(3)},
            attrs={"is_test": True, "epsilon": 1e-5, "momentum": 0.9})
 spec("batch_norm", is_test=True, **_BN)
+spec("rms_norm", ins={"X": f32(2, 3, 8), "Scale": pos(8), "Gate": f32(2, 3, 8)},
+     attrs={"epsilon": 1e-5, "groups": 2}, grad=["X", "Scale", "Gate"],
+     expect=lambda i, a: {"Out": [_rms_norm_ref(i, a)]})
 spec("layer_norm", ins={"X": f32(2, 6), "Scale": pos(6), "Bias": f32(6)},
      attrs={"begin_norm_axis": 1, "epsilon": 1e-5},
      grad=["X", "Scale", "Bias"])

@@ -1,0 +1,413 @@
+"""The pattern-string decoder (models/hybrid.py) against its plain
+reference (benchmark/configs/nemotron3_super_ep4_l11_reference.py) at a
+small size, seeded: each op against the reference's layer, the whole
+model through GenerationEngine, the expert shares adding up, no token
+dropped, what the engine refuses for a recurrent model, and GPT's two
+programs unchanged by the hook that builds them."""
+import hashlib
+import json
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import paddle_tpu as fluid
+from benchmark import manifest
+from benchmark.configs import nemotron3_super_ep4_l11_reference as ref
+from benchmark.families import hybrid_serve
+from paddle_tpu.core.registry import REGISTRY
+from paddle_tpu.models import gpt
+from paddle_tpu.ops import state_space
+from paddle_tpu.parallel import moe
+from paddle_tpu.serving import GenerationEngine, GenerationRequest, disagg
+from paddle_tpu.serving import kv_wire
+
+CELL = "nemotron3_super_ep4_l11.batch_reason"
+SEED = 2**31 + 29
+
+
+def small(**over):
+    """The rehearsal's sizes: hidden 64, 8 query heads over 2 KV heads
+    of 16, 16 experts top-4 in a latent of 32 (4 held), 8 Mamba heads of
+    8, state 16, pattern EM*EM, vocabulary 512."""
+    _, cfg, _, _ = manifest.cell(CELL, rehearsal=True)
+    cfg = {**cfg, **over}
+    return cfg, ref.sizes(cfg)
+
+
+def leaves(sz, i, dtype=jnp.float32):
+    return {k: v.astype(dtype) if v.dtype == jnp.bfloat16 else v
+            for k, v in ref.layer_leaves(sz, SEED, i).items()}
+
+
+def f32(tree):
+    return {k: v.astype(jnp.float32) for k, v in tree.items()}
+
+
+# -- (a) each op against the reference's layer --------------------------------
+
+def test_rms_norm_plain_grouped_and_gated():
+    rng = np.random.default_rng(0)
+    x = jnp.asarray(rng.normal(size=(3, 5, 32)), jnp.float32)
+    w = jnp.asarray(1 + 0.1 * rng.normal(size=32), jnp.float32)
+    gate = jnp.asarray(rng.normal(size=(3, 5, 32)), jnp.float32)
+    np.testing.assert_allclose(state_space.rms_norm(x, w, 1e-5),
+                               ref.rms_norm(x, w, 1e-5), rtol=1e-6)
+    want = ref.rms_norm((x * jax.nn.silu(gate)).reshape(3, 5, 4, 8),
+                        w.reshape(4, 8), 1e-5).reshape(3, 5, 32)
+    got = REGISTRY.get("rms_norm").lower(
+        None, {"X": [x], "Scale": [w], "Gate": [gate]},
+        {"epsilon": 1e-5, "groups": 4})["Out"][0]
+    np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
+    assert state_space.rms_norm(x.astype(jnp.bfloat16), w, 1e-5).dtype \
+        == jnp.bfloat16
+
+
+def mixer_weights(p):
+    return {"in_proj": p["mixer.in_proj.w"], "conv_w": p["mixer.conv.w"],
+            "conv_b": p["mixer.conv.b"], "dt_bias": p["mixer.dt_bias"],
+            "a_log": p["mixer.A_log"], "d": p["mixer.D"],
+            "norm_w": p["mixer.norm.w"], "out_proj": p["mixer.out_proj.w"]}
+
+
+def test_mamba2_mixer_chunks_and_steps_match_the_full_scan():
+    """Four rows, each its own sequence: a full chunk then steps; a
+    partly valid chunk then steps; a row muted throughout, whose state
+    (garbage) must come back untouched; a row that starts late over a
+    stale state, which start == 0 must wipe."""
+    _, sz = small()
+    p = leaves(sz, 1)
+    w = mixer_weights(p)
+    d, k = sz["hidden_size"], sz["conv_kernel"]
+    h, hp, n = sz["mamba_num_heads"], sz["mamba_head_dim"], \
+        sz["ssm_state_size"]
+    _, conv_c, _ = ref.mamba_dims(sz)
+    rng = np.random.default_rng(1)
+    lengths = [20, 12, 0, 6]
+    u = [jnp.asarray(rng.normal(size=(m, d)), jnp.float32) for m in lengths]
+    full = jax.jit(lambda x: ref.mamba_mixer(x, f32(p), sz))
+    want = [np.asarray(full(x)) if len(x) else None for x in u]
+    mixer = jax.jit(lambda *a: state_space.mamba2_mixer(
+        *a, groups=sz["n_groups"], eps=sz["norm_eps"]))
+    conv = jnp.asarray(rng.normal(size=(4, k - 1, conv_c)), jnp.float32)
+    ssm = jnp.asarray(rng.normal(size=(4, h, hp, n)), jnp.float32)
+    conv0, ssm0 = np.asarray(conv), np.asarray(ssm)
+    got = [[] for _ in lengths]
+    fed = [0, 0, 0, None]       # row 3 joins after the first chunk
+    # (tokens a step, n_valid by row); row 1's chunk holds 5 of 16
+    plan = [(16, [16, 5, 0, 0])] + [(1, [1, 1, 0, 1])] * 4 \
+        + [(16, [0, 3, 0, 2])]
+    for t, nv in plan:
+        x = np.zeros((4, t, d), np.float32)
+        start = np.zeros(4, np.int32)
+        for b, m in enumerate(nv):
+            if m:
+                fed[b] = fed[b] or 0
+                x[b, :m] = u[b][fed[b]:fed[b] + m]
+                start[b] = fed[b]
+        out, conv, ssm = mixer(jnp.asarray(x), w, conv, ssm,
+                               jnp.asarray(start),
+                               jnp.asarray(nv, jnp.int32))
+        for b, m in enumerate(nv):
+            if m:
+                got[b].append(np.asarray(out[b, :m]))
+                fed[b] += m
+    for b, m in enumerate(lengths):
+        if m:
+            np.testing.assert_allclose(np.concatenate(got[b]), want[b],
+                                       rtol=2e-4, atol=2e-5)
+            assert fed[b] == m
+    np.testing.assert_array_equal(np.asarray(conv[2]), conv0[2])
+    np.testing.assert_array_equal(np.asarray(ssm[2]), ssm0[2])
+
+
+@pytest.mark.parametrize("pool_dtype", [jnp.float32, jnp.bfloat16])
+def test_paged_attention_grouped_heads_against_the_reference(pool_dtype):
+    """8 query heads over 2 KV heads, a narrow pool of kv_heads x
+    head_dim lanes: a chunk of 16 (one row partly valid, one muted),
+    then single steps, against plain causal attention."""
+    _, sz = small()
+    p = f32(leaves(sz, 2))
+    h, kv, hd = sz["num_attention_heads"], sz["num_key_value_heads"], \
+        sz["head_dim"]
+    d, bs, nb = sz["hidden_size"], 16, 9
+    rng = np.random.default_rng(2)
+    lengths = [19, 7, 0]
+    u = [jnp.asarray(rng.normal(size=(m, d)), jnp.float32) for m in lengths]
+    plain = jax.jit(lambda x: ref.attention(x, p, sz))
+    want = [np.asarray(plain(x)) if len(x) else None for x in u]
+    from paddle_tpu.ops.pallas.paged_attention import pool_lanes
+    lanes = pool_lanes(kv * hd)
+    pools = [jnp.zeros((nb, bs, lanes), pool_dtype)] * 2
+    table = jnp.asarray([[1, 2, 0, 0], [3, 4, 0, 0], [0, 0, 0, 0]],
+                        jnp.int32)
+    lower = jax.jit(lambda ins: REGISTRY.get("paged_attention").lower(
+        None, ins, {"sm_scale": hd ** -0.5}))
+    fed, got = [0, 0, 0], [[], [], []]
+    for t, nv in [(16, [16, 5, 0]), (1, [1, 1, 0]), (1, [1, 1, 0]),
+                  (1, [1, 0, 0])]:
+        x = np.zeros((3, t, d), np.float32)
+        for b, m in enumerate(nv):
+            x[b, :m] = u[b][fed[b]:fed[b] + m]
+        x = jnp.asarray(x)
+
+        def heads(z, n):
+            return z.reshape(3, t, n, hd).transpose(0, 2, 1, 3)
+        outs = lower({
+            "Q": [heads(x @ p["att.q.w"], h)],
+            "K": [heads(x @ p["att.k.w"], kv)],
+            "V": [heads(x @ p["att.v.w"], kv)],
+            "CacheK": [pools[0]], "CacheV": [pools[1]],
+            "BlockTable": [table], "StartPos": [jnp.asarray(fed)],
+            "NValid": [jnp.asarray(nv)]})
+        pools = [outs["CacheKOut"][0], outs["CacheVOut"][0]]
+        assert pools[0].dtype == pool_dtype
+        ctx = outs["Out"][0].transpose(0, 2, 1, 3).reshape(3, t, h * hd)
+        y = ctx @ p["att.o.w"]
+        for b, m in enumerate(nv):
+            if m:
+                got[b].append(np.asarray(y[b, :m]))
+                fed[b] += m
+    tol = 2e-4 if pool_dtype == jnp.float32 else 3e-2
+    for b, m in enumerate(lengths):
+        if m:
+            np.testing.assert_allclose(np.concatenate(got[b]), want[b],
+                                       rtol=tol, atol=tol)
+
+
+def jit_moe(sz, share=0):
+    """The program's layer, jitted: (x [B, T, d], leaves, n_valid)."""
+    return jax.jit(lambda x, p, nv: moe.latent_moe(
+        x, moe_params(p), sz["num_experts_per_tok"],
+        sz["routed_scaling_factor"], share=share, n_valid=nv))
+
+
+def moe_params(p):
+    return {"router_w": p["moe.router.w"], "router_bias": p["moe.router.bias"],
+            "down": p["moe.down.w"], "w1": p["moe.w1"], "w2": p["moe.w2"],
+            "up": p["moe.up.w"], "shared_w1": p["moe.shared.w1"],
+            "shared_w2": p["moe.shared.w2"]}
+
+
+@pytest.mark.parametrize("tokens", [1, 16])
+def test_latent_moe_against_the_reference_layer(tokens):
+    """Share 0 of 4 (4 of 16 experts held), rows muted and partly valid:
+    the valid tokens equal the reference's layer, and the probe counts
+    them and no other."""
+    _, sz = small()
+    p = leaves(sz, 0)
+    rng = np.random.default_rng(3)
+    x = jnp.asarray(rng.normal(size=(4, tokens, sz["hidden_size"])),
+                    jnp.float32)
+    nv = [tokens, max(1, tokens // 3), 0, tokens]
+    out, probe = jit_moe(sz)(x, p, jnp.asarray(nv, jnp.int32))
+    # the reference over every token; the valid ones are compared
+    want = jax.jit(lambda u: ref.latent_moe(u, f32(p), sz))(
+        x.reshape(4 * tokens, -1)).reshape(x.shape)
+    sel, _ = jax.jit(lambda u: ref.route(u, f32(p), sz))(
+        x.reshape(4 * tokens, -1))
+    sel = np.asarray(sel).reshape(4, tokens, -1)
+    made = held = 0
+    for b, m in enumerate(nv):
+        np.testing.assert_allclose(out[b, :m], want[b, :m], rtol=2e-4,
+                                   atol=2e-5)
+        made += sel[b, :m].size
+        held += int((sel[b, :m] < sz["experts_held"]).sum())
+    assert probe.dtype == jnp.int32
+    assert int(probe[0]) == made and int(probe[1]) == held
+    assert 0 < int(probe[2]) <= sz["experts_held"]
+    assert int(probe[3]) * int(probe[2]) >= held >= int(probe[3])
+
+
+# -- (c) the shares add up; (d) nothing is dropped ----------------------------
+
+def uncut():
+    """The same layer with all 16 experts held, and its leaves."""
+    cfg, _ = small()
+    whole = dict(cfg, n_routed_experts=16)
+    sz = ref.sizes(whole)
+    return sz, leaves(sz, 0)
+
+
+def test_the_four_shares_add_up_to_the_uncut_layer():
+    """Shares k = 0..3, each with its own four experts: their routed
+    parts, with the latent projections and the shared expert counted
+    once, are the uncut 16-expert layer: in the reference, in the
+    program's op, and over an `ep` mesh axis of four devices."""
+    sz, p = uncut()
+    rng = np.random.default_rng(4)
+    u = jnp.asarray(rng.normal(size=(24, sz["hidden_size"])), jnp.float32)
+    whole = np.asarray(jax.jit(
+        lambda u: ref.latent_moe(u, f32(p), sz, share=0))(u))
+    held = 4
+    cut = dict(sz, experts_held=held)
+    shared = jax.jit(lambda u: ref.shared_part(u, f32(p)))(u)
+    part = jax.jit(lambda u, mine, k: ref.routed_part(u, f32(mine), cut, k))
+    routed_ref, routed_op = 0.0, 0.0
+    for k in range(4):
+        mine = dict(p, **{"moe.w1": p["moe.w1"][k * held:(k + 1) * held],
+                          "moe.w2": p["moe.w2"][k * held:(k + 1) * held]})
+        routed_ref = routed_ref + part(u, mine, k)
+        out, _ = jax.jit(lambda x, mine, k: moe.latent_moe(
+            x, moe_params(mine), sz["num_experts_per_tok"],
+            sz["routed_scaling_factor"], share=k))(u[None], mine, k)
+        # a share's output is its routed part through W_up, plus the
+        # shared expert that every share computes alike
+        routed_op = routed_op + out[0] - shared
+    summed = ref._mm(routed_ref, p["moe.up.w"]) + shared
+    np.testing.assert_allclose(summed, whole, rtol=2e-4, atol=2e-5)
+    np.testing.assert_allclose(routed_op + shared, whole, rtol=2e-4,
+                               atol=2e-5)
+    mesh = jax.sharding.Mesh(np.array(jax.devices()[:4]), ("ep",))
+    out, probe = moe.latent_moe_sharded(
+        u[None], moe_params(p), mesh, sz["num_experts_per_tok"],
+        sz["routed_scaling_factor"])
+    np.testing.assert_allclose(out[0], whole, rtol=2e-4, atol=2e-5)
+    assert int(probe[0]) == int(probe[1]) == 24 * sz["num_experts_per_tok"]
+
+
+def test_no_token_is_dropped_when_every_token_takes_one_expert():
+    """A selection bias that sends all 64 tokens to expert 2 (and to
+    three experts of other shares): the held expert sees 64 tokens, 16
+    times the even load, and every one gets its output."""
+    _, sz = small()
+    p = leaves(sz, 0)
+    bias = np.full(sz["router_width"], -10.0, np.float32)
+    bias[[2, 5, 9, 13]] = 10.0
+    p["moe.router.bias"] = jnp.asarray(bias)
+    rng = np.random.default_rng(5)
+    u = jnp.asarray(rng.normal(size=(4, 16, sz["hidden_size"])), jnp.float32)
+    out, probe = jit_moe(sz)(u, p, jnp.full((4,), 16, jnp.int32))
+    assert [int(v) for v in probe] == [256, 64, 1, 64]
+    want = jax.jit(lambda u: ref.latent_moe(u, f32(p), sz))(
+        u.reshape(64, -1))
+    np.testing.assert_allclose(out.reshape(64, -1), want, rtol=2e-4,
+                               atol=2e-5)
+    routed = out.reshape(64, -1) - ref.shared_part(u.reshape(64, -1), f32(p))
+    assert float(jnp.abs(routed).max(axis=1).min()) > 1e-7
+
+
+# -- (b) through GenerationEngine ---------------------------------------------
+
+def engine_logits(dtype, max_slots=3):
+    """Six requests of uneven prompts over three slots (so slots are
+    reused), greedy: for each the logits rows it was sampled from."""
+    cfg, sz = small()
+    cfg = dict(cfg, engine=dict(cfg["engine"], dtype=dtype,
+                                max_slots=max_slots))
+    cell = hybrid_serve.build(cfg, {"timeout_ms": 600000}, 1, SEED)
+    cell.warm()
+    rng = np.random.default_rng(6)
+    jobs = []
+    for n_prompt, n_out in [(1, 5), (17, 6), (40, 4), (16, 5), (33, 7),
+                            (5, 3)]:
+        prompt = rng.integers(0, sz["vocab_size"], n_prompt).tolist()
+        rows = []
+        resp = cell.engine.submit(GenerationRequest(
+            prompt, n_out, timeout_ms=600000,
+            logits_cb=lambda r, rows=rows: rows.append(np.array(r))))
+        jobs.append((prompt, rows, resp))
+    done = [(prompt, rows, resp.result(timeout=300))
+            for prompt, rows, resp in jobs]
+    records = [r for r in fluid.trace.iteration_records()]
+    cell.stop()
+    return cfg, done, records
+
+
+@pytest.mark.parametrize("dtype,limit", [("float32", 1e-4),
+                                         ("bfloat16", 0.15)])
+def test_engine_prefill_and_decode_match_the_full_forward(dtype, limit):
+    """Chunk-prefilled then decoded through the engine against the
+    reference's full forward, logits not tokens: the float32 build to
+    1e-4 of a row's spread; the bfloat16 build (weights, activations and
+    pool rounded to 8 bits of mantissa through five blocks) to 0.15 of
+    it, which a wrong state or a wrong page (gaps of order 1) fails."""
+    cfg, done, records = engine_logits(dtype)
+    p = ref.params(cfg, SEED)
+    for prompt, rows, result in done:
+        tokens = result["tokens"]
+        assert len(rows) == len(tokens) and result["cached_tokens"] == 0
+        seq = prompt + tokens
+        want = ref.logits(cfg, p, seq)[len(prompt) - 1:len(seq) - 1]
+        gap = np.abs(np.stack(rows) - want) / want.std(axis=-1,
+                                                       keepdims=True)
+        assert gap.max() < limit, (len(prompt), gap.max())
+    assert any(r["state_slots_live"] == 3 and r["state_bytes"] > 0
+               for r in records)
+    assert sum(r["moe_selected"] for r in records) > 0
+
+
+# -- (e) what the engine does not do for a recurrent model --------------------
+
+def test_prefix_cache_adopts_nothing_and_spec_disagg_wire_refuse():
+    cfg, sz = small()
+    cell = hybrid_serve.build(cfg, {"timeout_ms": 600000}, 1, SEED)
+    eng = cell.engine
+    assert eng.recurrent and eng.state_bytes() == \
+        cfg["engine"]["max_slots"] * eng.cfg.state_slot_bytes()
+    cell.warm()
+    prompt = list(range(40))
+    first = eng.generate(prompt, 2, timeout_ms=600000)
+    before = sum(r["prefix_skipped_recurrent"]
+                 for r in fluid.trace.iteration_records())
+    again = eng.generate(prompt, 2, timeout_ms=600000)
+    after = sum(r["prefix_skipped_recurrent"]
+                for r in fluid.trace.iteration_records())
+    assert first["cached_tokens"] == again["cached_tokens"] == 0
+    assert again["tokens"] == first["tokens"] and after == before + 1
+    assert len(eng._prefix) == 0
+    with pytest.raises(ValueError, match="recurrent"):
+        disagg.export_prefix(eng, prompt)
+    with pytest.raises(ValueError, match="recurrent state"):
+        kv_wire.pack_blocks(eng.scope, eng.step.cache_names, [1], ["a"],
+                            eng.block_size, eng.step.state_names)
+    cell.stop()
+    with pytest.raises(ValueError, match="speculative"):
+        GenerationEngine(eng.cfg, fluid.Scope(), max_slots=2, max_seq=64,
+                         paged=True, spec_decode=True, spec_k=2)
+    with pytest.raises(ValueError, match="paged"):
+        GenerationEngine(eng.cfg, fluid.Scope(), max_slots=2, max_seq=64,
+                         paged=False)
+
+
+# -- (f) GPT's programs through the same hook ---------------------------------
+
+def fingerprint(prog):
+    """Op list, attributes and shapes, every variable named by the order
+    it first appears in (the process's name counters do not show)."""
+    blk = prog.global_block()
+    order = {}
+
+    def idx(n):
+        return order.setdefault(n, len(order))
+    sig = []
+    for op in blk.ops:
+        ins = [(k, [idx(n) for n in v]) for k, v in sorted(op.inputs.items())]
+        outs = [(k, [idx(n) for n in v])
+                for k, v in sorted(op.outputs.items())]
+        sig.append((op.type, ins, outs,
+                    sorted((k, repr(v)) for k, v in op.attrs.items())))
+    shapes = [(i, tuple(blk.var(n).shape or ()), str(blk.var(n).dtype),
+               bool(blk.var(n).persistable)) for n, i in order.items()]
+    return len(blk.ops), hashlib.sha256(
+        json.dumps([sig, shapes]).encode()).hexdigest()
+
+
+def test_gpt_programs_are_the_parents():
+    """`GenerationEngine` asks the configuration for its programs; for
+    a TransformerConfig that builds what `gpt.build_paged_decode_step`
+    built in the parent commit (digests taken there, PR 28's tree)."""
+    cfg = gpt.gpt_small(vocab_size=512, d_model=64, n_heads=2, n_layers=2,
+                        d_ff=128, max_seq_len=128, dropout=0.0)
+    eng = GenerationEngine(cfg, fluid.Scope(), max_slots=4, max_seq=128,
+                           paged=True)
+    assert fingerprint(eng._prog) == (
+        73, "01f80c4c2ab1baf13200ac9eaf0b14f0"
+            "e8c2ff4e5a71d36500e32b0fe5f4fc39")
+    assert fingerprint(eng._prefill_prog) == (
+        73, "3a6e678459f2a0186717b32d0b76596f"
+            "864c904a009c503b116c8c5fb69e86a1")
+    assert not eng.recurrent and eng.state_bytes() == 0
+    assert eng.step.state_names == [] and eng.step.probe_var is None
+    assert eng.kv_block_bytes() == 2 * 2 * eng.block_size * 128 * 4

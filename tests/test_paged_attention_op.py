@@ -237,3 +237,34 @@ def test_kernel_compiles_for_v5e(one_chip, monkeypatch, t, h, hd, slots, mb):
     copies = [ln for ln in text.splitlines()
               if f"= {pool}" in ln and " copy(" in ln]
     assert not copies, copies
+
+
+@pytest.mark.parametrize("t", [1, 16], ids=["decode", "chunk_prefill"])
+def test_grouped_kernel_compiles_for_v5e(one_chip, monkeypatch, t):
+    """The grouped kernel at `nemotron3_super_ep4_l11`'s sizes: 32
+    query heads over 2 KV heads of 128, 64 slots of 2,048 positions, a
+    bfloat16 pool of 256 lanes that reaches the kernel with no copy."""
+    monkeypatch.setattr(kernel, "_interpret", lambda: False)
+    h, kv, hd, slots, mb, bs = 32, 2, 128, 64, 128, 16
+    lanes, nb = kernel.pool_lanes(kv * hd), slots * mb + 1
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    read = jax.jit(lambda *a: kernel.paged_attention_read.__wrapped__(
+        *a, sm_scale=hd ** -0.5, kv_heads=kv))
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        text = read.lower(
+            arg((slots, h, t, hd), jnp.float32),
+            arg((nb, bs, lanes), jnp.bfloat16),
+            arg((nb, bs, lanes), jnp.bfloat16),
+            arg((slots, mb), jnp.int32), arg((slots,), jnp.int32),
+            arg((slots,), jnp.int32)).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", prev)
+    assert "tpu_custom_call" in text
+    copies = [ln for ln in text.splitlines()
+              if f"= bf16[{nb},{bs},{lanes}]" in ln and " copy(" in ln]
+    assert not copies, copies
