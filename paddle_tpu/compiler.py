@@ -161,7 +161,9 @@ class CompiledProgram:
 
     def build_jit(self, step_fn, state_in_names, feed_arrays,
                   state_out_names=()):
-        """jit `step_fn(state, feeds, step_idx)` with SPMD shardings:
+        """jit `step_fn(state, pinned, feeds, step_idx)` (the Executor's
+        one calling shape; here the whole state is donated and `pinned`
+        is empty) with SPMD shardings:
         feeds sharded on the batch axes, params per state_spec_fn
         (replicated by default). GSPMD then emits gradient AllReduces /
         TP collectives over ICI — the entire reference multi-device
@@ -191,7 +193,8 @@ class CompiledProgram:
         else:
             out_state = None
         jitted = jax.jit(step_fn, donate_argnums=(0,),
-                         in_shardings=(state_shard, feed_shard, repl),
+                         in_shardings=(state_shard, None, feed_shard,
+                                       repl),
                          out_shardings=(None, out_state) if out_state
                          else None)
         if jax.process_count() <= 1:
@@ -214,11 +217,11 @@ class CompiledProgram:
             return jax.make_array_from_callback(
                 arr.shape, sharding, lambda idx: arr[idx])
 
-        def run_global(state, feeds, step_idx):
+        def run_global(state, pinned, feeds, step_idx):
             state = {n: _globalize(v, state_shard.get(n, repl))
                      for n, v in state.items()}
             feeds = {n: _globalize(v, feed_shard.get(n, repl))
                      for n, v in feeds.items()}
-            return jitted(state, feeds, step_idx)
+            return jitted(state, pinned, feeds, step_idx)
 
         return run_global

@@ -54,6 +54,16 @@ class _Flag:
 
 _REGISTRY: Dict[str, _Flag] = {}
 _LOCK = threading.Lock()
+# Bumped by every assignment to a flag's value (FLAGS.x = v, set_flags,
+# the environment readers). What was resolved under one generation
+# (the Executor's bound step: the gates' verdicts, trace_signature())
+# holds for as long as the number has not moved: an O(1) test in place
+# of re-reading every flag.
+_generation = 0
+
+
+def generation() -> int:
+    return _generation
 
 
 def _define(name, default, ftype, help_, noop=False, traced=False):
@@ -91,11 +101,13 @@ def _parse(ftype, raw: str):
 
 
 def _load_one_from_env(name):
+    global _generation
     raw = os.environ.get(f"FLAGS_{name}")
     if raw is not None:
         f = _REGISTRY[name]
         try:
             f.value = _parse(f.ftype, raw)
+            _generation += 1
         except (ValueError, TypeError):
             # a bad env value must not make the package unimportable
             import warnings
@@ -124,8 +136,10 @@ class _FlagsNamespace:
         f = _REGISTRY.get(name)
         if f is None:
             raise AttributeError(f"unknown flag {name!r}")
+        global _generation
         f.value = _parse(f.ftype, value) if isinstance(value, str) \
             else f.ftype(value)
+        _generation += 1
 
     def __dir__(self):
         return sorted(_REGISTRY)

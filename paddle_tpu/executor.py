@@ -14,6 +14,7 @@ is vars/names, results come back as numpy by default.
 from __future__ import annotations
 
 import time
+import weakref
 from typing import Dict, List, Optional
 
 import jax
@@ -22,6 +23,7 @@ import numpy as np
 
 from . import goodput as _goodput
 from . import trace as _trace
+from .core import flags as _flags
 from .core.dtypes import as_np_dtype
 from .core.lowering import LowerCtx, lower_block
 from .core.place import Place, default_place
@@ -36,44 +38,95 @@ __all__ = ["Executor", "global_scope", "scope_guard"]
 from .core.scope import scope_guard  # re-export  # noqa: E402
 
 
+def _as_device_array(v):
+    return v if isinstance(v, jax.Array) else jnp.asarray(v)
+
+
+def _not_initialised(name):
+    return RuntimeError(
+        f"persistable var {name!r} is not initialised — run the "
+        f"startup program first")
+
+
 class _CompiledStep:
     def __init__(self, fn, state_in_names, state_out_names, fetch_names,
-                 donate_names=None):
+                 donate_names):
         self.fn = fn
         self.state_in_names = state_in_names
         self.state_out_names = state_out_names
         self.fetch_names = fetch_names
-        # donation-planner result (FLAGS_graph_opt_level=2): the subset
-        # of state vars the jit donates; None = legacy whole-dict donate
+        # the state inputs the jit donates, gets back and the scope is
+        # written with: what some op of the program produces (the
+        # donation planner's narrower set under FLAGS_graph_opt_level=2;
+        # every state input of a CompiledProgram). The rest, the weights
+        # of an inference step, is passed pinned: not donated, not
+        # returned, gathered once by a bound step.
         self.donate_names = donate_names
+        self.donated_names = tuple(n for n in state_in_names
+                                   if n in donate_names)
+        self.pinned_names = tuple(n for n in state_in_names
+                                  if n not in donate_names)
         # run count: the first call pays XLA compile (jit is lazy), so
         # the monitor attributes it separately from steady-state steps
         self.runs = 0
 
 
-class _PlannedDonateStep:
-    """Adapter keeping the (state, feeds, step) call surface while the
-    underlying jit takes (donated_state, pinned_state, feeds, step)
-    with donate_argnums=(0,) — the donation planner's per-var split
-    (analysis/passes/donation.py)."""
+class _SplitStateStep:
+    """The (state, feeds, step) call surface over a jit that takes
+    (donated_state, pinned_state, feeds, step) with donate_argnums=(0,).
+    `split_call` is that jit itself, for a caller that already holds
+    the two halves (Executor.run)."""
 
     def __init__(self, jit_fn, donate_names):
-        self._fn = jit_fn
-        self._donate = frozenset(donate_names)
+        self.split_call = jit_fn
+        self._donate = donate_names
 
-    def _split(self, state):
+    def split(self, state):
         donated = {n: v for n, v in state.items() if n in self._donate}
         pinned = {n: v for n, v in state.items()
                   if n not in self._donate}
         return donated, pinned
 
     def __call__(self, state, feeds, step_idx):
-        donated, pinned = self._split(state)
-        return self._fn(donated, pinned, feeds, step_idx)
+        donated, pinned = self.split(state)
+        return self.split_call(donated, pinned, feeds, step_idx)
 
     def lower(self, state, feeds, step_idx):
-        donated, pinned = self._split(state)
-        return self._fn.lower(donated, pinned, feeds, step_idx)
+        donated, pinned = self.split(state)
+        return self.split_call.lower(donated, pinned, feeds, step_idx)
+
+
+class _BoundStep:
+    """What one call signature of Executor.run resolved to, kept for as
+    long as nothing it rests on has moved: the Program object and the
+    very fingerprint string it cached (every mutation site resets
+    `_fp_cache`, so identity is the program's stamp), the
+    CompiledProgram, the Scope, the fetch names, the feeds' (name,
+    kind, shape, dtype) in the caller's order, and the flags'
+    generation. A call that matches stages its feeds by `feed_plan`,
+    takes the pinned state from the scope's view and the donated state
+    from the scope, and runs `step_fn`: no scan, no gates, no key."""
+
+    __slots__ = ("program", "fp", "compiled", "scope_ref", "flags_gen",
+                 "step_fn", "cache_key", "feed_plan", "sharded")
+
+    def __init__(self, program, fp, compiled, scope, flags_gen, step_fn,
+                 cache_key, feed_plan):
+        self.program = program
+        self.fp = fp
+        self.compiled = compiled
+        # weak: a binding must not keep a scope's arrays alive
+        self.scope_ref = weakref.ref(scope)
+        self.flags_gen = flags_gen
+        self.step_fn = step_fn
+        self.cache_key = cache_key
+        # per fed name, in the caller's order: (name, dtype to cast to
+        # or None, sharding to place on or None)
+        self.feed_plan = feed_plan
+        # counted a call, as _resolve_step counts it
+        self.sharded = compiled is not None \
+            and compiled._is_data_parallel \
+            and compiled._state_spec_fn is not None
 
 
 class Executor:
@@ -86,6 +139,7 @@ class Executor:
         self._cache: "OrderedDict[tuple, _CompiledStep]" = OrderedDict()
         self._step_counters: Dict[str, int] = {}
         self._last_cache_hit = False
+        self._last_key = None
         # per-instance mirror of the global compile-cache counters: the
         # serving engine's warmup contract ("zero post-warmup compiles")
         # is about THIS executor, not every executor in the process
@@ -101,6 +155,15 @@ class Executor:
         self.last_step_timings: Optional[Dict[str, float]] = None
         self._last_feed_s = 0.0
         self._last_build_s = 0.0
+        # Bound steps (_BoundStep), LRU like the cache, keyed by the
+        # objects and the signature a call arrives with. `_bind_steps`
+        # is private: a test sets it False to send every call down
+        # _resolve_step.
+        self._bound: "OrderedDict[tuple, _BoundStep]" = OrderedDict()
+        self._bind_steps = True
+        self._bound_hits = 0
+        self._bound_binds = 0
+        self._bound_rebinds = 0
 
     # ------------------------------------------------------------------
     def run(self, program: Optional[Program] = None, feed=None,
@@ -118,27 +181,48 @@ class Executor:
             program = compiled.program
 
         scope = scope or global_scope()
-
-        # A listen_and_serv program IS the parameter-server loop: block in
-        # the host-side runtime instead of lowering (the reference's
-        # exe.run(pserver_prog) does the same, listen_and_serv_op.cc).
-        if any(op.type in ("listen_and_serv", "fl_listen_and_serv")
-               for op in program.global_block().ops):
-            from .distributed.ps_server import run_pserver
-            run_pserver(program, scope=scope)
-            return []
+        if feed is None:
+            feed = {}
 
         t_run0 = time.perf_counter()
         self._last_feed_s = 0.0
         self._last_build_s = 0.0
-        with _trace.region("executor.resolve"):
-            step_fn, state, feed_arrays = self._resolve_step(
-                program, feed, fetch_list, scope, compiled,
-                use_program_cache)
+        may_bind = use_program_cache and self._bind_steps
+        bound = self._find_bound(program, compiled, scope, feed,
+                                 fetch_list) if may_bind else None
+        if bound is not None:
+            step_fn, fp = bound.step_fn, bound.fp
+            with _trace.region("executor.resolve"):
+                donated, pinned, feed_arrays = self._stage_bound(
+                    bound, scope, feed)
+        else:
+            # A listen_and_serv program IS the parameter-server loop:
+            # block in the host-side runtime instead of lowering (the
+            # reference's exe.run(pserver_prog) does the same,
+            # listen_and_serv_op.cc). Such a program is never bound.
+            if any(op.type in ("listen_and_serv", "fl_listen_and_serv")
+                   for op in program.global_block().ops):
+                from .distributed.ps_server import run_pserver
+                run_pserver(program, scope=scope)
+                return []
 
-        fp = program.fingerprint()
+            t_run0 = time.perf_counter()
+            with _trace.region("executor.resolve"):
+                step_fn, state, feed_arrays = self._resolve_step(
+                    program, feed, fetch_list, scope, compiled,
+                    use_program_cache)
+                donated, pinned = step_fn.fn.split(state)
+                fp = program.fingerprint()
+                if may_bind:
+                    self._bind(program, fp, compiled, scope, feed,
+                               fetch_list, feed_arrays, step_fn)
+
         step = self._step_counters.get(fp, 0)
         self._step_counters[fp] = step + 1
+        # a host scalar: jnp.uint32(step) is a device computation of
+        # its own (a jit_convert_element_type run before every step);
+        # fold_in sees the same uint32 either way
+        step_idx = np.uint32(step)
 
         first_run = step_fn.runs == 0
         step_fn.runs += 1
@@ -164,8 +248,8 @@ class Executor:
             inj = _fault_injector()
             if inj is None:
                 with jax.default_device(self.place.jax_device()):
-                    fetches, new_state = step_fn.fn(state, feed_arrays,
-                                                    jnp.uint32(step))
+                    fetches, new_state = step_fn.fn.split_call(
+                        donated, pinned, feed_arrays, step_idx)
             else:
                 from .resilience.faults import TransientFault
                 from .resilience.retry import RetryPolicy
@@ -173,8 +257,8 @@ class Executor:
                 def _dispatch():
                     inj.pre_step("executor", step=step)
                     with jax.default_device(self.place.jax_device()):
-                        return step_fn.fn(state, feed_arrays,
-                                          jnp.uint32(step))
+                        return step_fn.fn.split_call(
+                            donated, pinned, feed_arrays, step_idx)
 
                 policy = RetryPolicy(is_retryable=lambda e: isinstance(
                     e, TransientFault))
@@ -237,6 +321,144 @@ class Executor:
                      fetch_block_seconds=round(now - t_fetch0, 6),
                      fetches=len(step_fn.fetch_names))
         return out
+
+    # ------------------------------------------------------------------
+    # The bound step: what a call signature resolved to, kept.
+    @staticmethod
+    def _bound_key(program, compiled, scope, feed, fetch_list):
+        """The key a call arrives with, or None for a call that is
+        never bound: a feed that is neither a plain ndarray nor a
+        jax.Array (a LoDTensor, a list: _prepare_feed's business)."""
+        if type(feed) is not dict:
+            return None
+        sig = []
+        for name, val in feed.items():
+            if type(val) is np.ndarray:
+                kind = 0
+            elif isinstance(val, jax.Array):
+                kind = 1
+            else:
+                return None
+            sig.append((name, kind, val.shape, val.dtype))
+        fetch_names = tuple(v.name if isinstance(v, Variable) else str(v)
+                            for v in (fetch_list or ()))
+        return (id(program), id(compiled), id(scope), fetch_names,
+                tuple(sig))
+
+    def _find_bound(self, program, compiled, scope, feed, fetch_list):
+        key = self._bound_key(program, compiled, scope, feed, fetch_list)
+        b = self._bound.get(key) if key is not None else None
+        if b is None:
+            return None
+        # ids are only unique among live objects: the binding holds the
+        # program and the CompiledProgram, and checks the scope it only
+        # weakly refers to. `_fp_cache is b.fp`: no mutation site has
+        # reset the fingerprint since; the generation: no flag was
+        # assigned since (the gates, trace_signature(), the fault spec).
+        if (b.scope_ref() is not scope
+                or program._fp_cache is not b.fp
+                or _flags.generation() != b.flags_gen
+                or scope.parent is not None or program.lod_link
+                or b.cache_key not in self._cache):
+            del self._bound[key]
+            return None
+        self._bound.move_to_end(key)
+        self._cache.move_to_end(b.cache_key)  # LRU touch, as a hit's
+        self._last_cache_hit = True
+        self._cache_hits += 1
+        self._bound_hits += 1
+        STAT_ADD("executor.compile_cache_hit")
+        STAT_ADD("executor.bound_step_hits")
+        if b.sharded:
+            STAT_ADD("parallel.sharded_steps")
+        return b
+
+    def _bind(self, program, fp, compiled, scope, feed, fetch_list,
+              feed_arrays, step_fn):
+        """Keep what this call resolved to, if every later call of its
+        signature can be vouched for by _find_bound's tests."""
+        from .resilience.faults import injector as _fault_injector
+        key = self._bound_key(program, compiled, scope, feed, fetch_list)
+        if (key is None or scope.parent is not None or program.lod_link
+                or list(feed_arrays) != list(feed)
+                or _fault_injector() is not None):
+            return
+        plan = []
+        for name, val in feed.items():
+            staged = feed_arrays[name]
+            cast = staged.dtype if staged.dtype != val.dtype else None
+            ns = compiled.feed_sharding(val.shape) \
+                if compiled is not None else None
+            plan.append((name, cast, ns))
+        cache_key = self._last_key
+        if self._cache.get(cache_key) is not step_fn:
+            return
+        self._pin_state(step_fn, scope)
+        self._bound[key] = _BoundStep(
+            program, fp, compiled, scope, _flags.generation(), step_fn,
+            cache_key, plan)
+        self._bound_binds += 1
+        STAT_ADD("executor.bound_step_binds")
+        cap = _flags.FLAGS.executor_cache_capacity
+        while cap > 0 and len(self._bound) > cap:
+            self._bound.popitem(last=False)
+
+    @staticmethod
+    def _pin_state(step_fn, scope):
+        try:
+            return scope.pin(step_fn, step_fn.pinned_names,
+                             _as_device_array)
+        except KeyError as e:
+            raise _not_initialised(e.args[0]) from None
+
+    def _stage_bound(self, bound, scope, feed):
+        """A bound call's resolve: stage the feeds by the plan, take the
+        pinned state from the scope's view (gathered again only when a
+        pinned name was written: a rebind, no recompilation) and the
+        donated state from the scope."""
+        step_fn = bound.step_fn
+        with _trace.region("executor.feed"):
+            t0 = time.perf_counter()
+            feed_arrays = {}
+            presharded = 0
+            for (name, cast, ns), val in zip(bound.feed_plan,
+                                             feed.values()):
+                staged = cast is not None
+                if staged:
+                    val = val.astype(cast)
+                if ns is not None and not (
+                        isinstance(val, jax.Array)
+                        and val.sharding.is_equivalent_to(ns, val.ndim)):
+                    val = jax.device_put(val, ns)
+                    staged = True
+                if not staged and isinstance(val, jax.Array):
+                    presharded += 1
+                feed_arrays[name] = val
+            self._last_feed_s = time.perf_counter() - t0
+            if _monitor_on():
+                self._note_feed(feed_arrays, presharded)
+        pinned = scope.pinned_view(step_fn)
+        if pinned is None:
+            pinned = self._pin_state(step_fn, scope)
+            self._bound_rebinds += 1
+            STAT_ADD("executor.bound_step_rebinds")
+        donated = {}
+        for n in step_fn.donated_names:
+            v = scope.find_var(n)
+            if v is None:
+                raise _not_initialised(n)
+            donated[n] = _as_device_array(v)
+        return donated, pinned, feed_arrays
+
+    def _drop_bound(self, dead=None):
+        """Forget the bindings whose executable `dead(cache_key)` names
+        (all without it), and the views they left on their scopes."""
+        for key, b in list(self._bound.items()):
+            if dead is None or dead(b.cache_key):
+                del self._bound[key]
+                scope = b.scope_ref()
+                if scope is not None:
+                    scope.drop_view(b.step_fn)
 
     # ------------------------------------------------------------------
     def _resolve_step(self, program, feed, fetch_list, scope, compiled,
@@ -332,7 +554,8 @@ class Executor:
                                    for n, a in feed_arrays.items()},
                       fetch_names=fetch_names, where="executor")
 
-        key = self._cache_key(program, feed_arrays, fetch_names, compiled)
+        key = self._last_key = self._cache_key(
+            program, feed_arrays, fetch_names, compiled)
         step_fn = self._cache.get(key) if use_program_cache else None
         self._last_cache_hit = step_fn is not None
         if step_fn is not None:
@@ -366,6 +589,7 @@ class Executor:
             while cap > 0 and len(self._cache) > cap:
                 old_key, _ = self._cache.popitem(last=False)
                 STAT_ADD("executor.compile_cache_evictions")
+                self._drop_bound(lambda k: k == old_key)
                 # drop the compiled-program strong ref if no other cache
                 # entry still uses it
                 cid = old_key[3]
@@ -379,10 +603,14 @@ class Executor:
         for n in step_fn.state_in_names:
             v = scope.find_var(n)
             if v is None:
-                raise RuntimeError(
-                    f"persistable var {n!r} is not initialised — run the "
-                    f"startup program first")
-            state[n] = v if isinstance(v, jax.Array) else jnp.asarray(v)
+                raise _not_initialised(n)
+            if not isinstance(v, jax.Array):
+                # the scope takes the device array too, as the step's
+                # write-back would have given it: a weight is uploaded
+                # once, not by every call that finds it on the host
+                v = jnp.asarray(v)
+                scope.set(n, v)
+            state[n] = v
         return step_fn, state, feed_arrays
 
     @staticmethod
@@ -503,21 +731,23 @@ class Executor:
                     f"reshape the feed or fix the data layer")
         self._last_feed_s = time.perf_counter() - t0
         if _monitor_on():
-            total = host = 0
-            for a in out.values():
-                nb = int(getattr(a, "nbytes", 0) or 0)
-                total += nb
-                if isinstance(a, np.ndarray):
-                    host += nb  # will cross host->device inside the step
-            STAT_ADD("executor.feed_bytes", total)
-            STAT_ADD("executor.feed_host_bytes", host)
-            # feeds that arrived already committed to the target
-            # sharding/device and were handed through untouched
-            STAT_ADD("exec.feed_presharded", presharded)
-            STAT_OBSERVE("executor.feed_stage_seconds",
-                         self._last_feed_s,
-                         exemplar=_trace.current_trace_id())
+            self._note_feed(out, presharded)
         return out
+
+    def _note_feed(self, staged, presharded):
+        total = host = 0
+        for a in staged.values():
+            nb = int(getattr(a, "nbytes", 0) or 0)
+            total += nb
+            if isinstance(a, np.ndarray):
+                host += nb  # will cross host->device inside the step
+        STAT_ADD("executor.feed_bytes", total)
+        STAT_ADD("executor.feed_host_bytes", host)
+        # feeds that arrived already committed to the target
+        # sharding/device and were handed through untouched
+        STAT_ADD("exec.feed_presharded", presharded)
+        STAT_OBSERVE("executor.feed_stage_seconds", self._last_feed_s,
+                     exemplar=_trace.current_trace_id())
 
     def _cache_key(self, program, feed_arrays, fetch_names, compiled):
         from .core.flags import trace_signature
@@ -554,20 +784,32 @@ class Executor:
                            (produced_global | set(state_in)))
         seed = program.random_seed
 
-        # Donation plan (analysis/passes/donation.py, graph_opt_level=2):
-        # donate only the hazard-free inplace-updated subset of state,
-        # pin the rest, and drop never-written pinned vars from the
-        # returned state so XLA emits no output copy for them at all.
-        # Every donated input must come back as an output, else its
-        # scope buffer is invalidated with no replacement.
+        # What is donated. Every donated input must come back as an
+        # output, else its scope buffer is invalidated with no
+        # replacement; what is not donated is passed pinned and, where
+        # no op produces it, not returned either, so XLA emits no
+        # output for it at all and the scope keeps the array it has.
+        # - a CompiledProgram: the whole state (build_jit pins the
+        #   outputs' shardings to the inputs');
+        # - a donation plan (analysis/passes/donation.py,
+        #   graph_opt_level=2): the hazard-free inplace-updated subset;
+        # - otherwise: the state inputs that some op of some block
+        #   produces. An inference step donates its KV pools and pins
+        #   its weights; a training step donates everything it updates.
         donate_plan = getattr(program, "_donation_plan", None)
-        donate_names = None
-        if compiled is None and donate_plan is not None:
+        if compiled is not None:
+            donate_names = frozenset(state_in)
+        elif donate_plan is not None:
             state_out = sorted(n for n in state_out
                                if n in produced_global)
             donate_names = frozenset(
                 n for n in state_in
                 if n in donate_plan and n in set(state_out))
+        else:
+            donate_names = frozenset(n for n in state_in
+                                     if n in produced_all)
+            state_out = sorted(persistables &
+                               (produced_global | donate_names))
 
         mesh = compiled.mesh() if compiled is not None and \
             compiled._is_data_parallel else None
@@ -579,7 +821,9 @@ class Executor:
                 f"FLAGS_prng_impl={prng_impl!r}: expected '', "
                 f"'threefry2x32', 'rbg' or 'unsafe_rbg'")
 
-        def step(state, feeds, step_idx):
+        def step(donated_state, pinned_state, feeds, step_idx):
+            state = dict(pinned_state)
+            state.update(donated_state)
             env = dict(state)
             env.update(feeds)
             if prng_impl:
@@ -601,22 +845,17 @@ class Executor:
                     new_state[n] = v
             return fetches, new_state
 
+        # ONE calling shape and one name: the device's `XLA Modules`
+        # line reads jit_step on every path (the benchmark's trace
+        # reduction counts a slice's runs by it)
         if compiled is not None:
-            fn = compiled.build_jit(step, state_in, feed_arrays,
-                                    state_out_names=state_out)
-        elif donate_names is not None:
-            def planned_step(donated_state, pinned_state, feeds,
-                             step_idx):
-                merged = dict(pinned_state)
-                merged.update(donated_state)
-                return step(merged, feeds, step_idx)
-            fn = _PlannedDonateStep(
-                jax.jit(planned_step, donate_argnums=(0,)),
-                donate_names)
+            jit_fn = compiled.build_jit(step, state_in, feed_arrays,
+                                        state_out_names=state_out)
         else:
-            fn = jax.jit(step, donate_argnums=(0,))
-        return _CompiledStep(fn, state_in, state_out, fetch_names,
-                             donate_names=donate_names)
+            jit_fn = jax.jit(step, donate_argnums=(0,))
+        return _CompiledStep(_SplitStateStep(jit_fn, donate_names),
+                             state_in, state_out, fetch_names,
+                             donate_names)
 
     def lowered_stablehlo(self, program=None, feed=None, fetch_list=None,
                           scope: Optional[Scope] = None) -> str:
@@ -638,7 +877,7 @@ class Executor:
         step_fn, state, feed_arrays = self._resolve_step(
             program, feed, fetch_list, scope, compiled)
         return step_fn.fn.lower(state, feed_arrays,
-                                jnp.uint32(0)).as_text()
+                                np.uint32(0)).as_text()
 
     def lowered_mlir_debug(self, program=None, feed=None, fetch_list=None,
                            scope: Optional[Scope] = None) -> str:
@@ -660,7 +899,7 @@ class Executor:
         step_fn, state, feed_arrays = self._resolve_step(
             program, feed, fetch_list, scope, compiled)
         ir = step_fn.fn.lower(state, feed_arrays,
-                              jnp.uint32(0)).compiler_ir(
+                              np.uint32(0)).compiler_ir(
                                   dialect="stablehlo")
         return ir.operation.get_asm(enable_debug_info=True)
 
@@ -686,12 +925,13 @@ class Executor:
         scope = scope or global_scope()
         step_fn, state, feed_arrays = self._resolve_step(
             program, feed, fetch_list, scope, compiled)
-        # the same default device as run(): it is part of jit's cache
-        # key, and without it a step that already ran is traced, lowered
-        # and compiled all over again
+        # the same default device and the same kinds of argument as
+        # run() (the step counter a host uint32): they are part of
+        # jit's cache key, and without them a step that already ran is
+        # traced, lowered and compiled all over again
         with jax.default_device(self.place.jax_device()):
             return step_fn.fn.lower(state, feed_arrays,
-                                    jnp.uint32(0)).compile()
+                                    np.uint32(0)).compile()
 
     def compiled_hlo(self, program=None, feed=None, fetch_list=None,
                      scope: Optional[Scope] = None) -> str:
@@ -707,9 +947,16 @@ class Executor:
         executor.compile_cache_* stats aggregate every Executor in the
         process; warmup-coverage checks need this one's)."""
         return {"hits": self._cache_hits, "misses": self._cache_misses,
-                "size": len(self._cache)}
+                "size": len(self._cache),
+                # calls that ran a bound step (each also a hit), call
+                # signatures bound, and bound steps that gathered their
+                # pinned state again because the scope was written
+                "bound_step_hits": self._bound_hits,
+                "bound_step_binds": self._bound_binds,
+                "bound_step_rebinds": self._bound_rebinds}
 
     def close(self):
+        self._drop_bound()
         self._cache.clear()
         self._compiled_refs.clear()
 

@@ -417,7 +417,11 @@ def span(name: str, attrs: Optional[dict] = None):
 # Regions of the program's own host code, and the iteration record
 # ---------------------------------------------------------------------------
 
-ITERATION_RING = 4096    # some 18 minutes of 270 ms iterations
+# A reader takes the whole of a 40 s serving window from the ring after
+# the window closed: 16,384 records cover it down to turns of 2.5 ms
+# (a turn of a 24-layer engine took 14 ms at 4,096, and is meant to
+# shrink). A record is some 600 bytes with its host_s dict: 10 MB full.
+ITERATION_RING = 16384
 
 _ITERATIONS: "deque" = deque(maxlen=ITERATION_RING)
 _ITERATION: "contextvars.ContextVar[Optional[IterationRecord]]" = \

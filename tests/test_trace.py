@@ -566,6 +566,9 @@ def test_region_seconds_go_to_the_open_iteration_exclusively():
 
 
 def test_iteration_ring_is_bounded_and_drops_turns_that_only_waited():
+    # the benchmark's counters read a whole 40 s window from the ring
+    # after it closed: it has to hold one at turns of 2.5 ms
+    assert trace.ITERATION_RING >= 16384
     trace.reset()
     rec = trace.begin_iteration(1, 0, 0)
     trace.end_iteration(rec, keep=False)
