@@ -18,7 +18,7 @@ belongs to one process at a time):
              calls in the compiled HLO; first-step loss against the
              composed path on the same weights and batch
   serve      GPT-small (12 layers, d 768, vocab 32000, max_seq 1024),
-             GenerationEngine(paged=True) with 8 slots behind
+             GenerationEngine with 8 slots behind
              serving.serve(port=0): eight POST /v1/generate, four at a
              time, prompts of 5..300 tokens, 32 new tokens each;
              /healthz, /metrics, zero post-warmup compiles, tokens equal
@@ -467,7 +467,7 @@ def phase_serve(smoke):
     cfg = gpt.gpt_small(dropout=0.0, **size["cfg"])
     scope = fluid.Scope()
     engine = GenerationEngine(cfg, scope, max_slots=size["slots"],
-                              max_seq=cfg.max_seq_len, paged=True)
+                              max_seq=cfg.max_seq_len)
     engine.init_scope()  # random weights from the program's seed
     rng = np.random.RandomState(0)
     prompts = [rng.randint(0, cfg.vocab_size, n).tolist()

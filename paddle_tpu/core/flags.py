@@ -428,15 +428,6 @@ DEFINE_int32(
     "the dynamic batcher before submissions are rejected with "
     "QueueFullError (backpressure instead of unbounded queueing).")
 
-DEFINE_bool(
-    "gen_paged_kv", True,
-    "Generation engine KV layout: True (default) = block-table paged "
-    "KV cache (serving/kv_blocks.py + models/gpt."
-    "build_paged_decode_step) with prefix caching and chunked prefill; "
-    "False = the PR-7 contiguous [max_slots, max_seq] slab decode, "
-    "retained for a paged-vs-slab A/B. Host-side program choice only — not part "
-    "of any executable cache key.")
-
 DEFINE_int32(
     "gen_kv_block_size", 16,
     "Paged KV cache: tokens per physical block. Larger blocks mean "

@@ -17,7 +17,7 @@ from . import transformer
 
 __all__ = ["gpt_small", "gpt_medium", "build_train", "greedy_generate",
            "DecodeStep", "build_decode_step", "PagedDecodeStep",
-           "build_paged_decode_step", "build_spec_verify_step",
+           "build_paged_decode_step",
            "kv_generate", "beam_generate"]
 
 
@@ -489,40 +489,6 @@ def build_paged_decode_step(cfg, batch, max_seq, block_size, num_blocks,
     return PagedDecodeStep(token, out, cache_names, table, start,
                            nvalid, batch, max_seq, block_size,
                            num_blocks, T, state_prefix)
-
-
-def build_spec_verify_step(cfg, batch, max_seq, block_size, num_blocks,
-                           k, state_prefix=""):
-    """Speculative-decoding verify step: the `[batch, k+1]` multi-token
-    sibling of the paged decode executable (`seq_tokens = k+1`,
-    `with_logits = True`), scoring a slot's committed token plus up to
-    `k` draft tokens in ONE dispatch.
-
-    Row b feeds `[cur, d_1..d_n, pad...]` at `start_pos = fed` with
-    `n_valid = 1+n` — the draft tokens scatter through the SAME block
-    table (and the same `state_prefix` K/V pools) as the decode step,
-    and the `paged_attention` causal mask makes position j's logits
-    condition on exactly the tokens a serial decode would have fed, so
-    the returned `[batch, k+1, vocab]` logits are bit-identical to k+1
-    sequential decode steps. The host accepts a draft prefix via
-    `models/sampling.accept_draft` and re-feeds from the first
-    rejection; rejected positions' pool writes are harmless — they sit
-    past the slot's advanced write cursor and are overwritten before
-    any mask ever exposes them. A draft-less slot rides along with
-    `n_valid = 1`, making this step a strict superset of the decode
-    step — the engine can route every decode iteration through it
-    without a scheduling special case.
-
-    One more fixed shape, compiled once in `GenerationEngine.start()`
-    warmup next to decode + chunked prefill: `post_warmup_compiles()`
-    still reads 0 for the engine's lifetime."""
-    if k < 1:
-        raise ValueError(f"build_spec_verify_step: k must be >= 1, "
-                         f"got {k}")
-    return build_paged_decode_step(
-        cfg, batch=batch, max_seq=max_seq, block_size=block_size,
-        num_blocks=num_blocks, seq_tokens=int(k) + 1,
-        state_prefix=state_prefix, with_logits=True)
 
 
 def _ensure_decode_state(scope, blk, cache_names):

@@ -41,15 +41,9 @@ class TransformerConfig:
         # attention WEIGHTS is a separate knob: the flash kernel does not
         # implement it, so attn_dropout > 0 forces the composed path
         # (keeping the trained model identical across kernel choices).
-        # "auto" = the measured-crossover heuristic: flash only from
-        # ops/attention.py:FLASH_AUTO_MIN_SEQ (4096) up. The r05
-        # microbench has blk=512 flash ~2x faster than composed at seq
-        # 512 in isolation (2.64 vs 5.47 ms fwd+bwd), but end-to-end
-        # flash LOST 37% tok/s at seq 512 (55.5k vs 88.4k) and the gap
-        # widened with batch; at 2048 the paths are within noise, so
-        # the flip sits where the tiled kernel's end-to-end win is
-        # unambiguous (docs/attention_tuning.md has the full history
-        # and the re-measurement recipe).
+        # "auto" = flash only from ops/attention.py:FLASH_AUTO_MIN_SEQ
+        # (4096) up; where the flip belongs is not measured on the chip
+        # (the comment there).
         if use_flash == "auto":
             from ..ops.attention import FLASH_AUTO_MIN_SEQ
             use_flash = max_seq_len >= FLASH_AUTO_MIN_SEQ
@@ -71,8 +65,8 @@ class TransformerConfig:
     # What `serving.GenerationEngine` asks of a model's configuration
     # (models/hybrid.HybridConfig answers the same three).
     def build_paged_step(self, **kw):
-        """The paged decode / chunk-prefill program of this model
-        (`gpt.build_paged_decode_step`)."""
+        """The paged decode / chunk-prefill / verify program of this
+        model (`gpt.build_paged_decode_step`)."""
         from . import gpt
         return gpt.build_paged_decode_step(self, **kw)
 

@@ -22,15 +22,10 @@ from .pallas.paged_attention import pad_lanes, paged_attention_read
 
 # use_flash="auto" crossover (models/transformer.py consults this):
 # enable the tiled kernel only at max_seq_len >= this many tokens.
-# Measured, not theoretical: the fwd+bwd microbench (tools/attn_micro.py)
-# has flash ahead at seq 512 in isolation, but end-to-end training at
-# seq 512 LOST 37% tok/s (55.5k vs 88.4k) when flash shipped always-on
-# with a hard-coded 128 tile, and the gap widened with batch. The
-# composed matmul+softmax path only starts losing outright once the
-# O(T^2) score tensor dominates — at 2048 the two are within noise
-# either way, so the flip sits at 4096 where the tiled kernel's win is
-# unambiguous at every batch measured. Full history + methodology:
-# docs/attention_tuning.md.
+# Where the flip belongs is not measured on the chip: the timings that
+# set it (docs/attention_tuning.md) are of a setup that is gone, no
+# cell of the benchmark runs the kernel, and ROADMAP.md queue 1, item
+# 11 (b) settles it or deletes it.
 FLASH_AUTO_MIN_SEQ = 4096
 
 

@@ -13,9 +13,10 @@ traffic (`ServingEngine.warmup`). A stdlib HTTP front end
 
 Autoregressive LLM traffic goes through `GenerationEngine`
 (serving/generation.py): Orca-style continuous batching over the
-multi-slot KV-cache decode step of models/gpt.py — requests join and
-leave a running decode batch between steps, with the whole serving
-lifetime covered by ONE compiled executable.
+paged programs the model's configuration builds
+(`cfg.build_paged_step`) — requests join and leave a running decode
+batch between steps, with the whole serving lifetime covered by the
+two or three executables that `start()` compiles.
 
 Quick start::
 

@@ -24,7 +24,7 @@ decode worker continuing on adopted blocks emits exactly the tokens
 the unified engine would.
 
 Engine access is serialized against the engine's worker thread via
-`engine._kv_mutex` (held by the worker around each paged iteration),
+`engine._kv_mutex` (held by the worker around each iteration),
 because BlockPool/PrefixCache are not thread-safe on their own.
 """
 from __future__ import annotations
@@ -41,12 +41,8 @@ from . import kv_wire
 from .kv_blocks import PrefixCache
 
 
-def _require_paged(engine):
-    if not getattr(engine, "paged", False):
-        raise ValueError(
-            "disaggregated KV transfer needs a paged engine "
-            "(FLAGS_gen_paged_kv / paged=True)")
-    if getattr(engine, "recurrent", False):
+def _refuse_recurrent(engine):
+    if engine.recurrent:
         raise ValueError(
             f"disaggregated KV transfer cannot serve "
             f"{type(engine.cfg).__name__}: a shipped KV block carries "
@@ -83,7 +79,7 @@ def export_prefix(engine, prompt: Sequence[int],
     every full prompt block in the PrefixCache before the first token
     is returned) — the prefill worker's actual job.
     """
-    _require_paged(engine)
+    _refuse_recurrent(engine)
     prompt = [int(t) for t in prompt]
     n_full = len(prompt) // engine.block_size
     if n_full == 0:
@@ -127,7 +123,7 @@ def adopt_prefix(engine, payload: dict) -> dict:
     exhaustion stops adoption early — a leading sub-chain is still a
     valid prefix, the decode worker just re-prefills the tail.
     """
-    _require_paged(engine)
+    _refuse_recurrent(engine)
     ship = payload if isinstance(payload, kv_wire.KVShipment) \
         else kv_wire.unpack_blocks(payload)
     if ship.block_size != engine.block_size:

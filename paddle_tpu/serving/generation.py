@@ -9,32 +9,34 @@ to a queued request immediately rather than waiting for the whole batch
 to finish.
 
 On TPU the constraint that shapes this design is XLA shape
-specialization: the decode step must be ONE fixed-shape executable for
-the engine's whole lifetime. `models/gpt.py:build_decode_step` therefore
-carries a per-slot `decode_pos` vector plus `slot_reset`/`slot_active`
-feeds: a new request joins a running batch by feeding reset=1 on its
-slot (the graph zeroes that slot's K/V rows in-device — no host zero
-upload, no recompile), and an empty slot rides along muted with
-active=0. Admission, prefill (prompt tokens stepped through the same
-graph), sampling (host-side, models/sampling.py), eviction and
-re-admission all happen without ever presenting XLA a novel shape —
-`Executor.cache_stats()` misses stay frozen after the single warmup
-compile, the same zero-post-warmup-compile contract `ServingEngine`
-keeps for encoder traffic.
+specialization: each step must be ONE fixed-shape executable for the
+engine's whole lifetime. The engine asks the model's configuration for
+its programs (`cfg.build_paged_step`: models/transformer.
+TransformerConfig, models/hybrid.HybridConfig), and every one of them
+takes the same per-step control feeds, a `block_table` row, a
+`start_pos` and an `n_valid` for each slot: a new request joins a
+running batch by being given blocks and a row of the table (no host
+zero upload, no recompile), and an empty slot rides along muted with
+n_valid=0, its writes landing in the scratch block. Admission, prefill,
+sampling (host-side, models/sampling.py), eviction and re-admission all
+happen without ever presenting XLA a novel shape —
+`Executor.cache_stats()` misses stay frozen after the warmup compiles,
+the same zero-post-warmup-compile contract `ServingEngine` keeps for
+encoder traffic.
 
 Queueing reuses the `batcher.py` vocabulary: bounded queue with
 `QueueFullError` backpressure, per-request deadlines failing with
 `DeadlineExceededError`, `EngineClosedError` + drain semantics on
 shutdown, `_Response` future handles.
 
-Paged KV (FLAGS_gen_paged_kv, the default): instead of one contiguous
-`[max_slots, max_seq]` slab per layer, K/V lives in per-layer physical
-POOLS of fixed-size blocks (`serving/kv_blocks.py`), addressed through
-per-slot block tables fed to the `paged_attention` op every step; the
-op reads a slot's table only as far as the slot's own length (a Pallas
-kernel, ops/pallas/paged_attention.py), so a step costs what the slots
-hold and not `max_seq`, and its outputs agree with the slab path token
-for token. Peak KV HBM becomes `num_blocks x block_bytes` — budget-derived and
+Paged KV: K/V lives in per-layer physical POOLS of fixed-size blocks
+(`serving/kv_blocks.py`), addressed through per-slot block tables fed
+to the `paged_attention` op every step; the op reads a slot's table
+only as far as the slot's own length (a Pallas kernel,
+ops/pallas/paged_attention.py), so a step costs what the slots hold and
+not `max_seq`, and its outputs agree token for token with the serial
+reference (`gpt.kv_generate` over one contiguous `[batch, max_seq]`
+cache). Peak KV HBM is `num_blocks x block_bytes` — budget-derived and
 decoupled from the longest POSSIBLE sequence — and three scheduler
 moves fall out of the indirection: admission gates on free BLOCKS
 (actual tokens) rather than slots alone; a slot "reset" is just
@@ -45,22 +47,21 @@ same physical blocks and skip re-prefill. Long prompts retire through
 a second fixed-shape executable that prefills a whole block per step
 (chunked prefill), so a 10k-token prompt costs ~10k/block_size
 iterations interleaved with — never stalling — the decode batch. The
-compile contract widens from one executable to exactly two (decode +
-chunk prefill), both compiled in `start()`: `post_warmup_compiles()`
-stays 0 for the engine's lifetime either way.
+compile contract is exactly two executables (decode + chunk prefill),
+both compiled in `start()`: `post_warmup_compiles()` stays 0 for the
+engine's lifetime.
 
 Speculative decoding (FLAGS_gen_spec_decode / GenerationRequest
-.spec_decode, paged engines only): a host-side n-gram drafter
-(`serving/spec_decode.py`) proposes up to FLAGS_spec_decode_k tokens per
-slot between steps, and a THIRD fixed-shape executable — the
-`[max_slots, k+1]` batched verify step (`models/gpt.py:
-build_spec_verify_step`) — scores every draft position in one pass.
-`models/sampling.py:accept_draft` commits the longest agreeing prefix
-through the same sample_token path as serial decode, so outputs stay
-token-for-token identical at any temperature; each accepted token skips
-one whole decode iteration. The verify executable is compiled in
-`start()` alongside the other two, keeping `post_warmup_compiles()` at
-0.
+.spec_decode): a host-side n-gram drafter (`serving/spec_decode.py`)
+proposes up to FLAGS_spec_decode_k tokens per slot between steps, and a
+THIRD fixed-shape executable — the `[max_slots, k+1]` batched verify
+step, `cfg.build_paged_step(seq_tokens=k+1)` — scores every draft
+position in one pass. `models/sampling.py:accept_draft` commits the
+longest agreeing prefix through the same sample_token path as serial
+decode, so outputs stay token-for-token identical at any temperature;
+each accepted token skips one whole decode iteration. The verify
+executable is compiled in `start()` alongside the other two, keeping
+`post_warmup_compiles()` at 0.
 """
 from __future__ import annotations
 
@@ -179,7 +180,7 @@ class _SlotState:
     """Per-occupied-slot decode progress (worker-thread private)."""
 
     __slots__ = ("req", "response", "fed", "cur", "generated", "rng",
-                 "needs_reset", "deadline", "t_submit", "t_prev_token",
+                 "deadline", "t_submit", "t_prev_token",
                  "ttft_ms", "blocks", "n_cached", "registered",
                  "span", "phase_span", "fetch_s",
                  "spec_k_cur", "spec_acc_ewma")
@@ -193,7 +194,6 @@ class _SlotState:
         self.cur = req.prompt[0]      # next token to feed
         self.generated: List[int] = []
         self.rng = np.random.RandomState(req.seed)
-        self.needs_reset = True       # feed slot_reset=1 on first step
         self.deadline = deadline
         self.t_submit = t_submit
         self.t_prev_token: Optional[float] = None
@@ -235,12 +235,12 @@ class GenerationEngine:
     """Iteration-level (continuous-batching) generation service.
 
     Construct with a trained `scope` (weights under the training-graph
-    names) and the model's TransformerConfig; the engine builds its own
-    `max_slots`-wide decode program whose STATE names carry
-    `state_prefix`, so it can share the scope with training graphs or a
-    serial batch=1 decode graph without collision. Lifecycle mirrors
-    `ServingEngine`: `start()` (state init + one warmup step = the one
-    compile of the engine's lifetime), `submit`/`generate` from any
+    names) and the model's configuration; the engine builds its own
+    `max_slots`-wide programs whose STATE names carry `state_prefix`,
+    so it can share the scope with training graphs or a serial batch=1
+    decode graph without collision. Lifecycle mirrors `ServingEngine`:
+    `start()` (state init + one warmup step an executable = all the
+    compiles of the engine's lifetime), `submit`/`generate` from any
     thread, `stop(drain=True)`.
     """
 
@@ -258,8 +258,14 @@ class GenerationEngine:
                  spec_adaptive: Optional[bool] = None):
         import paddle_tpu as fluid
         from ..core.flags import FLAGS
-        from ..models import gpt
 
+        # `paged` is there for benchmark/families/*_serve.py, which pass
+        # True, and goes with the `benchmark` issue that retires their
+        # wrapper of `_run_paged` (PERF.md, Open question 11).
+        if paged is not None and not paged:
+            raise ValueError(
+                "GenerationEngine(paged=False): the slab-KV engine was "
+                "removed in PR 31; the engine serves paged KV only")
         # The model's configuration says how it is served: it builds
         # the paged programs (`build_paged_step`) and prices a token of
         # KV and a slot of recurrent state (`kv_token_bytes`,
@@ -278,58 +284,37 @@ class GenerationEngine:
         self.default_timeout_ms = (
             default_timeout_ms if default_timeout_ms is not None
             else FLAGS.serving_default_timeout_ms)
-        self.paged = bool(FLAGS.gen_paged_kv if paged is None else paged)
         # the decode-step program(s); their startup is never run (it
         # would re-init the shared trained weights) — state is seeded
         # by _ensure_decode_state in start()
         self._prog = fluid.Program()
         self._startup = fluid.Program()
-        self._prefill_prog = None
-        self._pool: Optional[BlockPool] = None
-        self._prefix: Optional[PrefixCache] = None
-        if self.paged:
-            self.block_size = int(
-                min(block_size if block_size is not None
-                    else FLAGS.gen_kv_block_size, self.max_seq))
-            self.num_blocks = self._resolve_pool_blocks(kv_pool_blocks)
-            with fluid.program_guard(self._prog, self._startup):
-                self.step = cfg.build_paged_step(
-                    batch=self.max_slots, max_seq=self.max_seq,
+        self.block_size = int(
+            min(block_size if block_size is not None
+                else FLAGS.gen_kv_block_size, self.max_seq))
+        self.num_blocks = self._resolve_pool_blocks(kv_pool_blocks)
+        # what every program of the engine is built with but its tokens
+        # a row
+        dims = dict(batch=self.max_slots, max_seq=self.max_seq,
                     block_size=self.block_size,
-                    num_blocks=self.num_blocks, seq_tokens=1,
-                    state_prefix=state_prefix)
-            # the second (and last) executable of the lifetime: retires
-            # one whole block of prompt per row per step
-            self._prefill_prog = fluid.Program()
-            self._prefill_startup = fluid.Program()
-            with fluid.program_guard(self._prefill_prog,
-                                     self._prefill_startup):
-                self.prefill_step = cfg.build_paged_step(
-                    batch=self.max_slots, max_seq=self.max_seq,
-                    block_size=self.block_size,
-                    num_blocks=self.num_blocks,
-                    seq_tokens=self.block_size,
-                    state_prefix=state_prefix, with_logits=False)
-            self._pool = BlockPool(self.num_blocks, self.block_size)
-            self._prefix = PrefixCache(self._pool)
-        else:
-            if cfg.state_slot_bytes():
-                raise ValueError(
-                    "a model with recurrent layers is served paged: the "
-                    "slab decode graph carries no per-slot state "
-                    "(paged=True)")
-            spec_decode = False  # the slab graph has no verify substrate
-            self.block_size = 0
-            self.num_blocks = 0
-            with fluid.program_guard(self._prog, self._startup):
-                self.step = gpt.build_decode_step(
-                    cfg, batch=self.max_slots, max_seq=self.max_seq,
-                    state_prefix=state_prefix)
-        # speculative decoding (serving/spec_decode.py): paged-only —
-        # the verify step is the THIRD and last fixed-shape executable,
-        # sharing the decode/prefill programs' K/V pools via
-        # state_prefix. Engines with spec off build nothing extra and
-        # keep the two-executable warmup unchanged.
+                    num_blocks=self.num_blocks, state_prefix=state_prefix)
+        with fluid.program_guard(self._prog, self._startup):
+            self.step = cfg.build_paged_step(seq_tokens=1, **dims)
+        # the second (and last) executable of the lifetime: retires
+        # one whole block of prompt per row per step
+        self._prefill_prog = fluid.Program()
+        self._prefill_startup = fluid.Program()
+        with fluid.program_guard(self._prefill_prog,
+                                 self._prefill_startup):
+            self.prefill_step = cfg.build_paged_step(
+                seq_tokens=self.block_size, with_logits=False, **dims)
+        self._pool = BlockPool(self.num_blocks, self.block_size)
+        self._prefix = PrefixCache(self._pool)
+        # speculative decoding (serving/spec_decode.py): the verify
+        # step is the THIRD and last fixed-shape executable, sharing
+        # the decode/prefill programs' K/V pools via state_prefix.
+        # Engines with spec off build nothing extra and keep the
+        # two-executable warmup unchanged.
         self.spec_decode = bool(FLAGS.gen_spec_decode
                                 if spec_decode is None else spec_decode)
         self.spec_k = int(spec_k if spec_k is not None
@@ -341,7 +326,7 @@ class GenerationEngine:
         # their per-slot state moves forward only. A verify step would
         # advance it through drafts that are then rejected, and nothing
         # rolls it back; a cached KV block carries none of it.
-        self.recurrent = bool(self.paged and self.step.state_names)
+        self.recurrent = bool(self.step.state_names)
         if self.recurrent and self.spec_decode and self.spec_k >= 1:
             raise ValueError(
                 f"speculative decoding (spec_k={self.spec_k}) cannot "
@@ -353,11 +338,8 @@ class GenerationEngine:
             self._spec_startup = fluid.Program()
             with fluid.program_guard(self._spec_prog,
                                      self._spec_startup):
-                self.spec_step = gpt.build_spec_verify_step(
-                    cfg, batch=self.max_slots, max_seq=self.max_seq,
-                    block_size=self.block_size,
-                    num_blocks=self.num_blocks, k=self.spec_k,
-                    state_prefix=state_prefix)
+                self.spec_step = cfg.build_paged_step(
+                    seq_tokens=self.spec_k + 1, **dims)
             self._drafter = NgramDrafter(
                 max_ngram=int(FLAGS.spec_decode_ngram), k=self.spec_k)
         else:
@@ -384,7 +366,7 @@ class GenerationEngine:
         self._worker: Optional[threading.Thread] = None
         self._ready = threading.Event()
         self._warm_misses: Optional[int] = None
-        # the side-fetch of the last paged step, where the model has one
+        # the side-fetch of the last step, where the model has one
         self._probe: Optional[np.ndarray] = None
         # resilience: a failed decode step fails the requests that were
         # mid-step (their KV state is unreplayable) but never the
@@ -401,8 +383,6 @@ class GenerationEngine:
         as the model's configuration prices a token (`kv_token_bytes`:
         the layers that attend, their KV heads, the pool's type, in
         whole lane tiles)."""
-        if not self.paged:
-            return 0
         return self.block_size * self.cfg.kv_token_bytes()
 
     def state_bytes(self) -> int:
@@ -413,10 +393,7 @@ class GenerationEngine:
 
     def kv_pool_bytes(self) -> int:
         """Total K/V pool HBM across layers — what the static memory
-        planner prices for the paged program (pool persistables)."""
-        if not self.paged:
-            return 2 * self.cfg.n_layers * self.max_slots * \
-                self.max_seq * self.cfg.d_model * 4
+        planner prices for the engine's programs (pool persistables)."""
         return self.num_blocks * self.kv_block_bytes()
 
     def _resolve_pool_blocks(self, kv_pool_blocks) -> int:
@@ -455,21 +432,13 @@ class GenerationEngine:
 
     def executables(self):
         """The fixed-shape executables of the engine's lifetime, as
-        (name, program, feed, fetch_var) with every slot muted: slab —
-        one decode step; paged — decode + chunk prefill (+ the spec
-        verify step). `start()` warms exactly these; a caller can hand
-        one to `exe.compiled(...)` to read its HLO or XLA's memory
-        analysis, or run it again with other feed containers of the
-        same shapes to check that nothing recompiles."""
+        (name, program, feed, fetch_var) with every slot muted: decode
+        + chunk prefill (+ the spec verify step). `start()` warms
+        exactly these; a caller can hand one to `exe.compiled(...)` to
+        read its HLO or XLA's memory analysis, or run it again with
+        other feed containers of the same shapes to check that nothing
+        recompiles."""
         B = self.max_slots
-        if not self.paged:
-            return [("decode", self._prog,
-                     {self.step.token_var.name:
-                      np.zeros((B, 1), np.int64),
-                      self.step.reset_var.name: np.ones(B, np.float32),
-                      self.step.active_var.name:
-                      np.zeros(B, np.float32)},
-                     self.step.logits_var)]
         mb = self.step.max_blocks_per_slot
         return [(name, prog,
                  {step.token_var.name: np.zeros((B, t), np.int64),
@@ -480,8 +449,8 @@ class GenerationEngine:
                 for name, prog, step, t in self._paged_cells()]
 
     def _paged_cells(self):
-        """(name, program, step handle, tokens a row) of the paged
-        engine's executables."""
+        """(name, program, step handle, tokens a row) of the engine's
+        executables."""
         cells = [("decode", self._prog, self.step, 1),
                  ("prefill", self._prefill_prog, self.prefill_step,
                   self.block_size)]
@@ -497,8 +466,6 @@ class GenerationEngine:
         `executables()` names the first; a caller that compiles or
         re-runs an executable passes this list to get the one the
         engine runs."""
-        if not self.paged:
-            return [self.step.logits_var]
         return next(step.fetch_vars
                     for _, p, step, _ in self._paged_cells() if p is prog)
 
@@ -511,16 +478,13 @@ class GenerationEngine:
         from ..models import gpt
         blk = self._prog.global_block()
         gpt._ensure_decode_state(
-            self.scope, blk, self.step.cache_names
-            + (self.step.state_names if self.paged else []))
+            self.scope, blk,
+            self.step.cache_names + self.step.state_names)
         for _, prog, feed, _ in self.executables():
             self.exe.run(prog, feed=feed, fetch_list=self.fetch_list(prog),
                          scope=self.scope)
-        if self.paged:
-            STAT_SET("serving.gen_kv_blocks_total",
-                     self._pool.capacity())
-            STAT_SET("serving.gen_kv_blocks_free",
-                     self._pool.free_count())
+        STAT_SET("serving.gen_kv_blocks_total", self._pool.capacity())
+        STAT_SET("serving.gen_kv_blocks_free", self._pool.free_count())
         self._warm_misses = self.cache_stats()["misses"]
         self._closed = False
         self._worker = threading.Thread(target=self._worker_loop,
@@ -584,10 +548,7 @@ class GenerationEngine:
         """Snapshot of the paged pool for reporting (loadgen records,
         sweep ledgers): capacity/free in blocks, the bytes the pool
         pins, and how many prefix-cache entries are resident."""
-        if not self.paged:
-            return {"paged": False, "pool_bytes": self.kv_pool_bytes()}
-        return {"paged": True,
-                "block_size": self.block_size,
+        return {"block_size": self.block_size,
                 "blocks_total": self._pool.capacity(),
                 "blocks_free": self._pool.free_count(),
                 "prefix_entries": len(self._prefix),
@@ -606,28 +567,23 @@ class GenerationEngine:
         request before it finishes (`queue_ms`, `cached_tokens`,
         `prefill_steps`, `ttft_ms`)."""
         need = len(req.prompt) + req.max_new_tokens - 1
-        if self.paged:
-            # block-aware admission: a request that can never fit is
-            # rejected here; one that merely has to WAIT for blocks
-            # queues and is admitted by the worker when the pool drains
-            need_blocks = blocks_for_tokens(need, self.block_size)
-            if need_blocks > self.step.max_blocks_per_slot:
-                raise ValueError(
-                    f"request needs {need_blocks} KV blocks but a "
-                    f"slot's block table holds at most "
-                    f"{self.step.max_blocks_per_slot} "
-                    f"(max_seq={self.max_seq}, "
-                    f"block_size={self.block_size})")
-            if need_blocks > self._pool.capacity():
-                raise ValueError(
-                    f"request needs {need_blocks} KV blocks but the "
-                    f"engine's pool has only {self._pool.capacity()} "
-                    f"allocatable blocks "
-                    f"({self._pool.free_count()} free now)")
-        elif need > self.max_seq:
+        # block-aware admission: a request that can never fit is
+        # rejected here; one that merely has to WAIT for blocks queues
+        # and is admitted by the worker when the pool drains
+        need_blocks = blocks_for_tokens(need, self.block_size)
+        if need_blocks > self.step.max_blocks_per_slot:
             raise ValueError(
-                f"request needs {need} cache positions but the engine "
-                f"was built with max_seq={self.max_seq}")
+                f"request needs {need_blocks} KV blocks but a "
+                f"slot's block table holds at most "
+                f"{self.step.max_blocks_per_slot} "
+                f"(max_seq={self.max_seq}, "
+                f"block_size={self.block_size})")
+        if need_blocks > self._pool.capacity():
+            raise ValueError(
+                f"request needs {need_blocks} KV blocks but the "
+                f"engine's pool has only {self._pool.capacity()} "
+                f"allocatable blocks "
+                f"({self._pool.free_count()} free now)")
         timeout_ms = req.timeout_ms if req.timeout_ms is not None \
             else self.default_timeout_ms
         now = time.perf_counter()
@@ -680,18 +636,8 @@ class GenerationEngine:
             prompt, max_new_tokens, **kw)).result()
 
     # -- decode step -----------------------------------------------------
-    def _run_step(self, tokens, reset, active):
-        out, = self.exe.run(
-            self._prog,
-            feed={self.step.token_var.name: tokens,
-                  self.step.reset_var.name: reset,
-                  self.step.active_var.name: active},
-            fetch_list=[self.step.logits_var],
-            scope=self.scope)
-        return np.asarray(out)
-
     def _run_paged(self, prog, step, tokens, table, start, nvalid):
-        """One run of a paged executable; returns its logits (or probe
+        """One run of an executable; returns its logits (or probe
         row). A model's side-fetch comes back in the same fetch and is
         left in `_probe` for the iteration's record."""
         out, *probe = self.exe.run(
@@ -705,7 +651,7 @@ class GenerationEngine:
         self._probe = np.asarray(probe[0]) if probe else None
         return np.asarray(out)
 
-    # -- paged-KV bookkeeping (worker thread only) -----------------------
+    # -- KV-block bookkeeping (worker thread only) -----------------------
     def _alloc_block(self) -> Optional[int]:
         """Pool alloc with prefix-cache pressure relief: when the free
         list is empty, evict cold cached prefixes (LRU, only blocks no
@@ -750,71 +696,64 @@ class GenerationEngine:
         st.phase_span = trace.start_span("prefill", parent=st.span)
 
     def _admit_locked(self, rec) -> bool:
-        """Move the queue head into a free slot. Paged mode additionally
-        gates on block availability: shared prefix blocks come from the
-        PrefixCache (refcounted, zero prefill cost), the rest are
-        allocated upfront for the request's worst case — so a decode
-        can never die mid-flight from pool exhaustion. Returns False
-        (leaving the queue untouched) when the head cannot be placed
-        yet."""
+        """Move the queue head into a free slot, which also needs its
+        blocks: shared prefix blocks come from the PrefixCache
+        (refcounted, zero prefill cost), the rest are allocated upfront
+        for the request's worst case — so a decode can never die
+        mid-flight from pool exhaustion. Returns False (leaving the
+        queue untouched) when the head cannot be placed yet."""
         q = self._queue[0]
         slot = self._slots.acquire()
         if slot is None:
             return False
         st = _SlotState(q.req, q.response, q.deadline, q.t_submit)
-        if self.paged:
-            prompt = q.req.prompt
-            need = len(prompt) + q.req.max_new_tokens - 1
-            # the last prompt position must stay writable (its KV is
-            # written by this slot's first decode step), so the prefix
-            # match is capped one token short of the prompt
-            if self.recurrent:
-                # a cached KV block carries no recurrent state: a slot
-                # that skipped its tokens would decode from a state
-                # that has not seen them. Nothing is adopted.
-                n_cached, shared = 0, []
-                rec.prefix_skipped_recurrent += 1
-                STAT_ADD("serving.gen_prefix_skipped_recurrent")
-            else:
-                n_cached, shared = self._prefix.lookup(
-                    prompt, max_tokens=len(prompt) - 1)
-            owned: List[int] = []
-            missing = blocks_for_tokens(need, self.block_size) - \
-                len(shared)
-            while len(owned) < missing:
-                bid = self._alloc_block()
-                if bid is None:
-                    break
-                owned.append(bid)
-            else:
-                st.blocks = shared + owned
-                st.n_cached = n_cached
-                st.fed = n_cached
-                st.cur = prompt[n_cached]
-                STAT_ADD("serving.gen_prefix_hits" if n_cached
-                         else "serving.gen_prefix_misses")
-                self._admitted(st, q)
-                if st.phase_span is not None and n_cached:
-                    st.phase_span.set_attr("cached_tokens", n_cached)
-                self._state[slot] = st
-                self._queue.pop(0)
-                return True
-            # not enough blocks: roll back and wait for releases
-            for bid in owned + shared:
-                self._pool.decref(bid)
-            self._slots.release(slot)
-            return False
-        self._admitted(st, q)
-        self._state[slot] = st
-        self._queue.pop(0)
-        return True
+        prompt = q.req.prompt
+        need = len(prompt) + q.req.max_new_tokens - 1
+        # the last prompt position must stay writable (its KV is
+        # written by this slot's first decode step), so the prefix
+        # match is capped one token short of the prompt
+        if self.recurrent:
+            # a cached KV block carries no recurrent state: a slot
+            # that skipped its tokens would decode from a state
+            # that has not seen them. Nothing is adopted.
+            n_cached, shared = 0, []
+            rec.prefix_skipped_recurrent += 1
+            STAT_ADD("serving.gen_prefix_skipped_recurrent")
+        else:
+            n_cached, shared = self._prefix.lookup(
+                prompt, max_tokens=len(prompt) - 1)
+        owned: List[int] = []
+        missing = blocks_for_tokens(need, self.block_size) - len(shared)
+        while len(owned) < missing:
+            bid = self._alloc_block()
+            if bid is None:
+                break
+            owned.append(bid)
+        else:
+            st.blocks = shared + owned
+            st.n_cached = n_cached
+            st.fed = n_cached
+            st.cur = prompt[n_cached]
+            STAT_ADD("serving.gen_prefix_hits" if n_cached
+                     else "serving.gen_prefix_misses")
+            self._admitted(st, q)
+            if st.phase_span is not None and n_cached:
+                st.phase_span.set_attr("cached_tokens", n_cached)
+            self._state[slot] = st
+            self._queue.pop(0)
+            return True
+        # not enough blocks: roll back and wait for releases
+        for bid in owned + shared:
+            self._pool.decref(bid)
+        self._slots.release(slot)
+        return False
 
     def _release_slot(self, i: int):
-        """Retire slot i: in paged mode 'reset' IS this — the blocks go
-        back to the pool (or stay resident for the prefix cache /
-        other slots holding refs); the graph never wipes anything."""
+        """Retire slot i: 'reset' IS this — the blocks go back to the
+        pool (or stay resident for the prefix cache / other slots
+        holding refs); the graph never wipes anything."""
         st = self._state[i]
-        if st is not None and self.paged:
+        if st is not None:
             for bid in st.blocks:
                 self._pool.decref(bid)
             st.blocks = []
@@ -885,7 +824,7 @@ class GenerationEngine:
                          exemplar=st.span.trace_id if st.span else None)
 
     def _worker_loop(self):
-        total = self._pool.capacity() if self.paged else 0
+        total = self._pool.capacity()
         alive = True
         while alive:
             rec = trace.begin_iteration(self.max_slots, self.block_size,
@@ -911,8 +850,7 @@ class GenerationEngine:
         rec.kv_tokens_resident = sum(st.fed for st in live)
         STAT_SET("serving.gen_queue_depth", rec.queue_depth)
         STAT_SET("serving.gen_active_slots", rec.active_slots)
-        if self.paged:
-            self._set_block_gauges()
+        self._set_block_gauges()
         if self.recurrent:
             rec.state_slots_live = len(live)
             rec.state_bytes = len(live) * self.cfg.state_slot_bytes()
@@ -946,8 +884,8 @@ class GenerationEngine:
                     self._queue = []
                 # admit queued requests into free slots (iteration-level
                 # scheduling: this runs BETWEEN decode steps, so a slot
-                # — and in paged mode its KV blocks — freed by the
-                # previous step is reusable right now)
+                # and its KV blocks freed by the previous step are
+                # reusable right now)
                 while self._queue and self._slots.free_count() \
                         and self._admit_locked(rec):
                     pass
@@ -984,128 +922,11 @@ class GenerationEngine:
             return False
         if not active_idx:
             return True
-        if self.paged:
-            # _kv_mutex: disagg export/adopt (serving/disagg.py)
-            # mutates the same pools/PrefixCache between iterations
-            with self._kv_mutex:
-                self._paged_iteration(rec)
-        else:
-            self._slab_iteration(rec, active_idx)
+        # _kv_mutex: disagg export/adopt (serving/disagg.py) mutates
+        # the same pools/PrefixCache between iterations
+        with self._kv_mutex:
+            self._paged_iteration(rec)
         return True
-
-    # -- slab iteration --------------------------------------------------
-    def _slab_iteration(self, rec, active_idx):
-        """One decode step over the full fixed-shape batch of the slab
-        (non-paged) engine; a prompt is stepped through the same graph
-        one token at a time."""
-        # deferred: paddle_tpu/__init__ imports serving before the
-        # models package exists, so this cannot be a module-level import
-        from ..models import sampling
-        B = self.max_slots
-        now = time.perf_counter()
-        tokens = np.zeros((B, 1), np.int64)
-        reset = np.zeros(B, np.float32)
-        active = np.zeros(B, np.float32)
-        stepped: List[int] = []
-        for i in active_idx:
-            st = self._state[i]
-            if st.deadline is not None and now >= st.deadline:
-                STAT_ADD("serving.gen_timeouts")
-                st.response._complete(
-                    error=DeadlineExceededError(
-                        "generation deadline passed mid-decode"))
-                self._state[i] = None
-                self._slots.release(i)
-                continue
-            tokens[i, 0] = st.cur
-            reset[i] = 1.0 if st.needs_reset else 0.0
-            active[i] = 1.0
-            stepped.append(i)
-        if not stepped:
-            return
-        rec.decode_rows = len(stepped)
-
-        def _attempt():
-            inj = _fault_injector()
-            if inj is not None:
-                inj.pre_step("generation")
-            return self._run_step(tokens, reset, active)
-
-        try:
-            # only the injector's pre-dispatch TransientFault is
-            # retryable: once the real step ran, the KV cache
-            # advanced and a replay would double-step the slots
-            with trace.region("gen.decode.step"):
-                logits = self._step_retry.call(_attempt)
-        except Exception as e:  # noqa: BLE001 — worker must survive
-            if is_transient(e):
-                self._breaker.record_failure()
-            STAT_ADD("resilience.gen_step_failures")
-            for i in stepped:
-                st = self._state[i]
-                st.response._complete(error=RuntimeError(
-                    f"decode step failed: {e!r}"))
-                self._state[i] = None
-                self._slots.release(i)
-            return
-        self._breaker.record_success()
-        if trace.enabled():
-            lt = self.exe.last_step_timings
-            if lt is not None:
-                for i in stepped:
-                    self._state[i].fetch_s += lt["fetch_s"]
-        with trace.region("gen.sample"):
-            inj = _fault_injector()
-            if inj is not None:
-                # step_nan at site=generation corrupts only the host
-                # logits copy; the device KV state is untouched
-                arrs = [logits]
-                if inj.corrupt_fetches("generation", arrs):
-                    logits = arrs[0]
-            from ..core.flags import FLAGS
-            if FLAGS.serving_nan_guard:
-                bad = [i for i in stepped
-                       if not np.all(np.isfinite(logits[i, 0]))]
-                if bad:
-                    self._breaker.record_failure()
-                    STAT_ADD("resilience.gen_step_failures")
-                    for i in bad:
-                        st = self._state[i]
-                        st.response._complete(error=RuntimeError(
-                            "non-finite logits (cannot replay a "
-                            "stateful decode step)"))
-                        self._state[i] = None
-                        self._slots.release(i)
-                    stepped = [i for i in stepped if i not in bad]
-                    rec.decode_rows = len(stepped)
-                    if not stepped:
-                        return
-            STAT_ADD("serving.gen_steps")
-
-            # ---- per-slot bookkeeping (sampling, streaming, finish) --
-            t_step = time.perf_counter()
-            for i in stepped:
-                st = self._state[i]
-                st.needs_reset = False
-                st.fed += 1
-                prompt = st.req.prompt
-                if st.fed < len(prompt):
-                    st.cur = prompt[st.fed]     # still prefilling
-                    st.response.timings["prefill_steps"] += 1
-                    continue
-                tok = sampling.sample_token(
-                    logits[i, 0], temperature=st.req.temperature,
-                    top_k=st.req.top_k, rng=st.rng)
-                self._emit(rec, st, tok, logits[i, 0], t_step)
-                done_eos = (st.req.eos_id is not None
-                            and tok == st.req.eos_id)
-                if done_eos or len(st.generated) >= \
-                        st.req.max_new_tokens:
-                    self._finish(st, "eos" if done_eos else "length")
-                    self._state[i] = None
-                    self._slots.release(i)
-                else:
-                    st.cur = tok
 
     def _emit(self, rec, st: _SlotState, tok: int, row, t_step: float):
         """Commit one sampled token of a slot: the iteration's and the
@@ -1126,7 +947,7 @@ class GenerationEngine:
                 self._close_phase(st)
                 st.phase_span = trace.start_span(
                     "decode", parent=st.span)
-            if self.paged and not st.registered:
+            if not st.registered:
                 # the whole prompt (every full block of it) is now
                 # resident and immutable — shareable from here on
                 self._register_prefix(st)
@@ -1144,9 +965,9 @@ class GenerationEngine:
                 st.phase_span.add_event(
                     "stream_flush", token_index=len(st.generated))
 
-    # -- paged iteration -------------------------------------------------
+    # -- one iteration ---------------------------------------------------
     def _paged_iteration(self, rec):
-        """One scheduler iteration of the paged engine: (1) chunked
+        """One scheduler iteration: (1) chunked
         prefill — every slot still consuming its prompt retires up to
         one BLOCK of tokens through the prefill executable; (2) one
         decode step for every slot past its prompt. Both run the same

@@ -20,7 +20,7 @@ its own handler thread, which blocks in `engine.predict` /
   full-block KV prefix, prefilled locally if needed), and
   ``POST /v1/kv/adopt`` body = a shipment -> adoption summary; the
   disaggregated-fleet transfer hop (serving/disagg.py,
-  docs/serving.md). 404 unless a *paged* generation engine is attached.
+  docs/serving.md). 404 unless a generation engine is attached.
 - ``GET /healthz``      -> aggregated engine health. 200 with
   ``{"state": "ok"|"degraded", ...}`` while every attached engine is
   ready (degraded = some circuit breaker is half-open and probing);
@@ -337,12 +337,11 @@ class ServingHTTPServer:
                 """Disaggregated KV transfer (serving/disagg.py):
                 /v1/kv/export packs a prompt's full-block prefix into a
                 kv_wire shipment; /v1/kv/adopt unpacks one into the
-                local pool. 404 unless a paged generation engine is
-                attached."""
+                local pool. 404 unless a generation engine is attached."""
                 from . import disagg
-                if gen is None or not getattr(gen, "paged", False):
-                    self._reply(404, {"error": "no paged generation "
-                                               "engine attached"})
+                if gen is None:
+                    self._reply(404, {"error": "no generation engine "
+                                               "attached"})
                     return
                 try:
                     length = int(self.headers.get("Content-Length", 0))

@@ -5,8 +5,8 @@ pluggable block allocator (memory/allocation/allocator_facade.cc); this
 module is its serving-side analogue, applied to KV-cache HBM the way
 vLLM's PagedAttention applies OS paging to attention state. The device
 side holds ONE physical pool per layer — `[num_blocks, block_size,
-lanes]` persistable tensors built by `models/gpt.build_paged_decode_step`
-(a token's d_model numbers side by side, in whole lane tiles) — and
+lanes]` persistable tensors built by the model's `cfg.build_paged_step`
+(a token's K or V numbers side by side, in whole lane tiles) — and
 this module owns the host-side metadata:
 
 * `BlockPool` — free-list allocator over the physical block ids with

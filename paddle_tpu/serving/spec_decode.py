@@ -7,12 +7,19 @@ Transformers via Speculative Decoding", arXiv 2211.17192) breaks the
 one-token ceiling without breaking the contract: a cheap DRAFTER
 proposes k candidate continuation tokens, one batched VERIFY step
 scores all k+1 positions through the paged decode graph
-(`models/gpt.py:build_spec_verify_step`, a `[max_slots, k+1]`
+(`cfg.build_paged_step(seq_tokens=k+1)`, a `[max_slots, k+1]`
 fixed-shape sibling of the decode step), and the host accepts the
 longest prefix the target model agrees with
 (`models/sampling.py:accept_draft`). Every accepted token costs zero
 extra forward passes; a full rejection degenerates to exactly the
 single-token step.
+
+The verify step: row b feeds `[cur, d_1..d_n, pad...]` at `start_pos =
+fed` with `n_valid = 1+n`. The draft tokens scatter through the SAME
+block table, and the same `state_prefix` K/V pools, as the decode step.
+The host re-feeds from the first rejection; a rejected position's pool
+write is harmless: it sits past the slot's advanced write cursor and is
+overwritten before any mask exposes it.
 
 The drafter here is the prompt-lookup / n-gram variant (no second
 model, no extra weights, nothing on the device): LLM serving traffic is

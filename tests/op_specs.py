@@ -473,8 +473,8 @@ for _op in ["save", "save_combine", "load", "load_combine"]:
 skip("paged_attention", "stateful decode op over externally-allocated "
      "KV block pools + block table; the op itself (Pallas read against "
      "a gather reference, scatter write) is covered in "
-     "tests/test_paged_attention_op.py, token-exact parity vs the slab "
-     "path in tests/test_generation.py and the allocator in "
+     "tests/test_paged_attention_op.py, token-exact parity vs the serial "
+     "reference in tests/test_generation.py and the allocator in "
      "tests/test_kv_blocks.py")
 skip("mamba2_mixer", "stateful serving op over per-slot convolution and "
      "SSM state with start/n_valid feeds; chunks, single steps, muted "

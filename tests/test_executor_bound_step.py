@@ -85,7 +85,7 @@ def _tiny_engine(exe=None, seed=3):
     scope = fluid.Scope()
     with fluid.unique_name.guard():
         eng = GenerationEngine(cfg, scope, exe=exe or fluid.Executor(),
-                               max_slots=4, max_seq=128, paged=True)
+                               max_slots=4, max_seq=128)
     rng = np.random.default_rng(seed)
     blk = eng._prog.global_block()
     for p in blk.all_parameters():

@@ -271,7 +271,7 @@ def test_engine_deadline_fails_queued_request(trained):
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("opt_level", [0, 2])
-def test_paged_engine_matches_slab_and_serial(trained, opt_level):
+def test_paged_engine_matches_serial(trained, opt_level):
     """Paged engine (tight pool -> eviction + re-admission pressure)
     over mixed-length prompts must produce EXACTLY the serial slab
     kv_generate tokens at graph-opt level 0 and 2, with both of its
@@ -291,7 +291,7 @@ def test_paged_engine_matches_slab_and_serial(trained, opt_level):
         eng = GenerationEngine(cfg, scope, exe=fluid.Executor(),
                                max_slots=2, max_seq=SEQ,
                                block_size=4, kv_pool_blocks=8)
-        assert eng.paged and eng.block_size == 4
+        assert eng.block_size == 4
         eng.start()
         try:
             resps = [eng.submit(GenerationRequest(p, n))
@@ -334,7 +334,7 @@ def test_paged_prefix_cache_hit_reuses_blocks(trained):
             assert out_b["cached_tokens"] == len(prefix)
             assert eng.post_warmup_compiles() == 0
             stats = eng.kv_block_stats()
-            assert stats["paged"] and stats["prefix_entries"] >= 2
+            assert stats["prefix_entries"] >= 2
             c = monitor.get_stats_snapshot()["counters"]
             assert c["serving.gen_prefix_hits"] == 1
             assert c["serving.gen_prefix_misses"] == 1
@@ -514,33 +514,6 @@ def test_iteration_record_counts_the_pages_the_fed_rows_hold(trained):
     # the rows of this run hold far less than their tables name
     assert sum(r["kv_pages_read"] for r in recs) < \
         sum(r["kv_pages_table"] for r in recs) // 2
-
-
-def test_slab_engine_records_its_three_regions(trained):
-    import time
-    cfg, scope, _ = trained
-    eng = GenerationEngine(cfg, scope, exe=fluid.Executor(), max_slots=2,
-                           max_seq=SEQ, paged=False)
-    eng.start()
-    t0 = time.perf_counter()
-    try:
-        out = eng.generate([0, 1, 2], 4)
-        early = eng.submit(GenerationRequest([4, 5], 2))
-        early.result(timeout=60.0)
-    finally:
-        eng.stop()
-    recs = _records_since(t0)
-    # a prompt is stepped through the decode graph a token at a time
-    assert len(recs) >= 3 + 4 - 1
-    assert sum(r["tokens_emitted"] for r in recs) == 4 + 2
-    for r in recs:
-        assert r["prefill_rows"] == 0 and r["block_size"] == 0
-        assert 1 <= r["decode_rows"] <= 2
-        assert {k for k in r["host_s"] if k.startswith("gen.")} == \
-            {"gen.iteration", "gen.admit", "gen.decode.step", "gen.sample"}
-        assert sum(r["host_s"].values()) <= r["t_end"] - r["t_start"]
-    assert out["queue_ms"] >= 0.0
-    assert early.timings["prefill_steps"] == 1
 
 
 def test_timings_are_readable_before_the_request_finishes(trained):

@@ -2,8 +2,9 @@
 reference (benchmark/configs/nemotron3_super_ep4_l11_reference.py) at a
 small size, seeded: each op against the reference's layer, the whole
 model through GenerationEngine, the expert shares adding up, no token
-dropped, what the engine refuses for a recurrent model, and GPT's two
-programs unchanged by the hook that builds them."""
+dropped, what the engine refuses for a recurrent model, and the
+engine's programs, GPT's three and this model's two, as the parent
+commit built them."""
 import hashlib
 import json
 
@@ -365,8 +366,8 @@ def test_prefix_cache_adopts_nothing_and_spec_disagg_wire_refuse():
     cell.stop()
     with pytest.raises(ValueError, match="speculative"):
         GenerationEngine(eng.cfg, fluid.Scope(), max_slots=2, max_seq=64,
-                         paged=True, spec_decode=True, spec_k=2)
-    with pytest.raises(ValueError, match="paged"):
+                         spec_decode=True, spec_k=2)
+    with pytest.raises(ValueError, match="paged=False"):
         GenerationEngine(eng.cfg, fluid.Scope(), max_slots=2, max_seq=64,
                          paged=False)
 
@@ -394,20 +395,82 @@ def fingerprint(prog):
         json.dumps([sig, shapes]).encode()).hexdigest()
 
 
+GPT_CFG = dict(vocab_size=512, d_model=64, n_heads=2, n_layers=2, d_ff=128,
+               max_seq_len=128, dropout=0.0)
+GPT_DECODE = (73, "01f80c4c2ab1baf13200ac9eaf0b14f0"
+                  "e8c2ff4e5a71d36500e32b0fe5f4fc39")
+GPT_PREFILL = (73, "3a6e678459f2a0186717b32d0b76596f"
+                   "864c904a009c503b116c8c5fb69e86a1")
+
+
 def test_gpt_programs_are_the_parents():
     """`GenerationEngine` asks the configuration for its programs; for
     a TransformerConfig that builds what `gpt.build_paged_decode_step`
     built in the parent commit (digests taken there, PR 28's tree)."""
-    cfg = gpt.gpt_small(vocab_size=512, d_model=64, n_heads=2, n_layers=2,
-                        d_ff=128, max_seq_len=128, dropout=0.0)
-    eng = GenerationEngine(cfg, fluid.Scope(), max_slots=4, max_seq=128,
-                           paged=True)
-    assert fingerprint(eng._prog) == (
-        73, "01f80c4c2ab1baf13200ac9eaf0b14f0"
-            "e8c2ff4e5a71d36500e32b0fe5f4fc39")
-    assert fingerprint(eng._prefill_prog) == (
-        73, "3a6e678459f2a0186717b32d0b76596f"
-            "864c904a009c503b116c8c5fb69e86a1")
+    eng = GenerationEngine(gpt.gpt_small(**GPT_CFG), fluid.Scope(),
+                           max_slots=4, max_seq=128, paged=True)
+    assert fingerprint(eng._prog) == GPT_DECODE
+    assert fingerprint(eng._prefill_prog) == GPT_PREFILL
     assert not eng.recurrent and eng.state_bytes() == 0
     assert eng.step.state_names == [] and eng.step.probe_var is None
     assert eng.kv_block_bytes() == 2 * 2 * eng.block_size * 128 * 4
+
+
+def test_gpt_verify_program_is_the_parents():
+    """The third executable comes through the same hook, with k + 1
+    tokens a row: what `gpt.build_spec_verify_step` built in the parent
+    commit (digest taken there, PR 30's tree)."""
+    eng = GenerationEngine(gpt.gpt_small(**GPT_CFG), fluid.Scope(),
+                           max_slots=4, max_seq=128, spec_decode=True,
+                           spec_k=2)
+    assert [name for name, *_ in eng.executables()] == \
+        ["decode", "prefill", "spec_verify"]
+    assert eng.spec_step.seq_tokens == 3
+    assert fingerprint(eng._spec_prog) == (
+        73, "8aa9ce5610ee3da868f8dc2d7c5f09c1"
+            "7b9ce8dd84e81b27b2791ebec3dfa5c3")
+    assert fingerprint(eng._prog) == GPT_DECODE
+    assert fingerprint(eng._prefill_prog) == GPT_PREFILL
+
+
+@pytest.mark.parametrize("dtype,decode,prefill", [
+    ("float32",
+     (40, "250929f8c17f020696ebcdd82704d8ad"
+          "583aa9ea86d8d357150bc2b51bc6ab23"),
+     (37, "ec4da513ca58e82ef4ebd8dda5586a2b"
+          "826f2b2d499787545a3b06cda4dc50ee")),
+    ("bfloat16",
+     (40, "2f15391df1c4b590387b14a3a79e3e54"
+          "40dd4476e0d8b8d28629fc57bbf0779d"),
+     (37, "d9583f03065f081378bc6e2fc8743454"
+          "80dc17eb9198f91284205442575f7ff3"))])
+def test_hybrid_programs_are_the_parents(dtype, decode, prefill):
+    """The engine that `engine_logits` builds, not started: its two
+    programs digest as they did in the parent commit (PR 30's tree)."""
+    cfg, _ = small()
+    cfg = dict(cfg, engine=dict(cfg["engine"], dtype=dtype, max_slots=3))
+    eng = hybrid_serve.build(cfg, {"timeout_ms": 600000}, 1, SEED).engine
+    assert fingerprint(eng._prog) == decode
+    assert fingerprint(eng._prefill_prog) == prefill
+
+
+def test_paged_argument_selects_nothing():
+    """There is one engine. The constructor's `paged` is what the
+    benchmark's call passes: True and nothing build the same programs,
+    False is refused and not obeyed, and the flag that chose the other
+    engine is unknown, as any name that is no flag."""
+    cfg = gpt.gpt_small(**GPT_CFG)
+    for kw in ({}, {"paged": True}, {"paged": None}):
+        eng = GenerationEngine(cfg, fluid.Scope(), max_slots=4,
+                               max_seq=128, **kw)
+        assert fingerprint(eng._prog) == GPT_DECODE
+        assert fingerprint(eng._prefill_prog) == GPT_PREFILL
+        assert not hasattr(eng, "paged")
+    with pytest.raises(ValueError, match="paged=False.*PR 31"):
+        GenerationEngine(cfg, fluid.Scope(), max_slots=2, max_seq=64,
+                         paged=False)
+    for name in ("FLAGS_no_such_flag", "FLAGS_gen_paged_kv"):
+        with pytest.raises(ValueError, match="unknown flag"):
+            fluid.get_flags(name)
+        with pytest.raises(ValueError, match="unknown flag"):
+            fluid.set_flags({name: False})

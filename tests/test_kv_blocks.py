@@ -140,7 +140,6 @@ def test_submit_error_names_blocks_needed_vs_available():
                         dropout=0.0, use_flash=False)
     eng = GenerationEngine(cfg, fluid.Scope(), exe=fluid.Executor(),
                            max_slots=2, max_seq=16, block_size=4)
-    assert eng.paged
     # prompt + max_new - 1 = 20 tokens -> 5 blocks > the 4-block table
     with pytest.raises(ValueError) as ei:
         eng.submit(GenerationRequest(list(range(10)), 11))

@@ -197,7 +197,7 @@ def test_spec_engine_matches_serial_greedy(trained, opt_level):
         eng = GenerationEngine(cfg, scope, exe=fluid.Executor(),
                                max_slots=2, max_seq=SEQ, block_size=4,
                                spec_decode=True, spec_k=4)
-        assert eng.paged and eng.spec_decode and eng.spec_k == 4
+        assert eng.spec_decode and eng.spec_k == 4
         eng.start()
         try:
             resps = [eng.submit(GenerationRequest(p, n))
@@ -269,16 +269,6 @@ def test_spec_per_request_opt_out_and_flag_default(trained):
             eng.stop()
     finally:
         fluid.set_flags({"FLAGS_gen_spec_decode": prev})
-
-
-def test_spec_requires_paged_engine(trained):
-    """Slab-layout engines have no verify substrate: spec_decode must
-    quietly resolve to off rather than break."""
-    cfg, scope, exe = trained
-    eng = GenerationEngine(cfg, scope, exe=fluid.Executor(),
-                           max_slots=2, max_seq=SEQ,
-                           spec_decode=True, paged=False)
-    assert not eng.spec_decode
 
 
 # ---------------------------------------------------------------------------

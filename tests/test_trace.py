@@ -656,7 +656,6 @@ def test_regions_land_on_the_profilers_host_plane_nested(tmp_path):
 
     import jax
     eng = _fresh_engine()
-    assert eng.paged
     eng.start()
     jax.profiler.start_trace(str(tmp_path))
     try:

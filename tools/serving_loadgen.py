@@ -15,7 +15,7 @@ Two workloads:
   mismatch). --shared-prefix-frac makes that fraction of requests open
   with one fixed whole-block prefix: the record gains a "prefix"
   object splitting TTFT hit-vs-miss and snapshotting the paged KV
-  pool; --block-size / --slab pick the KV layout for A/B runs;
+  pool; --block-size sets the KV block size;
   --temperature applies one sampling temperature to every request
   (engine, HTTP and serial paths alike — parity holds at any value).
   --spec-decode switches to the speculative-decoding A/B
@@ -654,8 +654,6 @@ def run_generation(args):
     engine = GenerationEngine(cfg, scope, max_slots=args.slots,
                               max_seq=args.max_seq,
                               default_timeout_ms=args.timeout_ms,
-                              paged=(False if getattr(args, "slab", False)
-                                     else None),
                               block_size=block_size or None)
     engine.init_scope()   # scratch weights: loadgen measures the
     engine.start()        # serving path, not model quality
@@ -1427,7 +1425,7 @@ def run_disagg(args):
                         dropout=0.0, use_flash=False)
     scope = fluid.Scope()
     seed_engine = GenerationEngine(cfg, scope, max_slots=args.slots,
-                                   max_seq=args.max_seq, paged=True,
+                                   max_seq=args.max_seq,
                                    block_size=block_size)
     seed_engine.init_scope()  # scratch weights; never start()ed
     weights = {}
@@ -1841,9 +1839,6 @@ def main(argv=None):
     ap.add_argument("--block-size", type=int, default=0,
                     help="KV block size for the paged engine "
                          "(0 = FLAGS_gen_kv_block_size)")
-    ap.add_argument("--slab", action="store_true",
-                    help="force the contiguous slab KV layout "
-                         "(paged=False) regardless of FLAGS_gen_paged_kv")
     ap.add_argument("--trace", action="store_true",
                     help="generation only: arm FLAGS_enable_trace, dump "
                          "kept spans to --trace-out and assert complete "

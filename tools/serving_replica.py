@@ -57,7 +57,7 @@ def build_gen_engine(args):
         scope.set(name, np.array(data[name]))
     engine = GenerationEngine(
         cfg, scope, max_slots=args.slots, max_seq=args.max_seq,
-        default_timeout_ms=args.timeout_ms, paged=True,
+        default_timeout_ms=args.timeout_ms,
         block_size=args.block_size or None,
         kv_pool_blocks=args.kv_pool_blocks or None,
         spec_decode=args.spec_decode or None,
