@@ -764,9 +764,11 @@ class Executor:
         persistables = {v.name for v in program.list_vars() if v.persistable}
         produced_all = set()
         consumed_first = set()
+        consumed = set()
         for blk in program.blocks:
             for op in blk.ops:
                 for n in op.input_names():
+                    consumed.add(n)
                     if n in persistables and n not in produced_all:
                         consumed_first.add(n)
                 for n in op.output_names():
@@ -796,6 +798,20 @@ class Executor:
         # - otherwise: the state inputs that some op of some block
         #   produces. An inference step donates its KV pools and pins
         #   its weights; a training step donates everything it updates.
+        # - a persistable that no op of any block reads and the global
+        #   block writes on every run (by an op without a sub-block, and
+        #   no op under a condition or in a loop writes it too) is no
+        #   input at all: nothing of what it held is wanted. The step
+        #   returns a new array, and the one in the scope is neither
+        #   passed nor donated: whoever took it from the scope keeps it
+        #   (the logits a serving step leaves on the device,
+        #   serving/generation.py).
+        if compiled is None:
+            maybe_written = {n for blk in program.blocks for op in blk.ops
+                             if blk is not block or "sub_block" in op.attrs
+                             for n in op.output_names()}
+            write_only = produced_global - consumed - maybe_written
+            state_in = [n for n in state_in if n not in write_only]
         donate_plan = getattr(program, "_donation_plan", None)
         if compiled is not None:
             donate_names = frozenset(state_in)

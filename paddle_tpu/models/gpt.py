@@ -323,6 +323,11 @@ class PagedDecodeStep:
     `[batch, ...]` persistables of recurrent layers, row b slot b's
     (models/hybrid.py; none here). `probe_var`, where a model has one,
     is a few int32 that the decode step fetches beside its logits.
+    `picks_var` and `logits_name` are the serving engine's, set where
+    it appends its own ops to a step with logits
+    (serving/generation.py: `_pick_on_device`): the small variable such
+    a step then fetches in the logits' place, and the name of the state
+    variable under which the logits stay on the device.
     """
 
     def __init__(self, token_var, logits_var, cache_names, table_var,
@@ -330,6 +335,8 @@ class PagedDecodeStep:
                  num_blocks, seq_tokens, state_prefix):
         self.state_names = []
         self.probe_var = None
+        self.picks_var = None
+        self.logits_name = None
         self.token_var = token_var
         self.logits_var = logits_var
         self.cache_names = cache_names
@@ -349,9 +356,13 @@ class PagedDecodeStep:
 
     @property
     def fetch_vars(self):
-        """What one run of the step fetches: the logits (or the health
-        probe), and the model's int32 side-fetch where it has one."""
-        return [self.logits_var] + \
+        """What one run of the step fetches: the logits (the picks,
+        where the engine left the logits on the device; the health
+        probe of a step without logits), and the model's int32
+        side-fetch where it has one."""
+        first = self.logits_var if self.picks_var is None \
+            else self.picks_var
+        return [first] + \
             ([self.probe_var] if self.probe_var is not None else [])
 
 

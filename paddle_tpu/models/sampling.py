@@ -13,12 +13,30 @@ topk-based beam ops); here sampling stays on the host because the decode
 step is one fixed-shape XLA executable shared by every request — the
 per-request temperature/top-k knobs must not specialize (and recompile)
 the graph.
+
+A row need not be on the host to be sampled from. The serving engine's
+step leaves its logits on the device and brings back each position's
+arg-max (serving/generation.py); what `sample_token` is handed there is
+a row view: `shape`, `len()`, an `argmax()` that answers from the
+device's pick, and an `__array__` that brings the numbers to the host
+when someone reads them. The greedy case asks for the arg-max and reads
+nothing; with a temperature the row is read, the same float32 numbers
+as ever, and drawn from with the same rng. A NumPy row takes the same
+two ways: its `argmax()` is NumPy's.
 """
 from __future__ import annotations
 
 import numpy as np
 
 __all__ = ["sample_token", "accept_draft"]
+
+
+def _shaped(logits):
+    """`logits` as something with a `shape`: itself where it has one (a
+    NumPy array, a view of rows left on the device), else as an array."""
+    if getattr(logits, "shape", None) is None:
+        logits = np.asarray(logits)
+    return logits, tuple(logits.shape)
 
 
 def sample_token(step_logits, temperature=0.0, top_k=0, rng=None):
@@ -30,28 +48,35 @@ def sample_token(step_logits, temperature=0.0, top_k=0, rng=None):
     top_k > 0 restricts either mode to the k highest logits — the
     classic fan-out cap that keeps sampled generations from wandering
     into the distribution's tail.
+
+    `step_logits` is a NumPy row or anything with its `shape` and
+    `argmax()` that turns into one under `np.asarray`. Greedy asks for
+    the arg-max before anything reads the row (the largest logit is
+    among the k highest for any k, so `top_k` does not change it): a
+    row left on the device stays there.
     """
-    logits = np.asarray(step_logits)
-    if logits.ndim != 1:
+    step_logits, shape = _shaped(step_logits)
+    if len(shape) != 1:
         raise ValueError(
             f"sample_token expects one position's logits row, got shape "
-            f"{logits.shape}")
+            f"{shape}")
+    if not (temperature and temperature > 0.0):
+        return int(step_logits.argmax())
+    if rng is None:
+        raise ValueError(
+            "sample_token: temperature sampling needs an explicit "
+            "rng (np.random.RandomState) for reproducibility")
+    logits = np.asarray(step_logits)
     if top_k and 0 < int(top_k) < logits.shape[0]:
         k = int(top_k)
         keep = np.argpartition(-logits, k - 1)[:k]
         masked = np.full_like(logits, -np.inf)
         masked[keep] = logits[keep]
         logits = masked
-    if temperature and temperature > 0.0:
-        if rng is None:
-            raise ValueError(
-                "sample_token: temperature sampling needs an explicit "
-                "rng (np.random.RandomState) for reproducibility")
-        p = logits / temperature
-        p = np.exp(p - p.max())
-        p /= p.sum()
-        return int(rng.choice(len(p), p=p))
-    return int(logits.argmax())
+    p = logits / temperature
+    p = np.exp(p - p.max())
+    p /= p.sum()
+    return int(rng.choice(len(p), p=p))
 
 
 def accept_draft(step_logits, draft, temperature=0.0, top_k=0,
@@ -77,11 +102,11 @@ def accept_draft(step_logits, draft, temperature=0.0, top_k=0,
     this degenerates to exactly the single-token sample — the bit-exact
     fallback the serving engine and tests rely on.
     """
-    rows = np.asarray(step_logits)
-    if rows.ndim != 2 or rows.shape[0] != len(draft) + 1:
+    rows, shape = _shaped(step_logits)
+    if len(shape) != 2 or shape[0] != len(draft) + 1:
         raise ValueError(
             f"accept_draft expects [len(draft)+1, vocab] logits, got "
-            f"shape {rows.shape} for {len(draft)} draft token(s)")
+            f"shape {shape} for {len(draft)} draft token(s)")
     emitted = []
     n_accepted = 0
     for j in range(len(draft) + 1):

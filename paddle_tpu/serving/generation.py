@@ -94,6 +94,112 @@ __all__ = ["GenerationRequest", "SlotManager", "GenerationEngine"]
 SPEC_TOKEN_BUCKETS = (1.0, 2.0, 3.0, 4.0, 5.0, 6.0, 8.0, 12.0, 16.0)
 
 
+def _pick_on_device(step, name):
+    """What a decode (or verify) step hands to the host, appended to a
+    step with logits inside its `program_guard`: `step.picks_var`,
+    float32 `[2, B, T]`, and nothing else. `picks[0]` is each
+    position's arg-max (the first of equals, as `np.argmax`; a
+    vocabulary index is exact in float32), `picks[1]` its largest
+    |logit|, finite exactly when every logit of the position is. They
+    are ONE fetched variable because the fetch list is part of an
+    executable's identity (`Executor._cache_key`) and a caller of
+    `executables()` compiles `[fetch]`. The logits themselves stay on
+    the device, under `step.logits_name`: a state variable the step
+    writes, as it writes a KV pool, and that no op reads, so the
+    Executor neither passes nor donates it and an array taken from the
+    scope stays readable after later steps have run."""
+    from .. import layers
+    logits = step.logits_var
+    B, T, V = (int(d) for d in logits.shape)
+    if V >= 1 << 24:
+        raise ValueError(f"a vocabulary of {V} has indices that float32 "
+                         "does not hold exactly")
+    pick = layers.cast(layers.argmax(logits, axis=2), "float32")
+    top = layers.elementwise_max(
+        layers.reduce_max(logits, dim=2),
+        layers.scale(layers.reduce_min(logits, dim=2), scale=-1.0))
+    step.picks_var = layers.stack([pick, top], axis=0)
+    kept = layers.create_global_var(
+        [B, T, V], 0.0, "float32", persistable=True,
+        name=f"{step.state_prefix}logits.{name}")
+    layers.assign(logits, output=kept)
+    step.logits_name = kept.name
+
+
+class _DeviceLogits:
+    """A step's logits `[B, T, V]` as they lie on the device, or the
+    rows `[i, j0:j1]` or the one row `[i, j]` of them: what
+    `_run_paged` returns and what indexing it gives. Nothing crosses to
+    the host until someone reads the numbers (`np.asarray`, `np.array`:
+    `__array__`). The first row asked of a step brings the step's whole
+    array over, once, and every later row is cut from that copy: on the
+    chip a row gathered and copied alone takes as long as the whole
+    array copied (1.3 ms either way for `[32, 1, 50257]`: PERF.md,
+    PR 32), and a second row would pay it again. `rows_read` counts the
+    rows that were asked for, over every view of the step. A row
+    answers `argmax()` from the step's fetched picks and reads nothing
+    (models/sampling.py: the greedy case)."""
+
+    __slots__ = ("_arr", "_picks", "_host", "_i", "_j")
+
+    def __init__(self, arr, picks, host=None, i=None, j=None):
+        self._arr = arr          # jax.Array [B, T, V], not donated
+        self._picks = picks      # np [B, T], the positions' arg-maxes
+        # shared by the views of a step: the host's copy once someone
+        # asked ("whole"), and the (i, j) that were asked for
+        self._host = {"asked": set()} if host is None else host
+        self._i = i              # None: every slot
+        self._j = j              # a range: rows of slot i; an int: a row
+
+    @property
+    def rows_read(self):
+        return len(self._host["asked"])
+
+    @property
+    def shape(self):
+        B, T, V = self._arr.shape
+        if self._i is None:
+            return (B, T, V)
+        return (V,) if isinstance(self._j, int) else (len(self._j), V)
+
+    def __len__(self):
+        return self.shape[0]
+
+    def __getitem__(self, key):
+        if self._i is None:
+            i, j = key
+            B, T, _ = self._arr.shape
+            return _DeviceLogits(self._arr, self._picks, self._host,
+                                 range(B)[i], range(T)[j])
+        if isinstance(self._j, int):
+            return np.asarray(self)[key]
+        return _DeviceLogits(self._arr, self._picks, self._host,
+                             self._i, self._j[key])
+
+    def argmax(self):
+        if self._i is None or not isinstance(self._j, int):
+            return np.asarray(self).argmax()
+        return int(self._picks[self._i, self._j])
+
+    def __array__(self, dtype=None, copy=None):
+        host, i, j = self._host, self._i, self._j
+        if "whole" not in host:
+            host["whole"] = np.asarray(self._arr)
+        out = host["whole"]
+        if i is None:
+            B, T, _ = out.shape
+            asked = [(b, t) for b in range(B) for t in range(T)]
+        elif isinstance(j, int):
+            asked, out = [(i, j)], out[i, j]
+        else:
+            asked = [(i, t) for t in j]
+            out = out[i, j.start:j.stop:j.step]
+        host["asked"].update(asked)
+        if dtype is not None and out.dtype != dtype:
+            return out.astype(dtype)
+        return out.copy() if copy else out
+
+
 class GenerationRequest:
     """One generation job: prompt in, up to `max_new_tokens` out.
 
@@ -106,8 +212,10 @@ class GenerationRequest:
     streaming hook (and the loadgen's TTFT/inter-token probe).
     `logits_cb(row)` fires from the engine thread too, once for every
     generated token and just before its `stream_cb`, with the logits row
-    that the token was sampled from: a view into the step's fetch, to be
-    copied by a callee that keeps it. None costs one attribute check.
+    that the token was sampled from, float32 `[vocabulary]`: asking is
+    what brings a step's logits to the host (a step leaves them on the
+    device), one copy a step for all the rows that ask in it. None
+    costs one attribute check.
     `spec_decode` opts this request in/out of speculative decoding
     (serving/spec_decode.py): None defers to the engine default
     (FLAGS_gen_spec_decode), False forces plain one-token decode, True
@@ -300,6 +408,7 @@ class GenerationEngine:
                     num_blocks=self.num_blocks, state_prefix=state_prefix)
         with fluid.program_guard(self._prog, self._startup):
             self.step = cfg.build_paged_step(seq_tokens=1, **dims)
+            _pick_on_device(self.step, "decode")
         # the second (and last) executable of the lifetime: retires
         # one whole block of prompt per row per step
         self._prefill_prog = fluid.Program()
@@ -340,6 +449,7 @@ class GenerationEngine:
                                      self._spec_startup):
                 self.spec_step = cfg.build_paged_step(
                     seq_tokens=self.spec_k + 1, **dims)
+                _pick_on_device(self.spec_step, "spec_verify")
             self._drafter = NgramDrafter(
                 max_ngram=int(FLAGS.spec_decode_ngram), k=self.spec_k)
         else:
@@ -366,8 +476,10 @@ class GenerationEngine:
         self._worker: Optional[threading.Thread] = None
         self._ready = threading.Event()
         self._warm_misses: Optional[int] = None
-        # the side-fetch of the last step, where the model has one
+        # the side-fetch of the last step, where the model has one, and
+        # what the last decode (or verify) step fetched: `_pick_on_device`
         self._probe: Optional[np.ndarray] = None
+        self._picks: Optional[np.ndarray] = None
         # resilience: a failed decode step fails the requests that were
         # mid-step (their KV state is unreplayable) but never the
         # worker; repeated failures trip the breaker and submissions
@@ -437,7 +549,8 @@ class GenerationEngine:
         exactly these; a caller can hand one to `exe.compiled(...)` to
         read its HLO or XLA's memory analysis, or run it again with
         other feed containers of the same shapes to check that nothing
-        recompiles."""
+        recompiles. `fetch_var` is the first of `fetch_list(prog)`: a
+        step's picks (its probe row, for the prefill step)."""
         B = self.max_slots
         mb = self.step.max_blocks_per_slot
         return [(name, prog,
@@ -445,7 +558,7 @@ class GenerationEngine:
                   step.table_var.name: np.zeros((B, mb), np.int64),
                   step.start_var.name: np.zeros(B, np.int64),
                   step.nvalid_var.name: np.zeros(B, np.int64)},
-                 step.logits_var)
+                 step.fetch_vars[0])
                 for name, prog, step, t in self._paged_cells()]
 
     def _paged_cells(self):
@@ -460,7 +573,7 @@ class GenerationEngine:
         return cells
 
     def fetch_list(self, prog):
-        """What a run of `prog` fetches: the step's logits (or probe
+        """What a run of `prog` fetches: the step's picks (or probe
         row) and, in the same fetch, the few int32 a model with a
         `probe_var` counts in its decode step (`step.fetch_vars`).
         `executables()` names the first; a caller that compiles or
@@ -637,9 +750,15 @@ class GenerationEngine:
 
     # -- decode step -----------------------------------------------------
     def _run_paged(self, prog, step, tokens, table, start, nvalid):
-        """One run of an executable; returns its logits (or probe
-        row). A model's side-fetch comes back in the same fetch and is
-        left in `_probe` for the iteration's record."""
+        """One run of an executable; returns its logits (the prefill
+        step's probe row, a NumPy `[B]`). What a decode or verify step
+        brings to the host is its picks (`_pick_on_device`: each
+        position's arg-max and health number), left in `_picks` as a
+        model's side-fetch is left in `_probe` for the iteration's
+        record. The logits stay where the step wrote them: the return
+        is `[B, T, V]` on the device, `out[i, j]` a row that `np.array`
+        reads, then and after later steps have run, and that crosses to
+        the host only when it is read."""
         out, *probe = self.exe.run(
             prog,
             feed={step.token_var.name: tokens,
@@ -649,7 +768,11 @@ class GenerationEngine:
             fetch_list=step.fetch_vars,
             scope=self.scope)
         self._probe = np.asarray(probe[0]) if probe else None
-        return np.asarray(out)
+        if step.logits_name is None:
+            return np.asarray(out)
+        self._picks = np.asarray(out)
+        return _DeviceLogits(self.scope.find_var(step.logits_name),
+                             self._picks[0])
 
     # -- KV-block bookkeeping (worker thread only) -----------------------
     def _alloc_block(self) -> Optional[int]:
@@ -861,6 +984,9 @@ class GenerationEngine:
                      rec.moe_selected_held / rec.moe_selected)
             STAT_SET("serving.gen_moe_experts_hit", rec.moe_experts_hit)
             STAT_SET("serving.gen_moe_load_max", rec.moe_load_max)
+        if rec.logit_rows_fetched:
+            STAT_ADD("serving.gen_logit_rows_fetched",
+                     rec.logit_rows_fetched)
         if rec.decode_rows and _monitor_on():
             STAT_OBSERVE("serving.gen_slot_occupancy",
                          rec.decode_rows / float(rec.slots),
@@ -958,7 +1084,7 @@ class GenerationEngine:
                          buckets=MS_BUCKETS)
         st.t_prev_token = t_step
         if st.req.logits_cb is not None:
-            st.req.logits_cb(row)
+            st.req.logits_cb(np.asarray(row))
         if st.req.stream_cb is not None:
             st.req.stream_cb(tok)
             if st.phase_span is not None:
@@ -1170,24 +1296,34 @@ class GenerationEngine:
         with trace.region("gen.sample"):
             self._sample_paged(rec, logits, tokens, n_draft, decode_idx,
                                use_spec)
+            # a NumPy array in the logits' place was fetched whole by
+            # whoever returned it, and is not counted
+            rec.logit_rows_fetched += getattr(logits, "rows_read", 0)
 
     def _sample_paged(self, rec, logits, tokens, n_draft, decode_idx,
                       use_spec):
-        """What the host does with the logits of a decode (or verify)
-        step once they are fetched: the finiteness guard, then for every
-        slot sampling (or draft acceptance), the request's hooks, and
-        the finish, release and prefix registration that follow."""
+        """What the host does after a decode (or verify) step: the
+        finiteness guard over the step's fetched health numbers, then
+        for every slot sampling (or draft acceptance), the request's
+        hooks, and the finish, release and prefix registration that
+        follow. `logits` is `_run_paged`'s return, on the device: a row
+        is handed to `sampling.sample_token` as it lies there, and is
+        read only by what asks for its numbers, a temperature or a
+        `logits_cb`. A NumPy `[B, T, V]` in its place is served from as
+        it is."""
         from ..core.flags import FLAGS
         from ..models import sampling
+        health = self._picks[1]
         inj = _fault_injector()
         if inj is not None:
-            arrs = [logits]
+            arrs = [health]
             if inj.corrupt_fetches("generation", arrs):
-                logits = arrs[0]
+                health = arrs[0]
         if FLAGS.serving_nan_guard:
-            bad = [i for i in decode_idx
-                   if not np.all(np.isfinite(
-                       logits[i, :1 + n_draft[i]]))]
+            finite = np.isfinite(health)
+            bad = [] if finite.all() else [
+                i for i in decode_idx
+                if not finite[i, :1 + n_draft[i]].all()]
             if bad:
                 self._breaker.record_failure()
                 STAT_ADD("resilience.gen_step_failures")

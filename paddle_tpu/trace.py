@@ -442,6 +442,7 @@ class IterationRecord:
                  "state_slots_live", "state_bytes",
                  "prefix_skipped_recurrent", "moe_selected",
                  "moe_selected_held", "moe_experts_hit", "moe_load_max",
+                 "logit_rows_fetched",
                  "slots", "block_size", "host_s", "_open")
 
     def __init__(self, slots, block_size, kv_blocks_total):
@@ -467,6 +468,12 @@ class IterationRecord:
         self.prefix_skipped_recurrent = 0
         self.moe_selected = self.moe_selected_held = 0
         self.moe_experts_hit = self.moe_load_max = 0
+        # positions whose logits the host asked for in the turn: a
+        # decode step leaves them on the device and brings back each
+        # row's arg-max, so this counts the rows that were sampled with
+        # a temperature, handed to a `logits_cb` or read by a caller of
+        # `_run_paged`; beside `decode_rows` it gives the share
+        self.logit_rows_fetched = 0
         self.slots = slots
         self.block_size = block_size
         self.host_s: Dict[str, float] = {}

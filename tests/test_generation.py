@@ -616,6 +616,212 @@ def test_logits_cb_gets_the_rows_the_step_call_returned(trained, spec):
         assert any(f.shape[1] == 3 for f in fetched)
 
 
+# ---------------------------------------------------------------------------
+# What a decode step brings back (PR 32): the picks, and the rows that
+# were asked for
+# ---------------------------------------------------------------------------
+
+def _cb_keeps(rows):
+    return lambda row: rows.append(np.array(row))
+
+
+@pytest.mark.parametrize("mix", ["greedy", "mixed"])
+def test_rows_cross_to_the_host_only_for_what_asks(trained, mix):
+    """Every request decodes to the serial decoder's tokens. A greedy
+    one is answered from the device's pick and none of its logits is
+    fetched; a temperature (here with top_k) reads the row it draws
+    from, a `logits_cb` the row it is handed: `logit_rows_fetched`
+    counts exactly those, a token each."""
+    import time
+
+    from paddle_tpu import monitor
+    cfg, scope, exe = trained
+    dec_main, step = _serial_decode(cfg)
+    rows, want_rows = [], []
+    reqs = [dict(prompt=[0, 1, 2], max_new=5),
+            dict(prompt=[1, 2, 3, 4], max_new=4)]
+    asked = 0
+    if mix == "mixed":
+        reqs += [dict(prompt=[5, 6], max_new=5, temperature=0.9,
+                      top_k=3, seed=11),
+                 dict(prompt=[7, 8, 9], max_new=4)]
+        asked = 5 + 4
+    want = []
+    for k, r in enumerate(reqs):
+        kw = {a: r[a] for a in ("temperature", "top_k", "seed") if a in r}
+        if mix == "mixed" and k == 3:
+            kw["logits_cb"] = _cb_keeps(want_rows)
+        want.append(_kv(exe, scope, dec_main, step, r["prompt"],
+                        r["max_new"], **kw))
+    prev = fluid.FLAGS.enable_monitor
+    fluid.set_flags({"FLAGS_enable_monitor": True})
+    monitor.reset_stats()
+    try:
+        eng = GenerationEngine(cfg, scope, exe=fluid.Executor(),
+                               max_slots=2, max_seq=SEQ, block_size=4)
+        eng.start()
+        t0 = time.perf_counter()
+        try:
+            resps = []
+            for k, r in enumerate(reqs):
+                kw = {a: r[a] for a in ("temperature", "top_k", "seed")
+                      if a in r}
+                if mix == "mixed" and k == 3:
+                    kw["logits_cb"] = _cb_keeps(rows)
+                resps.append(eng.submit(GenerationRequest(
+                    r["prompt"], r["max_new"], **kw)))
+            got = [r.result(timeout=60.0)["tokens"] for r in resps]
+            assert eng.post_warmup_compiles() == 0, eng.cache_stats()
+        finally:
+            eng.stop()
+        snap = monitor.get_stats_snapshot()
+    finally:
+        monitor.reset_stats()
+        fluid.set_flags({"FLAGS_enable_monitor": prev})
+    assert got == want, (got, want)
+    recs = _records_since(t0)
+    assert all("logit_rows_fetched" in r for r in recs)
+    assert all(r["logit_rows_fetched"] <= r["decode_rows"] for r in recs)
+    assert sum(r["logit_rows_fetched"] for r in recs) == asked
+    assert snap["counters"].get("serving.gen_logit_rows_fetched", 0) \
+        == asked
+    if mix == "mixed":
+        # the rows handed over are float32 NumPy rows, the serial
+        # decoder's to rounding, and each token is its row's arg-max
+        assert len(rows) == len(want_rows) == 4
+        assert all(type(r) is np.ndarray and r.dtype == np.float32
+                   and r.shape == (VOCAB,) for r in rows)
+        np.testing.assert_allclose(rows, want_rows, rtol=1e-4, atol=1e-4)
+        assert [int(r.argmax()) for r in rows] == got[3]
+
+
+def test_step_return_stays_readable_and_a_numpy_one_is_served_from(trained):
+    """`_run_paged` returns the step's logits as they lie on the
+    device: `[i, 0]` reads to the row whose arg-max is the token the
+    slot emitted, at once and after later steps have run (nothing is
+    donated away under a caller who kept the return). A NumPy array
+    returned in its place is what the engine then samples from."""
+    cfg, scope, exe = trained
+    dec_main, step = _serial_decode(cfg)
+    want = _kv(exe, scope, dec_main, step, [3, 4, 5], 6)
+
+    def run(alter):
+        eng = GenerationEngine(cfg, scope, exe=fluid.Executor(),
+                               max_slots=2, max_seq=SEQ, block_size=4)
+        kept, at_once = [], []
+        inner = eng._run_paged
+
+        def tap(prog, step, tokens, table, start, nvalid):
+            out = inner(prog, step, tokens, table, start, nvalid)
+            if prog is eng._prefill_prog:
+                return out
+            assert out.shape == (2, 1, VOCAB)
+            out = alter(out)
+            kept.append(out)
+            at_once.append(np.array(out[0, 0], np.float32))
+            return out
+
+        eng._run_paged = tap
+        eng.start()
+        try:
+            toks = eng.generate([3, 4, 5], 6)["tokens"]
+        finally:
+            eng.stop()
+        return toks, kept, at_once
+
+    toks, kept, at_once = run(lambda out: out)
+    assert toks == want and len(kept) == 6
+    later = [np.array(f[0, 0], np.float32) for f in kept]
+    assert all(r.shape == (VOCAB,) for r in later)
+    assert [int(r.argmax()) for r in later] == toks
+    assert all(np.array_equal(a, b) for a, b in zip(at_once, later))
+    # the whole of a return reads as the array it is
+    assert np.array_equal(np.asarray(kept[2])[0, 0], later[2])
+
+    # the vocabulary turned by one: every token is one past the model's
+    toks, kept, _ = run(lambda out: np.roll(np.asarray(out), 1, axis=-1))
+    assert all(type(f) is np.ndarray for f in kept)
+    assert toks == [int(f[0, 0].argmax()) for f in kept]
+    assert toks[0] == (want[0] + 1) % VOCAB and toks != want
+
+
+@pytest.mark.parametrize("fault", ["weights", "step_nan"])
+def test_a_non_finite_row_fails_its_own_request_and_no_other(trained,
+                                                             fault):
+    """The guard reads the step's fetched health numbers, a row each:
+    a row whose logits are not finite (the embedding of its token
+    poked; the injector's `step_nan` at site `generation`, which pokes
+    slot 0's) fails its request, and the row beside it in the same
+    step decodes on to the serial decoder's tokens."""
+    from paddle_tpu.resilience.faults import reset_injector
+    cfg, scope, exe = trained
+    dec_main, step = _serial_decode(cfg)
+    want = _kv(exe, scope, dec_main, step, [7], 5)
+    emb = np.array(scope.find_var("word_emb"))
+    # pools of its own: a block that held NaN is not left to the
+    # module's other engines
+    eng = GenerationEngine(cfg, scope, exe=fluid.Executor(), max_slots=2,
+                           max_seq=SEQ, block_size=4,
+                           state_prefix=f"nan_{fault}.")
+    try:
+        if fault == "weights":
+            poked = emb.copy()
+            poked[12] = np.nan     # a token the other request never sees
+            scope.set("word_emb", poked)
+        else:
+            fluid.set_flags({"FLAGS_fault_spec":
+                             "step_nan:at=1:site=generation"})
+            reset_injector()
+        # both queued before the worker starts (the deadline has to
+        # outlast the warm-up): the first turn admits them to slots 0
+        # and 1, and their first decode step is one
+        bad = eng.submit(GenerationRequest([12], 4, timeout_ms=6e5))
+        good = eng.submit(GenerationRequest([7], 5, timeout_ms=6e5))
+        eng.start()
+        try:
+            with pytest.raises(RuntimeError, match="non-finite logits"):
+                bad.result(timeout=60.0)
+            assert good.result(timeout=60.0)["tokens"] == want
+        finally:
+            eng.stop()
+    finally:
+        scope.set("word_emb", emb)
+        fluid.set_flags({"FLAGS_fault_spec": ""})
+        reset_injector()
+
+
+def test_compiling_an_executable_either_way_finds_the_warmed_one(trained):
+    """`executables()` names the first variable a run of each program
+    fetches: compiled as `[fetch]` (benchmark/families/gpt_serve.py)
+    or as `fetch_list(prog)` (hybrid_serve.py), each of the three is
+    the executable `start()` warmed, and a step's fetch is the small
+    `[2, slots, tokens]` picks, not its logits."""
+    cfg, scope, _ = trained
+    eng = GenerationEngine(cfg, scope, exe=fluid.Executor(), max_slots=2,
+                           max_seq=SEQ, block_size=4, spec_decode=True,
+                           spec_k=2)
+    eng.start()
+    try:
+        cells = eng.executables()
+        assert [c[0] for c in cells] == ["decode", "prefill", "spec_verify"]
+        with fluid.scope_guard(scope):
+            for name, prog, feed, fetch in cells:
+                assert eng.fetch_list(prog) == [fetch]
+                for fetch_list in ([fetch], eng.fetch_list(prog)):
+                    compiled = eng.exe.compiled(prog, feed=feed,
+                                                fetch_list=fetch_list)
+                    assert compiled.memory_analysis() is not None
+        assert eng.post_warmup_compiles() == 0, eng.cache_stats()
+        assert tuple(eng.step.picks_var.shape) == (2, 2, 1)
+        assert tuple(eng.spec_step.picks_var.shape) == (2, 2, 3)
+        assert [tuple(c[3].shape) for c in cells] == \
+            [(2, 2, 1), (2,), (2, 2, 3)]
+        assert eng.generate([0, 1, 2], 3)["tokens"] == [3, 4, 5]
+        assert eng.post_warmup_compiles() == 0, eng.cache_stats()
+    finally:
+        eng.stop()
+
+
 def _post(url, obj):
     req = urllib.request.Request(
         url, data=json.dumps(obj).encode(),

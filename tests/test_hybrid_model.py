@@ -7,6 +7,7 @@ engine's programs, GPT's three and this model's two, as the parent
 commit built them."""
 import hashlib
 import json
+import time
 
 import jax
 import jax.numpy as jnp
@@ -299,6 +300,7 @@ def engine_logits(dtype, max_slots=3):
                                 max_slots=max_slots))
     cell = hybrid_serve.build(cfg, {"timeout_ms": 600000}, 1, SEED)
     cell.warm()
+    t0 = time.perf_counter()
     rng = np.random.default_rng(6)
     jobs = []
     for n_prompt, n_out in [(1, 5), (17, 6), (40, 4), (16, 5), (33, 7),
@@ -311,8 +313,9 @@ def engine_logits(dtype, max_slots=3):
         jobs.append((prompt, rows, resp))
     done = [(prompt, rows, resp.result(timeout=300))
             for prompt, rows, resp in jobs]
-    records = [r for r in fluid.trace.iteration_records()]
-    cell.stop()
+    cell.stop()     # the last turn's record is there once it has ended
+    records = [r for r in fluid.trace.iteration_records()
+               if r["t_start"] >= t0]
     return cfg, done, records
 
 
@@ -334,9 +337,15 @@ def test_engine_prefill_and_decode_match_the_full_forward(dtype, limit):
         gap = np.abs(np.stack(rows) - want) / want.std(axis=-1,
                                                        keepdims=True)
         assert gap.max() < limit, (len(prompt), gap.max())
+        # greedy: the token is the arg-max of the row handed over
+        assert [int(r.argmax()) for r in rows] == tokens
     assert any(r["state_slots_live"] == 3 and r["state_bytes"] > 0
                for r in records)
     assert sum(r["moe_selected"] for r in records) > 0
+    # every request asked for its rows (`logits_cb`): one crossed to
+    # the host for each token, and no other
+    assert sum(r["logit_rows_fetched"] for r in records) == \
+        sum(len(result["tokens"]) for _, _, result in done)
 
 
 # -- (e) what the engine does not do for a recurrent model --------------------
@@ -348,6 +357,7 @@ def test_prefix_cache_adopts_nothing_and_spec_disagg_wire_refuse():
     assert eng.recurrent and eng.state_bytes() == \
         cfg["engine"]["max_slots"] * eng.cfg.state_slot_bytes()
     cell.warm()
+    t0 = time.perf_counter()
     prompt = list(range(40))
     first = eng.generate(prompt, 2, timeout_ms=600000)
     before = sum(r["prefix_skipped_recurrent"]
@@ -358,6 +368,19 @@ def test_prefix_cache_adopts_nothing_and_spec_disagg_wire_refuse():
     assert first["cached_tokens"] == again["cached_tokens"] == 0
     assert again["tokens"] == first["tokens"] and after == before + 1
     assert len(eng._prefix) == 0
+    # greedy, and nobody asked for a row: the picks and the expert
+    # layers' probe are all a step brought back. Compiled with the
+    # engine's own fetch list, each program is the executable it warmed
+    assert sum(r["logit_rows_fetched"]
+               for r in fluid.trace.iteration_records()
+               if r["t_start"] >= t0) == 0
+    assert eng.fetch_list(eng._prog) == [eng.step.picks_var,
+                                         eng.step.probe_var]
+    with fluid.scope_guard(eng.scope):
+        for _, prog, feed, _ in eng.executables():
+            eng.exe.compiled(prog, feed=feed,
+                             fetch_list=eng.fetch_list(prog))
+    assert eng.post_warmup_compiles() == 0, eng.cache_stats()
     with pytest.raises(ValueError, match="recurrent"):
         disagg.export_prefix(eng, prompt)
     with pytest.raises(ValueError, match="recurrent state"):
@@ -374,16 +397,38 @@ def test_prefix_cache_adopts_nothing_and_spec_disagg_wire_refuse():
 
 # -- (f) GPT's programs through the same hook ---------------------------------
 
-def fingerprint(prog):
+ENGINE_OPS = ["arg_max", "cast", "reduce_max", "reduce_min", "scale",
+              "elementwise_max", "stack", "assign"]
+
+
+def model_ops(prog, step):
+    """The ops of the model's own step: those before the first that
+    reads the step's logits. What follows is the engine's, the same
+    eight for every model (`generation._pick_on_device`, PR 32: the
+    positions' arg-max and largest |logit| stacked into the one fetched
+    variable, and the logits assigned to the state variable that keeps
+    them on the device)."""
+    ops = prog.global_block().ops
+    first = next(k for k, op in enumerate(ops)
+                 if step.logits_var.name in op.input_names())
+    assert [op.type for op in ops[first:]] == ENGINE_OPS
+    assert ops[-1].output_names() == [step.logits_name]
+    assert ops[-2].output_names() == [step.picks_var.name]
+    return ops[:first]
+
+
+def fingerprint(prog, step=None):
     """Op list, attributes and shapes, every variable named by the order
-    it first appears in (the process's name counters do not show)."""
+    it first appears in (the process's name counters do not show). With
+    `step`, a decode or verify step's handle, of the model's own ops
+    (`model_ops`): the digests held below were taken of those."""
     blk = prog.global_block()
     order = {}
 
     def idx(n):
         return order.setdefault(n, len(order))
     sig = []
-    for op in blk.ops:
+    for op in (blk.ops if step is None else model_ops(prog, step)):
         ins = [(k, [idx(n) for n in v]) for k, v in sorted(op.inputs.items())]
         outs = [(k, [idx(n) for n in v])
                 for k, v in sorted(op.outputs.items())]
@@ -391,7 +436,7 @@ def fingerprint(prog):
                     sorted((k, repr(v)) for k, v in op.attrs.items())))
     shapes = [(i, tuple(blk.var(n).shape or ()), str(blk.var(n).dtype),
                bool(blk.var(n).persistable)) for n, i in order.items()]
-    return len(blk.ops), hashlib.sha256(
+    return len(sig), hashlib.sha256(
         json.dumps([sig, shapes]).encode()).hexdigest()
 
 
@@ -409,7 +454,7 @@ def test_gpt_programs_are_the_parents():
     built in the parent commit (digests taken there, PR 28's tree)."""
     eng = GenerationEngine(gpt.gpt_small(**GPT_CFG), fluid.Scope(),
                            max_slots=4, max_seq=128, paged=True)
-    assert fingerprint(eng._prog) == GPT_DECODE
+    assert fingerprint(eng._prog, eng.step) == GPT_DECODE
     assert fingerprint(eng._prefill_prog) == GPT_PREFILL
     assert not eng.recurrent and eng.state_bytes() == 0
     assert eng.step.state_names == [] and eng.step.probe_var is None
@@ -426,10 +471,10 @@ def test_gpt_verify_program_is_the_parents():
     assert [name for name, *_ in eng.executables()] == \
         ["decode", "prefill", "spec_verify"]
     assert eng.spec_step.seq_tokens == 3
-    assert fingerprint(eng._spec_prog) == (
+    assert fingerprint(eng._spec_prog, eng.spec_step) == (
         73, "8aa9ce5610ee3da868f8dc2d7c5f09c1"
             "7b9ce8dd84e81b27b2791ebec3dfa5c3")
-    assert fingerprint(eng._prog) == GPT_DECODE
+    assert fingerprint(eng._prog, eng.step) == GPT_DECODE
     assert fingerprint(eng._prefill_prog) == GPT_PREFILL
 
 
@@ -450,7 +495,7 @@ def test_hybrid_programs_are_the_parents(dtype, decode, prefill):
     cfg, _ = small()
     cfg = dict(cfg, engine=dict(cfg["engine"], dtype=dtype, max_slots=3))
     eng = hybrid_serve.build(cfg, {"timeout_ms": 600000}, 1, SEED).engine
-    assert fingerprint(eng._prog) == decode
+    assert fingerprint(eng._prog, eng.step) == decode
     assert fingerprint(eng._prefill_prog) == prefill
 
 
@@ -463,7 +508,7 @@ def test_paged_argument_selects_nothing():
     for kw in ({}, {"paged": True}, {"paged": None}):
         eng = GenerationEngine(cfg, fluid.Scope(), max_slots=4,
                                max_seq=128, **kw)
-        assert fingerprint(eng._prog) == GPT_DECODE
+        assert fingerprint(eng._prog, eng.step) == GPT_DECODE
         assert fingerprint(eng._prefill_prog) == GPT_PREFILL
         assert not hasattr(eng, "paged")
     with pytest.raises(ValueError, match="paged=False.*PR 31"):
