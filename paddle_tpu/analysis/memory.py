@@ -135,16 +135,18 @@ class MemoryPlan:
         """Decode KV-cache footprint, when this program holds one.
 
         Recognizes the two generation KV layouts by persistable naming
-        convention: `*.kv_pool_k` / `*.kv_pool_v` are the block pools
-        of the paged decode step (models/gpt.build_paged_decode_step —
-        sized num_blocks x block_size, decoupled from max_slots x
-        max_seq), `*.cache_k` / `*.cache_v` are the contiguous slabs of
+        convention: `*.kv_pool_k` / `*.kv_pool_v`, or a latent
+        layer's one `*.kv_pool`, are the block pools of the paged
+        decode step (models/gpt.build_paged_decode_step,
+        models/hybrid.py: sized num_blocks x block_size, decoupled from
+        max_slots x max_seq), `*.cache_k` / `*.cache_v` are the contiguous slabs of
         the classic step (sized max_slots x max_seq). Both are pinned
         at full size by the planner, so `kv_bytes` is exactly what the
         PTV050 budget gate prices them at. None when the program holds
         neither (i.e. it is not a decode program)."""
         paged = [iv for iv in self.intervals.values()
-                 if iv.name.endswith((".kv_pool_k", ".kv_pool_v"))]
+                 if iv.name.endswith((".kv_pool_k", ".kv_pool_v",
+                                      ".kv_pool"))]
         slab = [iv for iv in self.intervals.values()
                 if iv.name.endswith((".cache_k", ".cache_v"))]
         if not paged and not slab:

@@ -1,12 +1,16 @@
-"""Pattern-string decoders: state-space, latent-expert and attention
+"""Pattern-string decoders: state-space, expert, FFN and attention
 layers in one stack, served by `serving.GenerationEngine`.
 
 The layer list is a string, one letter a block: `M` a Mamba-2 mixer
 (`ops/state_space.py`), `E` a latent-expert layer
 (`parallel/moe.py:latent_moe`), `*` attention with grouped KV heads over
-the paged pool (`ops/attention.py:paged_attention`). Every block is ONE
-mixer behind a pre-RMSNorm with a residual; a final RMSNorm and an
-untied head follow the last; there is no position encoding.
+the paged pool (`ops/attention.py:paged_attention`), no positions; `L`
+latent attention with YaRN rotary positions over a paged LATENT pool
+(`ops/latent_attention.py`: one row a token for all heads), `D` a
+gated FFN, `G` gated experts (`parallel/moe.py:gated_moe`). Every
+block is ONE mixer behind a pre-RMSNorm with a residual, so a layer of
+attention AND an FFN is two letters (`LD`, `LG`); a final RMSNorm and
+an untied head follow the last.
 
 `HybridConfig.build_paged_step` yields the engine's decode (T = 1, with
 logits) and chunk-prefill (T = block_size, a health probe) programs
@@ -32,15 +36,23 @@ class HybridConfig:
     `n_experts` live here, share `expert_share` of them (expert
     parallelism: `parallel/moe.py`)."""
 
-    def __init__(self, vocab_size, d_model, pattern, n_heads, n_kv_heads,
-                 head_dim, mamba_heads, mamba_head_dim, ssm_state,
-                 ssm_groups, conv_kernel, n_experts, experts_held, top_k,
-                 moe_latent, moe_inter, shared_inter, routed_scale=1.0,
-                 expert_share=0, eps=1e-5, dtype="bfloat16",
-                 max_seq_len=2048):
+    def __init__(self, vocab_size, d_model, pattern, n_heads, n_kv_heads=1,
+                 head_dim=0, mamba_heads=0, mamba_head_dim=0, ssm_state=0,
+                 ssm_groups=1, conv_kernel=0, n_experts=1, experts_held=0,
+                 top_k=0, moe_latent=0, moe_inter=0, shared_inter=0,
+                 routed_scale=1.0, expert_share=0, eps=1e-5,
+                 dtype="bfloat16", max_seq_len=2048, q_rank=0, kv_rank=0,
+                 nope_dim=0, rope_dim=0, v_dim=0, dense_inter=0, rope=None):
+        """`L` layers: `q_rank`, `kv_rank` the latents' widths, a head's
+        `nope_dim` + `rope_dim` query/key and `v_dim` value; `rope` the
+        rotary attributes of `ops/latent_attention.py` (theta, factor,
+        original, beta_fast, beta_slow, mscale, mscale_all_dim). `D`:
+        `dense_inter`. `G`: `moe_inter`, `shared_inter` and the router
+        as `E`."""
         for kind in pattern:
-            if kind not in "ME*":
-                raise ValueError(f"pattern letter {kind!r}: M, E or *")
+            if kind not in "ME*LDG":
+                raise ValueError(
+                    f"pattern letter {kind!r}: M, E, *, L, D or G")
         if n_heads % n_kv_heads or mamba_heads % ssm_groups:
             raise ValueError("heads must divide into their KV heads / "
                              "state groups")
@@ -58,6 +70,9 @@ class HybridConfig:
         self.moe_latent, self.moe_inter = moe_latent, moe_inter
         self.shared_inter, self.routed_scale = shared_inter, routed_scale
         self.eps, self.dtype, self.max_seq_len = eps, dtype, max_seq_len
+        self.q_rank, self.kv_rank = q_rank, kv_rank
+        self.nope_dim, self.rope_dim, self.v_dim = nope_dim, rope_dim, v_dim
+        self.dense_inter, self.rope = dense_inter, dict(rope or {})
 
     @property
     def n_layers(self):
@@ -72,13 +87,16 @@ class HybridConfig:
         return self.mamba_inner + 2 * self.ssm_groups * self.ssm_state
 
     def kv_token_bytes(self):
-        """Bytes a token holds in the paged pools, all layers, K and V:
-        the attention layers' KV heads in the model's type."""
+        """Bytes a token holds in the paged pools, all layers, in the
+        model's type: K and V of a `*` layer's KV heads, the one latent
+        row of an `L` layer."""
         from ..core.dtypes import as_np_dtype
         from ..ops.pallas.paged_attention import pool_lanes
-        return 2 * self.pattern.count("*") * \
-            pool_lanes(self.n_kv_heads * self.head_dim) * \
-            as_np_dtype(self.dtype).itemsize
+        lanes = 2 * self.pattern.count("*") * \
+            pool_lanes(self.n_kv_heads * self.head_dim) + \
+            self.pattern.count("L") * pool_lanes(self.kv_rank
+                                                 + self.rope_dim)
+        return lanes * as_np_dtype(self.dtype).itemsize
 
     def state_slot_bytes(self):
         """Bytes of recurrent state a slot holds, all Mamba-2 layers:
@@ -124,8 +142,11 @@ def build_paged_step(cfg, batch, max_seq, block_size, num_blocks,
     names are `word_emb`, `layer_<i>.norm.w`, `layer_<i>.mixer.*` /
     `.att.*` / `.moe.*`, `final_norm.w`, `lm_head.w`; only the pools
     and the recurrent state carry `state_prefix`, and both programs
-    name them alike, so one scope carries one set."""
+    name them alike, so one scope carries one set. `cache_names` are
+    the pools as the model has them, in layer order: K then V of a `*`
+    layer, the one `kv_pool` of an `L` layer."""
     from ..initializer import Constant, Normal
+    from ..ops.latent_attention import yarn_sm_scale
     from ..ops.pallas.paged_attention import pool_lanes
 
     d, dt = cfg.d_model, cfg.dtype
@@ -216,6 +237,68 @@ def build_paged_step(cfg, batch, max_seq, block_size, num_blocks,
                 layers.transpose(outs["Out"], [0, 2, 1, 3]),
                 [batch, T, h * hd])
             y = dense(ctx, f"{pre}.att.o.w", h * hd, d)
+        elif kind == "L":
+            a = f"{pre}.att"
+            row_w = cfg.kv_rank + cfg.rope_dim
+            kv_b = _param(f"{a}.kv_b.w",
+                          [cfg.kv_rank, h * (cfg.nope_dim + cfg.v_dim)], dt,
+                          mat)
+            proj = _op("mla_project", {
+                "X": u,
+                "QA": _param(f"{a}.q_a.w", [d, cfg.q_rank], dt, mat),
+                "QNorm": _param(f"{a}.q_norm.w", [cfg.q_rank], dt, one),
+                "QB": _param(f"{a}.q_b.w",
+                             [cfg.q_rank, h * (cfg.nope_dim + cfg.rope_dim)],
+                             dt, mat),
+                "KVA": _param(f"{a}.kv_a.w", [d, row_w], dt, mat),
+                "KVNorm": _param(f"{a}.kv_norm.w", [cfg.kv_rank], dt, one),
+                "KVB": kv_b, "StartPos": start},
+                {"Q": dt, "Row": dt},
+                {"heads": h, "nope_dim": cfg.nope_dim,
+                 "rope_dim": cfg.rope_dim, "epsilon": cfg.eps, **cfg.rope})
+            pool = state_var(f"{pre}.kv_pool",
+                             [num_blocks, block_size, pool_lanes(row_w)], dt)
+            cache_names.append(pool.name)
+            outs = _op("paged_attention", {
+                "Q": proj["Q"], "K": proj["Row"], "CacheK": pool,
+                "BlockTable": table, "StartPos": start, "NValid": nvalid},
+                {"Out": dt, "CacheKOut": dt},
+                {"sm_scale": yarn_sm_scale(
+                    cfg.nope_dim + cfg.rope_dim,
+                    cfg.rope.get("factor", 1.0),
+                    cfg.rope.get("mscale_all_dim", 0.0)),
+                 "value_lanes": cfg.kv_rank})
+            layers.assign(outs["CacheKOut"], output=pool)
+            y = _op("mla_output", {
+                "X": outs["Out"], "KVB": kv_b,
+                "WO": _param(f"{a}.o.w", [h * cfg.v_dim, d], dt, mat)},
+                {"Out": dt}, {"nope_dim": cfg.nope_dim})["Out"]
+        elif kind == "D":
+            f = cfg.dense_inter
+            y = _op("gated_ffn", {
+                "X": u,
+                "W1": _param(f"{pre}.ffn.w1", [d, 2 * f], dt, mat),
+                "W2": _param(f"{pre}.ffn.w2", [f, d], dt, mat)},
+                {"Out": dt}, {})["Out"]
+        elif kind == "G":
+            e = f"{pre}.moe"
+            eh, mid, sh = cfg.experts_held, cfg.moe_inter, cfg.shared_inter
+            outs = _op("gated_moe", {
+                "X": u,
+                "RouterW": _param(f"{e}.router.w", [d, cfg.n_experts], dt,
+                                  mat),
+                "RouterBias": _param(f"{e}.router.bias", [cfg.n_experts],
+                                     "float32", Constant(0.0)),
+                "W1": _param(f"{e}.w1", [eh, d, 2 * mid], dt, mat),
+                "W2": _param(f"{e}.w2", [eh, mid, d], dt, mat),
+                "SharedW1": _param(f"{e}.shared.w1", [d, 2 * sh], dt, mat),
+                "SharedW2": _param(f"{e}.shared.w2", [sh, d], dt, mat),
+                "NValid": nvalid},
+                {"Out": dt, "Probe": "int32"},
+                {"top_k": cfg.top_k, "scale": cfg.routed_scale,
+                 "share": cfg.expert_share})
+            probes.append(outs["Probe"])
+            y = outs["Out"]
         else:
             e = f"{pre}.moe"
             eh, lat, mid = cfg.experts_held, cfg.moe_latent, cfg.moe_inter

@@ -32,6 +32,7 @@ from . import parity_final  # noqa: F401
 from . import straggler_ops  # noqa: F401
 from . import fused  # noqa: F401
 from . import state_space  # noqa: F401
+from . import latent_attention  # noqa: F401
 
 
 def registered_types():

@@ -96,15 +96,22 @@ def _paged_attention_op(ctx, ins, attrs):
     entries past the row's pages are never read; a muted row reads
     nothing and returns zeros; rows t >= NValid are don't-care but
     finite.
+
+    A LATENT pool (no V, no CacheV; attr `value_lanes`): a token holds
+    ONE row for all H heads, K [B, T, w] written into CacheK
+    [nb, bs, pool_lanes(w)]; Q is [B, H, T, w], a token's values are
+    the first `value_lanes` numbers of its row, Out is
+    [B, H, T, value_lanes], and the kernel copies each held page once
+    for scores and values both.
     """
-    q, k, v = ins["Q"][0], ins["K"][0], ins["V"][0]
-    cache_k, cache_v = ins["CacheK"][0], ins["CacheV"][0]
+    q, k = ins["Q"][0], ins["K"][0]
+    cache_k = ins["CacheK"][0]
     table = ins["BlockTable"][0].astype(jnp.int32)
     start = ins["StartPos"][0].astype(jnp.int32)
     nvalid = ins["NValid"][0].astype(jnp.int32)
     nb, bs, d = cache_k.shape
     B, H, T, hd = q.shape
-    KV = k.shape[1]
+    latent = not ins.get("V")
     sm_scale = attrs.get("sm_scale") or float(hd) ** -0.5
 
     steps = jnp.arange(T, dtype=jnp.int32)
@@ -113,15 +120,22 @@ def _paged_attention_op(ctx, ins, attrs):
     phys = jnp.take_along_axis(table, qpos // bs, axis=1)
     flat_idx = jnp.where(valid, phys * bs + qpos % bs, 0)
 
-    def write(pool, new):                                # new [B,KV,T,hd]
+    def write(pool, new):                # new [B,KV,T,hd] or [B,T,w]
         flat = pool.reshape(nb * bs, d)
-        rows = pad_lanes(
-            new.transpose(0, 2, 1, 3).reshape(B * T, KV * hd), d)
+        if not latent:
+            new = new.transpose(0, 2, 1, 3)
+        rows = pad_lanes(new.reshape(B * T, -1), d)
         return flat.at[flat_idx.reshape(-1)].set(
             rows.astype(pool.dtype)).reshape(nb, bs, d)
 
     ck_new = write(cache_k, k)
-    cv_new = write(cache_v, v)
+    if latent:
+        out = paged_attention_read(
+            q, ck_new, None, table, start, nvalid,
+            sm_scale=float(sm_scale), value_lanes=int(attrs["value_lanes"]))
+        return {"Out": [out.astype(q.dtype)], "CacheKOut": [ck_new]}
+    v, KV = ins["V"][0], k.shape[1]
+    cv_new = write(ins["CacheV"][0], v)
     out = paged_attention_read(q, ck_new, cv_new, table, start, nvalid,
                                sm_scale=float(sm_scale),
                                kv_heads=None if KV == H else KV)

@@ -34,6 +34,10 @@ from .flash_attention import NEG_INF, _interpret
 # 128 keys, one lane tile of scores
 PAGES = 8
 LANES = 128
+# what the latent kernel may take of VMEM: at 64 heads x 16 tokens its
+# scores, probabilities, accumulator and blocks pass the 16 MiB that a
+# kernel gets unasked
+LATENT_VMEM_BYTES = 64 * 2**20
 
 
 def pool_lanes(d_model):
@@ -51,13 +55,14 @@ def pad_lanes(x, lanes):
         if extra else x
 
 
-def _page_walk(table_ref, b, n_pages, k_hbm, v_hbm, kbuf, vbuf, sem,
-               max_blocks):
+def _page_walk(table_ref, b, n_pages, copies, sem, max_blocks):
     """(start_chunk, wait_chunk) over row `b`'s pages: chunk c is pages
-    [c PAGES, (c + 1) PAGES) of the row's table, copied into buffer
-    slot `slot`. A copy is started and waited for under the same guard,
-    and a page past the row's length costs none."""
-    pages = kbuf.shape[1]
+    [c pages, (c + 1) pages) of the row's table, copied into buffer
+    slot `slot`, once for each (pool, buffer) of `copies`: keys and
+    values, or the one pool of a latent cache. A copy is started and
+    waited for under the same guard, and a page past the row's length
+    costs none."""
+    pages = copies[0][1].shape[1]
 
     def for_held_pages(chunk, slot, act):
         for p in range(pages):
@@ -66,8 +71,7 @@ def _page_walk(table_ref, b, n_pages, k_hbm, v_hbm, kbuf, vbuf, sem,
             @pl.when(page < n_pages)
             def _():
                 block = table_ref[b * max_blocks + page]
-                for which, (hbm, buf) in enumerate(((k_hbm, kbuf),
-                                                    (v_hbm, vbuf))):
+                for which, (hbm, buf) in enumerate(copies):
                     act(pltpu.make_async_copy(
                         hbm.at[block], buf.at[slot, p],
                         sem.at[which, slot, p]))
@@ -94,7 +98,8 @@ def _kernel(table_ref, start_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
     n_chunks = (n_pages + pages - 1) // pages
 
     start_chunk, wait_chunk = _page_walk(
-        table_ref, b, n_pages, k_hbm, v_hbm, kbuf, vbuf, sem, max_blocks)
+        table_ref, b, n_pages, ((k_hbm, kbuf), (v_hbm, vbuf)), sem,
+        max_blocks)
 
     @pl.when(length == 0)
     def _muted():
@@ -181,7 +186,8 @@ def _kernel_grouped(table_ref, start_ref, len_ref, q_ref, k_hbm, v_hbm,
     n_pages = (length + bs - 1) // bs
     n_chunks = (n_pages + pages - 1) // pages
     start_chunk, wait_chunk = _page_walk(
-        table_ref, b, n_pages, k_hbm, v_hbm, kbuf, vbuf, sem, max_blocks)
+        table_ref, b, n_pages, ((k_hbm, kbuf), (v_hbm, vbuf)), sem,
+        max_blocks)
 
     @pl.when(length == 0)
     def _muted():
@@ -276,9 +282,121 @@ def _read_grouped(q, pool_k, pool_v, table, start, length, sm_scale,
     return out.reshape(B, H, T, hd)
 
 
-@functools.partial(jax.jit, static_argnames=("sm_scale", "kv_heads"))
+# a latent page is one row kind of 640 lanes: 32 pages of 16 tokens are
+# 512 keys a turn, so that the walk's own cost is a small share of a
+# row of some thousand keys
+PAGES_LATENT = 32
+
+
+def _kernel_latent(table_ref, start_ref, len_ref, q_ref, k_hbm, o_ref,
+                   kbuf, sem, m_s, l_s, acc_s, *, sm_scale, tokens,
+                   value_lanes, max_blocks):
+    """A latent cache: ONE pool, one row a token, shared by every query
+    head; a token's values are the first `value_lanes` lanes of its
+    key. Each held page is copied once and the copy serves both
+    products. The queries come as the rows (head, token) of `q_ref[0]`,
+    as wide as the pool. The products take the pool's type with float32
+    accumulation (float32 pools: at full precision); max, sum and
+    accumulator are float32."""
+    b = pl.program_id(0)
+    _, pages, bs, d = kbuf.shape
+    rows = q_ref.shape[1]
+    span = pages * bs
+    length = len_ref[b]
+    start = start_ref[b]
+    n_pages = (length + bs - 1) // bs
+    n_chunks = (n_pages + pages - 1) // pages
+    start_chunk, wait_chunk = _page_walk(
+        table_ref, b, n_pages, ((k_hbm, kbuf),), sem, max_blocks)
+    precision = jax.lax.Precision.HIGHEST \
+        if kbuf.dtype == jnp.float32 else None
+
+    @pl.when(length == 0)
+    def _muted():
+        o_ref[0] = jnp.zeros(o_ref.shape[1:], o_ref.dtype)
+
+    @pl.when(length > 0)
+    def _attend():
+        start_chunk(0, 0)
+        qpos = start + jax.lax.rem(
+            jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0), tokens)
+        m_s[...] = jnp.full(m_s.shape, NEG_INF, jnp.float32)
+        l_s[...] = jnp.zeros(l_s.shape, jnp.float32)
+        acc_s[...] = jnp.zeros(acc_s.shape, jnp.float32)
+
+        def fold(chunk, carry):
+            slot = chunk % 2
+
+            @pl.when(chunk + 1 < n_chunks)
+            def _next():
+                start_chunk(chunk + 1, 1 - slot)
+
+            wait_chunk(chunk, slot)
+            k = kbuf[slot].reshape(span, d)
+            held = chunk * span + jax.lax.broadcasted_iota(
+                jnp.int32, (span, 1), 0) < length
+            v = jnp.where(held, k[:, :value_lanes], 0.0)
+            s = jax.lax.dot_general(
+                q_ref[0], k, (((1,), (1,)), ((), ())), precision=precision,
+                preferred_element_type=jnp.float32) * sm_scale
+            kpos = chunk * span + jax.lax.broadcasted_iota(
+                jnp.int32, (rows, span), 1)
+            s = jnp.where(jnp.logical_and(kpos <= qpos, kpos < length),
+                          s, NEG_INF)
+            m = m_s[...]
+            m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m - m_new)
+            l_s[...] = alpha * l_s[...] + jnp.sum(p, axis=1, keepdims=True)
+            acc_s[...] = alpha * acc_s[...] + jax.lax.dot_general(
+                p.astype(k.dtype), v, (((1,), (0,)), ((), ())),
+                precision=precision, preferred_element_type=jnp.float32)
+            m_s[...] = m_new
+            return carry
+
+        jax.lax.fori_loop(0, n_chunks, fold, 0)
+        o_ref[0] = (acc_s[...] / l_s[...]).astype(o_ref.dtype)
+
+
+def _read_latent(q, pool, table, start, length, sm_scale, value_lanes):
+    B, H, T, _ = q.shape
+    nb, bs, d = pool.shape
+    rows = H * T
+    q_rows = pad_lanes(q.reshape(B, rows, q.shape[-1]), d).astype(pool.dtype)
+    kernel = functools.partial(
+        _kernel_latent, sm_scale=float(sm_scale), tokens=T,
+        value_lanes=value_lanes, max_blocks=table.shape[1])
+    vmem = functools.partial(pl.BlockSpec, memory_space=pltpu.VMEM)
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(B,),
+            in_specs=[vmem((1, rows, d), lambda b, *_: (b, 0, 0)),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=vmem((1, rows, value_lanes),
+                           lambda b, *_: (b, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((2, PAGES_LATENT, bs, d), pool.dtype),
+                pltpu.SemaphoreType.DMA((1, 2, PAGES_LATENT)),
+                pltpu.VMEM((rows, 1), jnp.float32),
+                pltpu.VMEM((rows, 1), jnp.float32),
+                pltpu.VMEM((rows, value_lanes), jnp.float32),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((B, rows, value_lanes), pool.dtype),
+        interpret=_interpret(),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=(pltpu.GridDimensionSemantics.ARBITRARY,),
+            vmem_limit_bytes=LATENT_VMEM_BYTES),
+        name="paged_attention_read_latent",
+    )(table.reshape(-1), start, length, q_rows, pool)
+    return out.reshape(B, H, T, value_lanes)
+
+
+@functools.partial(jax.jit, static_argnames=("sm_scale", "kv_heads",
+                                             "value_lanes"))
 def paged_attention_read(q, pool_k, pool_v, table, start, nvalid, *,
-                         sm_scale, kv_heads=None):
+                         sm_scale, kv_heads=None, value_lanes=None):
     """Attention of `q` [B, H, T, hd] over each row's own history in the
     paged pools [nb, bs, H*hd]: logical block j of row b is physical
     block `table[b, j]`, query t of row b sits at position
@@ -289,7 +407,10 @@ def paged_attention_read(q, pool_k, pool_v, table, start, nvalid, *,
     KV head g serves query heads [g H/KV, (g+1) H/KV); that case, and a
     pool that is not float32, take the grouped kernel, chosen here from
     the static shapes and types: H == KV over float32 pools compiles to
-    the one kernel it always did.
+    the one kernel it always did. With `pool_v` None the pool is a
+    latent cache: one row a token for all H heads, `q` as wide as a row
+    holds numbers, the values the row's first `value_lanes` lanes;
+    returns [B, H, T, value_lanes] in the pool's type.
 
     Jitted, so that the layers of one program (same shapes) share one
     trace and one lowering of the kernel."""
@@ -297,6 +418,9 @@ def paged_attention_read(q, pool_k, pool_v, table, start, nvalid, *,
     nb, bs, d = pool_k.shape
     max_blocks = table.shape[1]
     length = jnp.where(nvalid > 0, start + nvalid, 0)
+    if pool_v is None:
+        return _read_latent(q, pool_k, table, start, length, sm_scale,
+                            int(value_lanes))
     if (kv_heads or H) != H or pool_k.dtype != jnp.float32:
         return _read_grouped(q, pool_k, pool_v, table, start, length,
                              sm_scale, kv_heads or H)

@@ -27,6 +27,8 @@ of the held experts (`experts_apply`):
   capacity, and no token is ever dropped. What the experts of other
   shares would add is theirs to add: under an `ep` mesh axis a psum
   sums the shares, on one chip nothing stands in for them.
+  `gated_moe` is the same layer without the latent projections and
+  with gated experts (`(silu(u W_gate) * u W_up) W_down`).
 """
 from __future__ import annotations
 
@@ -38,7 +40,8 @@ from jax.sharding import PartitionSpec as P
 
 __all__ = ["moe_ffn", "moe_ffn_sharded", "moe_ffn_sparse",
            "moe_ffn_sparse_sharded", "init_moe_params", "route_top_k",
-           "experts_apply", "latent_moe", "latent_moe_sharded"]
+           "experts_apply", "latent_moe", "latent_moe_sharded", "gated_moe",
+           "gated_moe_sharded"]
 
 
 def init_moe_params(rng, n_experts, d_model, d_ff, dtype=jnp.float32):
@@ -275,28 +278,24 @@ def _relu2(h):
     return jnp.square(jax.nn.relu(h))
 
 
-def latent_moe(x, p, top_k, scale, share=0, n_valid=None, axis_name=None):
-    """x [B, T, d]; p: router_w [d, E], router_bias [E], down [d, L],
-    w1 [E_held, L, f], w2 [E_held, f, L], up [L, d], shared_w1 [d, s],
-    shared_w2 [s, d]. This shard holds experts [share E_held,
-    (share + 1) E_held) of the router's E. `n_valid` [B] leaves tokens
-    t >= n_valid[b] out (routed nowhere, counted nowhere; their output
-    is the shared expert's and is don't-care). Inside shard_map,
-    `axis_name` sums the shares' routed parts.
+def _swiglu(h):
+    """Gate and up side by side: silu(first half) * second half."""
+    f = h.shape[-1] // 2
+    return jax.nn.silu(h[:, :f]) * h[:, f:]
 
-    Returns (out [B, T, d], probe [4] int32: selections made, those
-    that fell on held experts, held experts with at least one token,
-    tokens on the busiest held expert)."""
-    b, t, d = x.shape
-    n = b * t
+
+def _routed_share(x2, rows_in, p, top_k, scale, share, valid, act,
+                  axis_name):
+    """What this share's held experts add for tokens `x2` [N, d]: route
+    over the whole router, sort the selections that fell on held
+    experts by expert, evaluate them over `rows_in` [N, d_in] (the
+    experts' input a token) with grouped products, weigh and sum each
+    token's. Returns (routed [N, d_out] float32, probe [4] int32)."""
+    n = x2.shape[0]
     eh = p["w1"].shape[0]
-    x2 = x.reshape(n, d)
     _, sel, w = route_top_k(x2, p["router_w"], top_k, score="sigmoid",
                             select_bias=p["router_bias"], normalise=True,
                             scale=scale)
-    valid = jnp.ones((n,), bool) if n_valid is None else (
-        jnp.arange(t, dtype=jnp.int32)[None, :]
-        < n_valid.astype(jnp.int32)[:, None]).reshape(n)
     local = sel - share * eh
     held = (local >= 0) & (local < eh) & valid[:, None]
     # selections sorted by held expert; those of other shares go last,
@@ -304,9 +303,7 @@ def latent_moe(x, p, top_k, scale, share=0, n_valid=None, axis_name=None):
     eid = jnp.where(held, local, eh).reshape(n * top_k)
     order = jnp.argsort(eid, stable=True)
     sizes = jnp.zeros((eh + 1,), jnp.int32).at[eid].add(1)[:eh]
-    lat = jnp.matmul(x2, p["down"],
-                     preferred_element_type=jnp.float32).astype(x.dtype)
-    f = experts_apply(lat[order // top_k], sizes, p["w1"], p["w2"], _relu2)
+    f = experts_apply(rows_in[order // top_k], sizes, p["w1"], p["w2"], act)
     wf = jnp.where(held, w, 0.0).reshape(n * top_k)[order]
     f = jnp.where((eid[order] < eh)[:, None], f * wf[:, None], 0.0)
     # back to token order: a gather by the inverse permutation, then
@@ -322,20 +319,66 @@ def latent_moe(x, p, top_k, scale, share=0, n_valid=None, axis_name=None):
         hit = jax.lax.psum(hit, axis_name)
         n_held = jax.lax.psum(n_held, axis_name)
         busiest = jax.lax.pmax(busiest, axis_name)
-    shared = jnp.matmul(
-        _relu2(jnp.matmul(x2, p["shared_w1"],
-                          preferred_element_type=jnp.float32)
-               ).astype(x.dtype),
-        p["shared_w2"], preferred_element_type=jnp.float32)
-    out = jnp.matmul(routed.astype(x.dtype), p["up"],
-                     preferred_element_type=jnp.float32) + shared
     probe = jnp.stack([jnp.sum(valid).astype(jnp.int32) * top_k,
                        n_held, hit, busiest])
+    return routed, probe
+
+
+def _shared_expert(x2, p, act):
+    """The expert every share computes alike, float32 out."""
+    h = act(jnp.matmul(x2, p["shared_w1"],
+                       preferred_element_type=jnp.float32)).astype(x2.dtype)
+    return jnp.matmul(h, p["shared_w2"], preferred_element_type=jnp.float32)
+
+
+def _valid_tokens(b, t, n_valid):
+    if n_valid is None:
+        return jnp.ones((b * t,), bool)
+    return (jnp.arange(t, dtype=jnp.int32)[None, :]
+            < n_valid.astype(jnp.int32)[:, None]).reshape(b * t)
+
+
+def latent_moe(x, p, top_k, scale, share=0, n_valid=None, axis_name=None):
+    """x [B, T, d]; p: router_w [d, E], router_bias [E], down [d, L],
+    w1 [E_held, L, f], w2 [E_held, f, L], up [L, d], shared_w1 [d, s],
+    shared_w2 [s, d]. This shard holds experts [share E_held,
+    (share + 1) E_held) of the router's E. `n_valid` [B] leaves tokens
+    t >= n_valid[b] out (routed nowhere, counted nowhere; their output
+    is the shared expert's and is don't-care). Inside shard_map,
+    `axis_name` sums the shares' routed parts.
+
+    Returns (out [B, T, d], probe [4] int32: selections made, those
+    that fell on held experts, held experts with at least one token,
+    tokens on the busiest held expert)."""
+    b, t, d = x.shape
+    x2 = x.reshape(b * t, d)
+    lat = jnp.matmul(x2, p["down"],
+                     preferred_element_type=jnp.float32).astype(x.dtype)
+    routed, probe = _routed_share(x2, lat, p, top_k, scale, share,
+                                  _valid_tokens(b, t, n_valid), _relu2,
+                                  axis_name)
+    out = jnp.matmul(routed.astype(x.dtype), p["up"],
+                     preferred_element_type=jnp.float32) \
+        + _shared_expert(x2, p, _relu2)
     return out.astype(x.dtype).reshape(b, t, d), probe
 
 
-def latent_moe_sharded(x, p, mesh, top_k, scale, ep_axis="ep",
-                       n_valid=None):
+def gated_moe(x, p, top_k, scale, share=0, n_valid=None, axis_name=None):
+    """`latent_moe` without the latent projections and with gated
+    experts: p: router_w [d, E], router_bias [E], w1 [E_held, d, 2 f]
+    (gate and up side by side), w2 [E_held, f, d], shared_w1 [d, 2 s],
+    shared_w2 [s, d]; an expert is `(silu(u W_gate) * u W_up) W_down`.
+    Told its share, counted and summed as `latent_moe` is."""
+    b, t, d = x.shape
+    x2 = x.reshape(b * t, d)
+    routed, probe = _routed_share(x2, x2, p, top_k, scale, share,
+                                  _valid_tokens(b, t, n_valid), _swiglu,
+                                  axis_name)
+    out = routed + _shared_expert(x2, p, _swiglu)
+    return out.astype(x.dtype).reshape(b, t, d), probe
+
+
+def _expert_shard_map(layer, x, p, mesh, top_k, scale, ep_axis, n_valid):
     """Global arrays -> shard_map: w1 / w2 sharded on their expert
     dimension over `ep_axis`, everything else replicated; shard i is
     share i, and a psum over the axis sums the shares."""
@@ -343,15 +386,25 @@ def latent_moe_sharded(x, p, mesh, top_k, scale, ep_axis="ep",
     specs["w1"] = specs["w2"] = P(ep_axis, None, None)
 
     def inner(x, p, n_valid):
-        return latent_moe(x, p, top_k, scale,
-                          share=jax.lax.axis_index(ep_axis),
-                          n_valid=n_valid, axis_name=ep_axis)
+        return layer(x, p, top_k, scale, share=jax.lax.axis_index(ep_axis),
+                     n_valid=n_valid, axis_name=ep_axis)
 
     if n_valid is None:
         n_valid = jnp.full((x.shape[0],), x.shape[1], jnp.int32)
     return jax.shard_map(inner, mesh=mesh, in_specs=(P(), specs, P()),
                          out_specs=(P(), P()), check_vma=False)(
         x, p, n_valid)
+
+
+def latent_moe_sharded(x, p, mesh, top_k, scale, ep_axis="ep",
+                       n_valid=None):
+    return _expert_shard_map(latent_moe, x, p, mesh, top_k, scale, ep_axis,
+                             n_valid)
+
+
+def gated_moe_sharded(x, p, mesh, top_k, scale, ep_axis="ep", n_valid=None):
+    return _expert_shard_map(gated_moe, x, p, mesh, top_k, scale, ep_axis,
+                             n_valid)
 
 
 # ---------------------------------------------------------------------------
@@ -389,6 +442,20 @@ def _moe_ffn_op(ctx, ins, attrs):
     return {"Out": [y], "Load": [load]}
 
 
+def _expert_layer_op(layer, sharded, p, ctx, ins, attrs):
+    n_valid = ins["NValid"][0] if ins.get("NValid") else None
+    top_k, scale = int(attrs["top_k"]), float(attrs.get("scale", 1.0))
+    ep_axis = attrs.get("ep_axis", "ep")
+    if ctx.mesh is not None and ep_axis in ctx.mesh.axis_names:
+        out, probe = sharded(ins["X"][0], p, ctx.mesh, top_k, scale,
+                             ep_axis, n_valid)
+    else:
+        out, probe = layer(ins["X"][0], p, top_k, scale,
+                           share=int(attrs.get("share", 0)),
+                           n_valid=n_valid)
+    return {"Out": [out], "Probe": [probe]}
+
+
 def _latent_moe_op(ctx, ins, attrs):
     """Program-IR face of `latent_moe`: X [B, T, d], RouterW,
     RouterBias, Down, W1, W2, Up, SharedW1, SharedW2, optional NValid
@@ -399,17 +466,18 @@ def _latent_moe_op(ctx, ins, attrs):
          "down": ins["Down"][0], "w1": ins["W1"][0], "w2": ins["W2"][0],
          "up": ins["Up"][0], "shared_w1": ins["SharedW1"][0],
          "shared_w2": ins["SharedW2"][0]}
-    n_valid = ins["NValid"][0] if ins.get("NValid") else None
-    top_k, scale = int(attrs["top_k"]), float(attrs.get("scale", 1.0))
-    ep_axis = attrs.get("ep_axis", "ep")
-    if ctx.mesh is not None and ep_axis in ctx.mesh.axis_names:
-        out, probe = latent_moe_sharded(ins["X"][0], p, ctx.mesh, top_k,
-                                        scale, ep_axis, n_valid)
-    else:
-        out, probe = latent_moe(ins["X"][0], p, top_k, scale,
-                                share=int(attrs.get("share", 0)),
-                                n_valid=n_valid)
-    return {"Out": [out], "Probe": [probe]}
+    return _expert_layer_op(latent_moe, latent_moe_sharded, p, ctx, ins,
+                            attrs)
+
+
+def _gated_moe_op(ctx, ins, attrs):
+    """Program-IR face of `gated_moe`: X [B, T, d], RouterW, RouterBias,
+    W1, W2, SharedW1, SharedW2, optional NValid [B]; attrs and outputs
+    as `latent_moe`."""
+    p = {"router_w": ins["RouterW"][0], "router_bias": ins["RouterBias"][0],
+         "w1": ins["W1"][0], "w2": ins["W2"][0],
+         "shared_w1": ins["SharedW1"][0], "shared_w2": ins["SharedW2"][0]}
+    return _expert_layer_op(gated_moe, gated_moe_sharded, p, ctx, ins, attrs)
 
 
 def _register():
@@ -417,6 +485,8 @@ def _register():
     register_op("moe_ffn", nondiff_outputs=("Load",))(_moe_ffn_op)
     register_op("latent_moe", nondiff_inputs=("NValid",),
                 nondiff_outputs=("Probe",))(_latent_moe_op)
+    register_op("gated_moe", nondiff_inputs=("NValid",),
+                nondiff_outputs=("Probe",))(_gated_moe_op)
 
 
 _register()

@@ -131,14 +131,14 @@ def adopt_prefix(engine, payload: dict) -> dict:
             f"shipment block_size {ship.block_size} != engine "
             f"block_size {engine.block_size}")
     names = engine.step.cache_names
-    if 2 * len(ship.layers) != len(names):
+    if len(ship.pools) != len(names):
         raise ValueError(
-            f"shipment has {len(ship.layers)} layers, engine has "
-            f"{len(names) // 2}")
+            f"shipment has {len(ship.pools)} pools, engine has "
+            f"{len(names)}")
     adopted = 0
     dup = 0
     with engine._kv_mutex:
-        if ship.n_blocks and ship.layers:
+        if ship.n_blocks and ship.pools:
             pool0 = np.asarray(engine.scope.get(names[0]))
             if ship.dtype != pool0.dtype or \
                     tuple(ship.shape[1:]) != tuple(pool0.shape[1:]):
@@ -158,9 +158,8 @@ def adopt_prefix(engine, payload: dict) -> dict:
             if pools is None:
                 pools = [np.array(np.asarray(engine.scope.get(n)))
                          for n in names]
-            for li, (karr, varr) in enumerate(ship.layers):
-                pools[2 * li][bid] = karr[j]
-                pools[2 * li + 1][bid] = varr[j]
+            for pool, rows in zip(pools, ship.pools):
+                pool[bid] = rows[j]
             engine._prefix.insert(h, bid)   # cache takes its ref (-> 2)
             engine._pool.decref(bid)        # drop ours (-> 1, cache-held)
             adopted += 1

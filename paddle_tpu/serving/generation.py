@@ -65,6 +65,7 @@ executable is compiled in `start()` alongside the other two, keeping
 """
 from __future__ import annotations
 
+import gc
 import threading
 import time
 from typing import Callable, List, Optional, Sequence
@@ -599,6 +600,12 @@ class GenerationEngine:
         STAT_SET("serving.gen_kv_blocks_total", self._pool.capacity())
         STAT_SET("serving.gen_kv_blocks_free", self._pool.free_count())
         self._warm_misses = self.cache_stats()["misses"]
+        # what lives now (weights' handles, compiled programs, a
+        # caller's queue of requests with their token lists) lives for
+        # as long as the engine serves: a full collection that walks it
+        # again stops the loop for 0.1 s and more and frees nothing
+        gc.collect()
+        gc.freeze()
         self._closed = False
         self._worker = threading.Thread(target=self._worker_loop,
                                         name="ptn-generation-worker",
@@ -621,6 +628,7 @@ class GenerationEngine:
         if self._worker is not None:
             self._worker.join(timeout)
             self._worker = None
+        gc.unfreeze()
 
     @property
     def ready(self) -> bool:
@@ -979,6 +987,9 @@ class GenerationEngine:
             rec.state_bytes = len(live) * self.cfg.state_slot_bytes()
             STAT_SET("serving.gen_state_slots_live", rec.state_slots_live)
             STAT_SET("serving.gen_state_bytes", rec.state_bytes)
+        if rec.kv_pages_read:
+            rec.kv_bytes_read = rec.kv_pages_read * self.kv_block_bytes()
+            STAT_ADD("serving.gen_kv_bytes_read", rec.kv_bytes_read)
         if rec.moe_selected:
             STAT_SET("serving.gen_moe_held_share",
                      rec.moe_selected_held / rec.moe_selected)

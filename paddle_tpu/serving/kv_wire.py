@@ -1,8 +1,9 @@
 """KV wire format: serialize filled block-table rows for cross-process
 transfer.
 
-A *shipment* carries, for one request prefix, the per-layer paged-KV
-pool rows that hold its already-prefilled tokens, plus the content
+A *shipment* carries, for one request prefix, the paged-KV pool rows
+that hold its already-prefilled tokens (every pool the model has, as
+its step lists them: K and V a layer, or one latent pool a layer), plus the content
 chain hashes (`PrefixCache.chunk_hashes`) that name them and the
 start-position metadata a decode worker needs to resume.  Payloads are
 base64 of the raw pool bytes — `np.tobytes`/`np.frombuffer` round-trip
@@ -16,11 +17,11 @@ from __future__ import annotations
 
 import base64
 from dataclasses import dataclass
-from typing import Dict, List, Sequence, Tuple
+from typing import List, Sequence, Tuple
 
 import numpy as np
 
-WIRE_VERSION = 2   # 2: rows are [n_blocks, block_size, lanes]
+WIRE_VERSION = 3   # 3: a flat list of pools, each [n_blocks, block_size, lanes]
 
 
 def _resolve_dtype(name: str) -> np.dtype:
@@ -43,16 +44,17 @@ def _dtype_name(dt: np.dtype) -> str:
 
 @dataclass
 class KVShipment:
-    """Decoded wire payload: per-layer (k, v) row stacks of shape
-    [n_blocks, block_size, lanes] (a pool's rows as the pool holds
-    them: ops/attention.py:paged_attention)."""
+    """Decoded wire payload: a row stack of shape [n_blocks,
+    block_size, lanes] for each pool of the model, in its step's
+    `cache_names` order (a pool's rows as the pool holds them:
+    ops/attention.py:paged_attention)."""
     version: int
     block_size: int
     n_tokens: int
     dtype: np.dtype
     shape: Tuple[int, int, int]
     chain_hashes: List[str]
-    layers: List[Tuple[np.ndarray, np.ndarray]]
+    pools: List[np.ndarray]
 
     @property
     def n_blocks(self) -> int:
@@ -65,7 +67,7 @@ def pack_blocks(scope, cache_names: Sequence[str],
                 block_size: int,
                 state_names: Sequence[str] = ()) -> dict:
     """Serialize pool rows `block_ids` from every paged KV pool in
-    `cache_names` (alternating k, v per layer) into a JSON-safe dict.
+    `cache_names` (the model's list as it is) into a JSON-safe dict.
 
     `chain_hashes[i]` must be the content hash of the tokens stored in
     `block_ids[i]`; the adopting side keys its PrefixCache on them.
@@ -77,14 +79,11 @@ def pack_blocks(scope, cache_names: Sequence[str],
             f"kv_wire ships paged KV blocks only; this model also "
             f"carries per-slot recurrent state ({state_names[0]}, ...) "
             f"that a block does not hold")
-    if len(cache_names) % 2 != 0:
-        raise ValueError(
-            f"cache_names must alternate k/v pools, got {len(cache_names)}")
     if len(block_ids) != len(chain_hashes):
         raise ValueError(
             f"{len(block_ids)} block ids vs {len(chain_hashes)} hashes")
     ids = list(int(b) for b in block_ids)
-    layers = []
+    pools = []
     shape = None
     dtype = None
     for name in cache_names:
@@ -93,7 +92,11 @@ def pack_blocks(scope, cache_names: Sequence[str],
         if shape is None:
             shape = rows.shape
             dtype = rows.dtype
-        layers.append(base64.b64encode(rows.tobytes()).decode("ascii"))
+        elif (rows.shape, rows.dtype) != (shape, dtype):
+            raise ValueError(
+                f"{name}: rows {rows.dtype}{list(rows.shape)} beside "
+                f"{dtype}{list(shape)}: one shipment, one row shape")
+        pools.append(base64.b64encode(rows.tobytes()).decode("ascii"))
     if shape is None:
         shape = (len(ids), block_size, 0)
         dtype = np.dtype("float32")
@@ -106,8 +109,7 @@ def pack_blocks(scope, cache_names: Sequence[str],
         "dtype": _dtype_name(dtype),
         "shape": [int(d) for d in shape],
         "chain_hashes": list(chain_hashes),
-        "layers": [{"k": layers[i], "v": layers[i + 1]}
-                   for i in range(0, len(layers), 2)],
+        "pools": pools,
     }
     return payload
 
@@ -133,17 +135,13 @@ def unpack_blocks(payload: dict) -> KVShipment:
         raise ValueError(
             f"{len(hashes)} chain hashes for {shape[0]} blocks")
     want = int(np.prod(shape)) * dtype.itemsize
-    layers: List[Tuple[np.ndarray, np.ndarray]] = []
-    for layer in payload["layers"]:
-        pair = []
-        for key in ("k", "v"):
-            raw = base64.b64decode(layer[key])
-            if len(raw) != want:
-                raise ValueError(
-                    f"layer {key} buffer is {len(raw)} bytes, "
-                    f"expected {want}")
-            pair.append(np.frombuffer(raw, dtype=dtype).reshape(shape))
-        layers.append((pair[0], pair[1]))
+    pools: List[np.ndarray] = []
+    for i, coded in enumerate(payload["pools"]):
+        raw = base64.b64decode(coded)
+        if len(raw) != want:
+            raise ValueError(
+                f"pool {i} buffer is {len(raw)} bytes, expected {want}")
+        pools.append(np.frombuffer(raw, dtype=dtype).reshape(shape))
     return KVShipment(
         version=WIRE_VERSION,
         block_size=int(payload["block_size"]),
@@ -151,15 +149,15 @@ def unpack_blocks(payload: dict) -> KVShipment:
         dtype=dtype,
         shape=shape,  # type: ignore[arg-type]
         chain_hashes=hashes,
-        layers=layers)
+        pools=pools)
 
 
 def payload_bytes(payload: dict) -> int:
     """Raw KV bytes carried by a packed shipment (excludes base64 and
-    JSON overhead): n_layers * 2 pools * prod(shape) * itemsize."""
+    JSON overhead): pools * prod(shape) * itemsize."""
     shape = [int(d) for d in payload.get("shape", ())]
     if len(shape) != 3:
         return 0
     dtype = _resolve_dtype(str(payload.get("dtype", "float32")))
     per_pool = int(np.prod(shape)) * dtype.itemsize
-    return per_pool * 2 * len(payload.get("layers", ()))
+    return per_pool * len(payload.get("pools", ()))

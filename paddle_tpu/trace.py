@@ -439,6 +439,7 @@ class IterationRecord:
                  "decode_rows", "tokens_emitted", "queue_depth",
                  "active_slots", "kv_blocks_held", "kv_tokens_resident",
                  "kv_blocks_total", "kv_pages_read", "kv_pages_table",
+                 "kv_bytes_read",
                  "state_slots_live", "state_bytes",
                  "prefix_skipped_recurrent", "moe_selected",
                  "moe_selected_held", "moe_experts_hit", "moe_load_max",
@@ -455,8 +456,11 @@ class IterationRecord:
         self.kv_blocks_total = kv_blocks_total
         # over the paged steps the turn ran: the pages that the fed
         # rows' lengths cover (what paged_attention reads), and rows x
-        # table width (what reading every table entry would take)
+        # table width (what reading every table entry would take);
+        # the pages read times a page's bytes, all layers' pools (set
+        # once, at the turn's end)
         self.kv_pages_read = self.kv_pages_table = 0
+        self.kv_bytes_read = 0
         # a model with recurrent layers: the slots whose state is live
         # at the turn's end and the bytes it takes (not paged: a row a
         # slot), and the admissions of the turn for which the prefix

@@ -1145,6 +1145,56 @@ skip("read", "host reader infeed; covered in "
 skip("create_custom_reader", "host reader binding; covered in "
                              "tests/test_straggler_ops.py")
 
+# -- the latent-attention decoder's ops (ops/latent_attention.py) ------------
+
+def _gated_ffn_ref(i, a):
+    h = i["X"] @ i["W1"]
+    f = h.shape[-1] // 2
+    return (h[..., :f] / (1.0 + np.exp(-h[..., :f])) * h[..., f:]) @ i["W2"]
+
+
+def _yarn_rotary_ref(i, a):
+    """Pairs (x[2k], x[2k+1]) turned by (start + t) * inv_freq_k, the
+    YaRN frequencies written out again."""
+    x, dim = i["X"], i["X"].shape[-1]
+    k = np.arange(dim // 2, dtype=np.float64)
+    f = a["theta"] ** (-2.0 * k / dim)
+
+    def pair(turns):
+        return dim * np.log(a["original"] / (2 * np.pi * turns)) \
+            / (2 * np.log(a["theta"]))
+    low = max(np.floor(pair(a["beta_fast"])), 0)
+    high = min(np.ceil(pair(a["beta_slow"])), dim // 2 - 1)
+    keep = 1 - np.clip((k - low) / (high - low), 0, 1)
+    inv = f / a["factor"] * (1 - keep) + f * keep
+    pos = i["StartPos"][:, None] + np.arange(x.shape[1])[None, :]
+    ang = (pos[..., None] * inv)[:, :, None, :]
+    out = np.empty_like(x)
+    out[..., 0::2] = x[..., 0::2] * np.cos(ang) - x[..., 1::2] * np.sin(ang)
+    out[..., 1::2] = x[..., 1::2] * np.cos(ang) + x[..., 0::2] * np.sin(ang)
+    return out
+
+
+spec("gated_ffn", ins={"X": f32(2, 3, 8), "W1": f32(8, 12), "W2": f32(6, 8)},
+     grad=["X", "W1", "W2"],
+     expect=lambda i, a: {"Out": [_gated_ffn_ref(i, a)]})
+spec("yarn_rotary", ins={"X": f32(2, 3, 2, 16),
+                         "StartPos": ints(2, lo=0, hi=40)},
+     attrs={"theta": 50.0, "factor": 4.0, "original": 16, "beta_fast": 2.0,
+            "beta_slow": 1.0, "mscale": 1.0, "mscale_all_dim": 1.0},
+     grad=["X"], expect=lambda i, a: {"Out": [_yarn_rotary_ref(i, a)]})
+skip("mla_project", "latent attention's projections into the absorbed "
+     "form (queries in the latent space, the token's cache row); with "
+     "`paged_attention` over a latent pool and `mla_output` against the "
+     "reference's per-head attention in tests/test_latent_model.py")
+skip("mla_output", "the value half of W_kvb and W_o behind the latent "
+     "pool's attention; covered with `mla_project` in "
+     "tests/test_latent_model.py")
+skip("gated_moe", "top-k routed gated experts with a held share and an "
+     "int32 probe; against the plain reference layer, the shares adding "
+     "up (also over an `ep` mesh axis) and a one-expert router in "
+     "tests/test_latent_model.py")
+
 # ===========================================================================
 # independent numpy references + extra grad slots (op_expects.py) —
 # merged last so every entry targets an existing spec

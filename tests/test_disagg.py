@@ -99,16 +99,19 @@ def test_kv_wire_roundtrip_fp32_byte_exact():
     assert payload["kind"] == "kv_shipment"
     assert payload["n_blocks"] == 2 and payload["n_tokens"] == 2 * BLOCK
     assert payload["shape"] == [2, BLOCK, 6]
-    # raw-bytes accounting: 2 pools/layer x 2 layers x rows x fp32
-    assert payload_bytes(payload) == 2 * 2 * (2 * BLOCK * 6) * 4
+    # raw-bytes accounting: 4 pools x rows x fp32
+    assert payload_bytes(payload) == 4 * (2 * BLOCK * 6) * 4
 
     ship = unpack_blocks(payload)
     assert ship.chain_hashes == hashes
-    assert ship.dtype == np.float32 and len(ship.layers) == 2
-    for li, (kn, vn) in enumerate((("k0", "v0"), ("k1", "v1"))):
-        k, v = ship.layers[li]
-        assert k.tobytes() == pools[kn][ids].tobytes()
-        assert v.tobytes() == pools[vn][ids].tobytes()
+    assert ship.dtype == np.float32 and len(ship.pools) == 4
+    for rows, name in zip(ship.pools, names):
+        assert rows.tobytes() == pools[name][ids].tobytes()
+    # the model's pool list as it is: an odd count (one latent pool a
+    # layer) ships as any other
+    odd = unpack_blocks(pack_blocks(scope, names[:3], ids, hashes, BLOCK))
+    assert [r.tobytes() for r in odd.pools] == \
+        [pools[n][ids].tobytes() for n in names[:3]]
 
 
 def test_kv_wire_roundtrip_bf16_byte_exact():
@@ -119,14 +122,15 @@ def test_kv_wire_roundtrip_bf16_byte_exact():
     assert payload["dtype"] == "bfloat16"
     ship = unpack_blocks(payload)
     assert ship.dtype == np.dtype(ml_dtypes.bfloat16)
-    assert ship.layers[0][0].tobytes() == \
-        pools["k0"][[1, 3, 5]].tobytes()
+    assert ship.pools[0].tobytes() == pools["k0"][[1, 3, 5]].tobytes()
 
 
 def test_kv_wire_rejects_malformed():
     scope, names, _ = _fake_pools(np.float32)
-    with pytest.raises(ValueError):
-        pack_blocks(scope, names[:3], [1], ["a"], BLOCK)  # odd pools
+    with pytest.raises(ValueError):   # pools of two row shapes
+        pack_blocks(_FakeScope({"a": np.zeros((4, BLOCK, 6), np.float32),
+                                "b": np.zeros((4, BLOCK, 8), np.float32)}),
+                    ["a", "b"], [1], ["a"], BLOCK)
     with pytest.raises(ValueError):
         pack_blocks(scope, names, [1, 2], ["a"], BLOCK)  # id/hash skew
     good = pack_blocks(scope, names, [1], ["a"], BLOCK)
@@ -136,10 +140,7 @@ def test_kv_wire_rejects_malformed():
         unpack_blocks({**good, "version": 99})
     with pytest.raises(ValueError):
         unpack_blocks({**good, "chain_hashes": ["a", "b"]})
-    bad = {**good,
-           "layers": [{"k": good["layers"][0]["k"][:8],
-                       "v": good["layers"][0]["v"]},
-                      good["layers"][1]]}
+    bad = {**good, "pools": [good["pools"][0][:8]] + good["pools"][1:]}
     with pytest.raises(ValueError):
         unpack_blocks(bad)
 
@@ -188,13 +189,9 @@ def test_export_adopt_cross_engine_parity(trained):
         for j, h in enumerate(ship.chain_hashes):
             bid = eng_b._prefix._entries[h]
             assert eng_b._pool.refcount(bid) == 1
-            for li in range(len(ship.layers)):
-                pool_k = np.asarray(eng_b.scope.get(names[2 * li]))
-                pool_v = np.asarray(eng_b.scope.get(names[2 * li + 1]))
-                assert pool_k[bid].tobytes() == \
-                    ship.layers[li][0][j].tobytes()
-                assert pool_v[bid].tobytes() == \
-                    ship.layers[li][1][j].tobytes()
+            for name, rows in zip(names, ship.pools):
+                pool = np.asarray(eng_b.scope.get(name))
+                assert pool[bid].tobytes() == rows[j].tobytes()
 
         # re-adoption is a pure dup (move-to-end, no new blocks)
         res2 = adopt_prefix(eng_b, payload)

@@ -268,3 +268,36 @@ def test_grouped_kernel_compiles_for_v5e(one_chip, monkeypatch, t):
     copies = [ln for ln in text.splitlines()
               if f"= bf16[{nb},{bs},{lanes}]" in ln and " copy(" in ln]
     assert not copies, copies
+
+
+@pytest.mark.parametrize("t", [1, 16, 5],
+                         ids=["decode", "chunk_prefill", "spec_verify_k4"])
+def test_latent_kernel_compiles_for_v5e(one_chip, monkeypatch, t):
+    """The latent kernel at `kimi_k2_5_ep32_l5`'s sizes: 64 query heads
+    over ONE pool of 576-number rows in 640 lanes, 64 slots of 9,216
+    positions, bfloat16; the pool reaches the kernel with no copy and
+    is its only pool operand."""
+    monkeypatch.setattr(kernel, "_interpret", lambda: False)
+    h, row, rank, slots, mb, bs = 64, 576, 512, 64, 576, 16
+    lanes, nb = kernel.pool_lanes(row), slots * mb + 1
+    assert lanes == 640
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    read = jax.jit(lambda q, pool, *a: kernel.paged_attention_read.__wrapped__(
+        q, pool, None, *a, sm_scale=0.1447, value_lanes=rank))
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        text = read.lower(
+            arg((slots, h, t, row), jnp.bfloat16),
+            arg((nb, bs, lanes), jnp.bfloat16),
+            arg((slots, mb), jnp.int32), arg((slots,), jnp.int32),
+            arg((slots,), jnp.int32)).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", prev)
+    assert "tpu_custom_call" in text
+    copies = [ln for ln in text.splitlines()
+              if f"= bf16[{nb},{bs},{lanes}]" in ln and " copy(" in ln]
+    assert not copies, copies
