@@ -380,8 +380,12 @@ def build_paged_decode_step(cfg, batch, max_seq, block_size, num_blocks,
     (analysis/memory.py pins persistables at full size).
 
     `seq_tokens` tokens are consumed per row per step: 1 builds the
-    decode executable, `block_size` builds the chunked-prefill
-    executable that retires a whole block of prompt per step. Both use
+    decode executable, `block_size` the chunked-prefill executable,
+    whose row is a TILE: one page of some request's prompt, with that
+    request's table row, start and valid count. Nothing here ties row
+    b to slot b (positions come from `start_pos[b] + t`), so the engine
+    feeds successive pages of ONE request as rows of one step
+    (serving/generation.py:_prefill_plan). Both use
     the same pool var names, so one scope carries one physical pool.
     `with_logits=False` (the prefill program) skips the lm head and
     returns a cheap [batch] health probe as `logits_var` instead —

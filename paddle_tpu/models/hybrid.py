@@ -18,8 +18,10 @@ with the feeds of `gpt.PagedDecodeStep`. Beside the paged KV pools of
 its attention layers the step names a second kind of per-slot state,
 `state_names`: each Mamba-2 layer's convolution window and SSM state,
 `[max_slots, ...]` persistables that are not paged (row b of the batch
-is slot b), and `probe_var`, four int32 a latent-expert layer that the
-decode step fetches beside its logits.
+is slot b: why the engine packs several pages of one request into a
+prefill step only for a model that names no such state), and
+`probe_var`, four int32 a latent-expert layer that the decode step
+fetches beside its logits.
 """
 from __future__ import annotations
 

@@ -97,6 +97,13 @@ def _paged_attention_op(ctx, ins, attrs):
     nothing and returns zeros; rows t >= NValid are don't-care but
     finite.
 
+    Rows are independent of their index, and rows of ONE call may be
+    successive pages of one sequence (the same BlockTable row, StartPos
+    a page apart: the engine's prefill tiles). That is right because
+    every row's new K/V are written into the pool BEFORE any row reads:
+    the later page reads from the pool what the earlier page wrote in
+    this call. Keep the write ahead of the read.
+
     A LATENT pool (no V, no CacheV; attr `value_lanes`): a token holds
     ONE row for all H heads, K [B, T, w] written into CacheK
     [nb, bs, pool_lanes(w)]; Q is [B, H, T, w], a token's values are

@@ -43,13 +43,17 @@ moves fall out of the indirection: admission gates on free BLOCKS
 releasing its blocks back to the pool (no in-graph wipe — the table
 simply never maps the old blocks again); and shared prompt prefixes
 hit a content-hash `PrefixCache` so identical system prompts reuse the
-same physical blocks and skip re-prefill. Long prompts retire through
-a second fixed-shape executable that prefills a whole block per step
-(chunked prefill), so a 10k-token prompt costs ~10k/block_size
-iterations interleaved with — never stalling — the decode batch. The
-compile contract is exactly two executables (decode + chunk prefill),
-both compiled in `start()`: `post_warmup_compiles()` stays 0 for the
-engine's lifetime.
+same physical blocks and skip re-prefill. Prompts retire through a
+second fixed-shape executable, `[max_slots, block_size]` (chunked
+prefill), whose rows are TILES: a row is one page of some request's
+prompt, and a step spends its rows on the oldest prompts first, many
+pages of ONE request side by side (`_prefill_plan`). The executable is
+a budget of max_slots x block_size prompt tokens a step, so a 10k-token
+prompt costs ~10k / (max_slots x block_size) prefill steps, each
+followed by the decode batch's step. A model with per-slot recurrent
+state keeps a page a request a step. The compile contract is exactly
+two executables (decode + chunk prefill), both compiled in `start()`:
+`post_warmup_compiles()` stays 0 for the engine's lifetime.
 
 Speculative decoding (FLAGS_gen_spec_decode / GenerationRequest
 .spec_decode): a host-side n-gram drafter (`serving/spec_decode.py`)
@@ -93,6 +97,20 @@ __all__ = ["GenerationRequest", "SlotManager", "GenerationEngine"]
 # fraction bucket ladders don't fit; upper rungs leave headroom for
 # larger FLAGS_spec_decode_k settings.
 SPEC_TOKEN_BUCKETS = (1.0, 2.0, 3.0, 4.0, 5.0, 6.0, 8.0, 12.0, 16.0)
+
+# The share of the prefill executable's rows that a step may fill while
+# some row is decoding and no request waits in the queue. Every decoding
+# row waits for the prefill step of its turn, and that step grows by
+# the real tiles in it (0.35 ms a tile for gpt2_medium), so the share
+# bounds the gap between a request's tokens. Of 3/4, 1/2 and 1/4 the
+# largest that kept gpt2_medium.long_in_open_v2's gap_p95_ms within 3%
+# of a page a slot a turn: unbounded +20%, 1/2 +7%, 1/4 -0.1%, TTFT
+# p90 870 -> 63, 100 and 380 ms (PERF.md section 6, PR 34). With
+# nothing decoding nobody waits on a gap; with requests queued for a
+# slot the sooner a row reaches decode the sooner a slot comes free,
+# and tokens a second is what is short (a share of 1/2 there took
+# kimi_k2_5_ep32_l5.batch_long_ctx's gain away): the whole shape then.
+PREFILL_ROWS_WHILE_DECODING = 0.25
 
 
 def _pick_on_device(step, name):
@@ -410,8 +428,9 @@ class GenerationEngine:
         with fluid.program_guard(self._prog, self._startup):
             self.step = cfg.build_paged_step(seq_tokens=1, **dims)
             _pick_on_device(self.step, "decode")
-        # the second (and last) executable of the lifetime: retires
-        # one whole block of prompt per row per step
+        # the second (and last) executable of the lifetime: a row
+        # retires one page of SOME request's prompt (`_prefill_plan`
+        # says whose), so a step has room for max_slots pages
         self._prefill_prog = fluid.Program()
         self._prefill_startup = fluid.Program()
         with fluid.program_guard(self._prefill_prog,
@@ -995,6 +1014,8 @@ class GenerationEngine:
                      rec.moe_selected_held / rec.moe_selected)
             STAT_SET("serving.gen_moe_experts_hit", rec.moe_experts_hit)
             STAT_SET("serving.gen_moe_load_max", rec.moe_load_max)
+        if rec.prefill_tiles:
+            STAT_ADD("serving.gen_prefill_tiles", rec.prefill_tiles)
         if rec.logit_rows_fetched:
             STAT_ADD("serving.gen_logit_rows_fetched",
                      rec.logit_rows_fetched)
@@ -1102,17 +1123,58 @@ class GenerationEngine:
                 st.phase_span.add_event(
                     "stream_flush", token_index=len(st.generated))
 
+    def _prefill_plan(self, prefill_idx, queue_depth):
+        """How one prefill step spends the executable's rows, as
+        `(slot, first row, rows)` for every request it advances. A row
+        is a TILE: up to one page of one request's prompt, fed with
+        that request's block-table row, its own `start_pos` and
+        `n_valid`. Nothing in the programs ties row i to slot i, and
+        `paged_attention` writes every row's keys before any row reads,
+        so successive pages of ONE request may ride in one step: the
+        later tile reads from the pool what the earlier tile wrote in
+        that step (ops/attention.py). The rows go to the oldest request
+        first, as many as it has pages left, then to the next, until
+        the step's rows are spent: a long prompt is a few steps, not a
+        step a page, and the rest of the rows stay muted. The step has
+        all `max_slots` rows to spend, or `PREFILL_ROWS_WHILE_DECODING`
+        of them while a slot decodes and no request waits in the queue.
+
+        A model with per-slot recurrent state (`self.recurrent`: the
+        step the engine built names `state_names`) keeps row i = slot
+        i and one tile a request a step: its mixers carry row b's state
+        in row b of the state variable, and a request's tiles would
+        have to be scanned in order, not side by side."""
+        if self.recurrent:
+            return [(i, i, 1) for i in prefill_idx]
+        rows = self.max_slots
+        if queue_depth == 0 \
+                and len(prefill_idx) < self._slots.active_count():
+            rows = max(1, int(rows * PREFILL_ROWS_WHILE_DECODING))
+        plan, row = [], 0
+        for i in sorted(prefill_idx,
+                        key=lambda i: self._state[i].t_submit):
+            st = self._state[i]
+            k = min(blocks_for_tokens(len(st.req.prompt) - 1 - st.fed,
+                                      self.block_size),
+                    rows - row)
+            if k == 0:
+                break
+            plan.append((i, row, k))
+            row += k
+        return plan
+
     # -- one iteration ---------------------------------------------------
     def _paged_iteration(self, rec):
-        """One scheduler iteration: (1) chunked
-        prefill — every slot still consuming its prompt retires up to
-        one BLOCK of tokens through the prefill executable; (2) one
-        decode step for every slot past its prompt. Both run the same
-        two warmed executables every time (fixed shapes; muted rows
-        write to the scratch block), so admission, chunk scheduling,
-        release and prefix reuse never cost a compile. Long prompts
-        therefore interleave with decode at block granularity instead
-        of stalling the batch for O(prompt) steps."""
+        """One scheduler iteration: (1) chunked prefill: the prefill
+        executable's rows go, a page a row, to the requests still
+        consuming their prompts, oldest first and as many pages of one
+        request as it has left (`_prefill_plan`); (2) one decode step
+        for every slot past its prompt. Both run the same two warmed
+        executables every time (fixed shapes; muted rows write to the
+        scratch block), so admission, tile packing, release and prefix
+        reuse never cost a compile, and a long prompt delays the decode
+        batch by one prefill step a turn, of at most the shape's rows
+        (`PREFILL_ROWS_WHILE_DECODING` of them while that matters)."""
         from ..core.flags import FLAGS
         B = self.max_slots
         bs = self.block_size
@@ -1194,16 +1256,25 @@ class GenerationEngine:
                 table = np.zeros((B, mb), np.int64)
                 start = np.zeros(B, np.int64)
                 nvalid = np.zeros(B, np.int64)
+                plan = self._prefill_plan(prefill_idx, rec.queue_depth)
                 chunk_n = {}
-                for i in prefill_idx:
+                for i, r0, k in plan:
+                    # rows r0 .. r0 + k are successive pages of slot i's
+                    # prompt: each has the request's table and its own
+                    # start, and the last may be partial
                     st = self._state[i]
-                    prompt = st.req.prompt
-                    n = min(bs, len(prompt) - 1 - st.fed)
-                    tokens[i, :n] = prompt[st.fed:st.fed + n]
-                    fill_row(table, start, i, st)
-                    nvalid[i] = n
-                    chunk_n[i] = n
-            rec.prefill_rows = len(prefill_idx)
+                    tiles = slice(r0, r0 + k)
+                    chunk = st.req.prompt[
+                        st.fed:min(st.fed + k * bs, len(st.req.prompt) - 1)]
+                    tokens[tiles].reshape(-1)[:len(chunk)] = chunk
+                    table[tiles, :len(st.blocks)] = st.blocks
+                    offs = bs * np.arange(k)
+                    start[tiles] = st.fed + offs
+                    nvalid[tiles] = np.minimum(bs, len(chunk) - offs)
+                    chunk_n[i] = len(chunk)
+                prefill_idx = list(chunk_n)
+            rec.prefill_rows = len(plan)
+            rec.prefill_tiles = sum(k for _, _, k in plan)
             with trace.region("gen.prefill.step"):
                 probe = run_guarded(self._prefill_prog,
                                     self.prefill_step, tokens, table,
@@ -1212,8 +1283,10 @@ class GenerationEngine:
             if probe is None:
                 return
             if FLAGS.serving_nan_guard:
-                bad = [i for i in prefill_idx
-                       if not np.isfinite(probe[i])]
+                # the probe is a number a row: a request fails when any
+                # of its tiles reads non-finite
+                bad = [i for i, r0, k in plan
+                       if not np.isfinite(probe[r0:r0 + k]).all()]
                 if bad:
                     self._breaker.record_failure()
                     STAT_ADD("resilience.gen_step_failures")

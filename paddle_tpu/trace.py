@@ -435,9 +435,10 @@ class IterationRecord:
     the values add up to the stretch that `gen.iteration` covers and
     `host_s["gen.iteration"]` is what lies under no other region."""
 
-    __slots__ = ("t_start", "t_end", "prefill_rows", "prefill_tokens",
-                 "decode_rows", "tokens_emitted", "queue_depth",
-                 "active_slots", "kv_blocks_held", "kv_tokens_resident",
+    __slots__ = ("t_start", "t_end", "prefill_rows", "prefill_tiles",
+                 "prefill_tokens", "decode_rows", "tokens_emitted",
+                 "queue_depth", "active_slots", "kv_blocks_held",
+                 "kv_tokens_resident",
                  "kv_blocks_total", "kv_pages_read", "kv_pages_table",
                  "kv_bytes_read",
                  "state_slots_live", "state_bytes",
@@ -449,7 +450,10 @@ class IterationRecord:
     def __init__(self, slots, block_size, kv_blocks_total):
         self.t_start = time.perf_counter()
         self.t_end = None
-        self.prefill_rows = self.prefill_tokens = 0
+        # the prefill step of the turn: requests it advanced, the rows
+        # it fed them (a tile is one row: up to a page of ONE request;
+        # a request may take several), the prompt tokens in those rows
+        self.prefill_rows = self.prefill_tiles = self.prefill_tokens = 0
         self.decode_rows = self.tokens_emitted = 0
         self.queue_depth = self.active_slots = 0
         self.kv_blocks_held = self.kv_tokens_resident = 0

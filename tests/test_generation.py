@@ -380,6 +380,192 @@ def test_paged_pool_decouples_planner_kv_from_slots(trained):
 
 
 # ---------------------------------------------------------------------------
+# Prefill tiles: many pages of ONE request in one step (PR 34)
+# ---------------------------------------------------------------------------
+
+LONG_SEQ, LONG_BS, LONG_VOCAB = 48, 4, 32
+
+
+@pytest.fixture(scope="module")
+def long_model():
+    """A GPT of random weights with room for prompts of several pages;
+    (cfg, scope, serial decoder). The token embedding is N(0, 1), so
+    that a row's logits follow its tokens (at the start-up program's
+    0.02 under positions of amplitude 1 they barely do, and a pool
+    holding the wrong keys would pass)."""
+    cfg = gpt.gpt_small(vocab_size=LONG_VOCAB, d_model=32, n_heads=4,
+                        n_layers=2, d_ff=64, max_seq_len=LONG_SEQ,
+                        dropout=0.0, use_flash=False)
+    scope = fluid.Scope()
+    GenerationEngine(cfg, scope, exe=fluid.Executor(), max_slots=1,
+                     max_seq=LONG_SEQ, block_size=LONG_BS,
+                     state_prefix="init.").init_scope()
+    emb = np.random.RandomState(0).normal(
+        size=scope.find_var("word_emb").shape).astype(np.float32)
+    scope.set("word_emb", emb)
+    dec_main, dec_start = fluid.Program(), fluid.Program()
+    with fluid.program_guard(dec_main, dec_start):
+        step = gpt.build_decode_step(cfg, batch=1, max_seq=LONG_SEQ)
+
+    def serial(prompt, n):
+        return gpt.kv_generate(fluid.Executor(), scope, dec_main,
+                               step.token_var, step.logits_var,
+                               step.cache_names, prompt=prompt,
+                               max_new_tokens=n)
+    return cfg, scope, serial
+
+
+def _long_engine(model, slots, prefix, **kw):
+    cfg, scope, _ = model
+    return GenerationEngine(cfg, scope, exe=fluid.Executor(),
+                            max_slots=slots, max_seq=LONG_SEQ,
+                            block_size=LONG_BS, state_prefix=prefix, **kw)
+
+
+def _tap_prefill(eng):
+    """Every prefill call's live rows as (first block of the row's
+    table, start, n_valid), a list a call."""
+    calls, inner = [], eng._run_paged
+
+    def tap(prog, step, tokens, table, start, nvalid):
+        if prog is eng._prefill_prog:
+            calls.append([(int(table[r, 0]), int(start[r]), int(nvalid[r]))
+                          for r in np.flatnonzero(nvalid)])
+        return inner(prog, step, tokens, table, start, nvalid)
+
+    eng._run_paged = tap
+    return calls
+
+
+# five pages of prompt to prefill, the last partial: 19 = 4 x 4 + 3
+FIVE_PAGES = [(7 * i + 3) % LONG_VOCAB for i in range(20)]
+
+
+@pytest.mark.parametrize("slots,steps", [(8, 1), (4, 2), (1, 5)])
+def test_a_long_prompt_prefills_in_the_steps_its_tiles_need(long_model,
+                                                            slots, steps):
+    """A prompt of five pages is ONE prefill step of an engine with
+    eight rows, two of one with four, and five of one with a single
+    row: the last is the schedule of before PR 34, a page a step. All
+    three serve the serial decoder's tokens, and the tiles of a step
+    are the request's successive pages, the last one partial."""
+    _, _, serial = long_model
+    want = serial(FIVE_PAGES, 6)
+    eng = _long_engine(long_model, slots, f"tiles{slots}.")
+    eng.start()
+    calls = _tap_prefill(eng)
+    try:
+        resp = eng.submit(GenerationRequest(FIVE_PAGES, 6))
+        assert resp.result(timeout=60.0)["tokens"] == want
+        assert resp.timings["prefill_steps"] == steps == len(calls)
+        assert eng.post_warmup_compiles() == 0, eng.cache_stats()
+    finally:
+        eng.stop()
+    fed = [(s, n) for call in calls for _, s, n in call]
+    assert fed == [(0, 4), (4, 4), (8, 4), (12, 4), (16, 3)]
+    assert all(len(call) <= slots for call in calls)
+    assert len({b for call in calls for b, _, _ in call}) == 1
+
+
+def test_prefill_rows_go_to_the_oldest_request_first(long_model):
+    """Two long prompts and a short one, admitted together: in every
+    prefill step a request gets rows only when each older one that is
+    still in prefill has all the rows it can use, and all three come
+    out as the serial decoder's."""
+    _, _, serial = long_model
+    older = FIVE_PAGES
+    younger = [(5 * i + 1) % LONG_VOCAB for i in range(18)]
+    short = [9, 8, 7]
+    jobs = [(older, 4), (younger, 4), (short, 5)]
+    want = [serial(p, n) for p, n in jobs]
+    eng = _long_engine(long_model, 4, "fcfs.")
+    calls = _tap_prefill(eng)
+    # queued before the worker starts: one admission, in this order
+    resps = [eng.submit(GenerationRequest(p, n, timeout_ms=6e5))
+             for p, n in jobs]
+    eng.start()
+    try:
+        got = [r.result(timeout=60.0)["tokens"] for r in resps]
+    finally:
+        eng.stop()
+    assert got == want
+    # who a row belongs to: the first block of its table, in the order
+    # the requests first appear (the oldest is fed first)
+    order = []
+    for call in calls:
+        for b, _, _ in call:
+            if b not in order:
+                order.append(b)
+    assert len(order) == 3
+    left = dict(zip(order, (-(-(len(p) - 1) // LONG_BS) for p, _ in jobs)))
+    for call in calls:
+        rows = [order.index(b) for b, _, _ in call]
+        assert rows == sorted(rows)              # oldest first in a step
+        took = {b: sum(1 for x, _, _ in call if x == b) for b in order}
+        for i, b in enumerate(order):
+            if took[b] < left[b]:                # b is not done: nobody
+                assert all(took[y] == 0          # younger got a row
+                           for y in order[i + 1:])
+            left[b] -= took[b]
+    assert not any(left.values())
+    assert len(calls[0]) == 4 and {b for b, _, _ in calls[0]} == {order[0]}
+    assert [r.timings["prefill_steps"] for r in resps][0] == 2
+
+
+def test_tiles_start_where_a_prefix_cache_hit_ends(long_model):
+    """A hit in the prefix cache sets `fed` to a page boundary in mid
+    prompt: the one prefill step feeds the pages after it."""
+    _, _, serial = long_model
+    first = FIVE_PAGES[:9]                       # two full pages cached
+    second = FIVE_PAGES[:8] + [(3 * i + 2) % LONG_VOCAB for i in range(11)]
+    want = serial(second, 5)
+    eng = _long_engine(long_model, 8, "hit.")
+    eng.start()
+    calls = _tap_prefill(eng)
+    try:
+        eng.generate(first, 2)
+        del calls[:]
+        out = eng.submit(GenerationRequest(second, 5))
+        assert out.result(timeout=60.0)["tokens"] == want
+        assert out.timings["cached_tokens"] == 8
+        assert out.timings["prefill_steps"] == 1
+    finally:
+        eng.stop()
+    (call,) = calls
+    assert [(s, n) for _, s, n in call] == [(8, 4), (12, 4), (16, 2)]
+
+
+def test_a_non_finite_tile_fails_its_request_and_no_other(long_model):
+    """The prefill step's probe is a number a row: a request whose
+    THIRD tile reads non-finite fails, and the request whose tiles ride
+    in the same step is served as the serial decoder serves it."""
+    _, scope, serial = long_model
+    good = [(5 * i + 1) % 31 for i in range(11)]         # never token 31
+    bad = good[:9] + [31, 2]                             # 31 on page three
+    want = serial(good, 4)
+    emb = np.array(scope.find_var("word_emb"))
+    eng = _long_engine(long_model, 8, "nan_tile.")
+    calls = _tap_prefill(eng)
+    try:
+        poked = emb.copy()
+        poked[31] = np.nan
+        scope.set("word_emb", poked)
+        r_bad = eng.submit(GenerationRequest(bad, 3, timeout_ms=6e5))
+        r_good = eng.submit(GenerationRequest(good, 4, timeout_ms=6e5))
+        eng.start()
+        try:
+            with pytest.raises(RuntimeError,
+                               match="non-finite activations in chunked"):
+                r_bad.result(timeout=60.0)
+            assert r_good.result(timeout=60.0)["tokens"] == want
+        finally:
+            eng.stop()
+    finally:
+        scope.set("word_emb", emb)
+    assert len(calls) == 1 and len(calls[0]) == 6        # one step, both
+
+
+# ---------------------------------------------------------------------------
 # HTTP front end: /v1/generate
 # ---------------------------------------------------------------------------
 
@@ -439,7 +625,8 @@ def test_paged_engine_leaves_one_record_an_iteration(trained):
         assert r["decode_rows"] <= r["slots"] == 2
         assert r["prefill_rows"] <= r["active_slots"] <= r["slots"]
         assert r["block_size"] == 4 and r["kv_blocks_total"] == total
-        assert r["prefill_tokens"] <= r["prefill_rows"] * r["block_size"]
+        assert r["prefill_rows"] <= r["prefill_tiles"] <= r["slots"]
+        assert r["prefill_tokens"] <= r["prefill_tiles"] * r["block_size"]
         assert (r["prefill_tokens"] > 0) == (r["prefill_rows"] > 0)
         assert r["kv_tokens_resident"] <= \
             r["kv_blocks_held"] * r["block_size"]

@@ -311,8 +311,10 @@ def engine_logits(dtype, max_slots=3):
             prompt, n_out, timeout_ms=600000,
             logits_cb=lambda r, rows=rows: rows.append(np.array(r))))
         jobs.append((prompt, rows, resp))
-    done = [(prompt, rows, resp.result(timeout=300))
+    done = [(prompt, rows, dict(resp.result(timeout=300),
+                                prefill_steps=resp.timings["prefill_steps"]))
             for prompt, rows, resp in jobs]
+    assert cell.engine.recurrent
     cell.stop()     # the last turn's record is there once it has ended
     records = [r for r in fluid.trace.iteration_records()
                if r["t_start"] >= t0]
@@ -346,6 +348,27 @@ def test_engine_prefill_and_decode_match_the_full_forward(dtype, limit):
     # the host for each token, and no other
     assert sum(r["logit_rows_fetched"] for r in records) == \
         sum(len(result["tokens"]) for _, _, result in done)
+
+
+def test_a_recurrent_model_keeps_a_page_a_request_a_step():
+    """The mixers carry row b's state in row b, so the engine packs no
+    second tile of a request into a prefill step: a prompt rides one
+    step a page, every step feeds each request one row, and the logits
+    stay the full forward's (the prompts of 40 and 33 tokens are three
+    pages each on an engine with three rows, which a model without
+    such state prefills in one step)."""
+    cfg, done, records = engine_logits("float32")
+    p = ref.params(cfg, SEED)
+    for prompt, rows, result in done:
+        assert result["prefill_steps"] == -(-(len(prompt) - 1) // 16)
+        seq = prompt + result["tokens"]
+        want = ref.logits(cfg, p, seq)[len(prompt) - 1:len(seq) - 1]
+        gap = np.abs(np.stack(rows) - want) / want.std(axis=-1,
+                                                       keepdims=True)
+        assert gap.max() < 1e-4, (len(prompt), gap.max())
+    fed = [r for r in records if r["prefill_rows"]]
+    assert fed and all(r["prefill_tiles"] == r["prefill_rows"] <= 3
+                       and r["block_size"] == 16 for r in fed)
 
 
 # -- (e) what the engine does not do for a recurrent model --------------------

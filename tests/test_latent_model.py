@@ -266,9 +266,10 @@ def test_no_token_is_dropped_when_every_token_takes_one_expert():
 JOBS = [(1, 5), (17, 6), (40, 4), (16, 5), (33, 7), (5, 3)]
 
 
-def engine_logits(dtype, max_slots=3):
+def engine_logits(dtype, max_slots=3, jobs=None):
     """Six requests of uneven prompts over three slots (so slots are
-    reused), greedy: for each the logits rows it was sampled from."""
+    reused), greedy: for each the logits rows it was sampled from (and
+    in its result the prefill steps it rode)."""
     cfg, sz = small()
     cfg = dict(cfg, engine=dict(cfg["engine"], dtype=dtype,
                                 max_slots=max_slots))
@@ -276,16 +277,17 @@ def engine_logits(dtype, max_slots=3):
     cell.warm()
     t0 = time.perf_counter()
     rng = np.random.default_rng(6)
-    jobs = []
-    for n_prompt, n_out in JOBS:
+    sent = []
+    for n_prompt, n_out in jobs or JOBS:
         prompt = rng.integers(0, sz["vocab_size"], n_prompt).tolist()
         rows = []
         resp = cell.engine.submit(GenerationRequest(
             prompt, n_out, timeout_ms=600000,
             logits_cb=lambda r, rows=rows: rows.append(np.array(r))))
-        jobs.append((prompt, rows, resp))
-    done = [(prompt, rows, resp.result(timeout=300))
-            for prompt, rows, resp in jobs]
+        sent.append((prompt, rows, resp))
+    done = [(prompt, rows, dict(resp.result(timeout=300),
+                                prefill_steps=resp.timings["prefill_steps"]))
+            for prompt, rows, resp in sent]
     cell.stop()
     records = [r for r in fluid.trace.iteration_records()
                if r["t_start"] >= t0]
@@ -329,6 +331,26 @@ def test_engine_float32_prefill_and_decode_match_the_full_forward():
     kv = analyze_program_memory(eng._prog).kv_summary()
     assert kv["layout"] == "paged" and kv["kv_vars"] == 3
     assert kv["kv_bytes"] == eng.num_blocks * eng.kv_block_bytes()
+
+
+@pytest.mark.parametrize("slots,steps", [(8, 1), (4, 2)])
+def test_a_prompt_of_five_pages_prefills_in_one_step_of_eight_rows(slots,
+                                                                   steps):
+    """Five pages of prompt (75 tokens to prefill: four pages and
+    eleven tokens) are ONE prefill step of an engine with eight rows
+    and two of one with four: the later tiles read, from the one latent
+    pool a layer, the rows the earlier tiles wrote in that step, and
+    rotate by their own start. Logits against the reference's full
+    forward, as for a page a step."""
+    cfg, cell, done, records = engine_logits("float32", max_slots=slots,
+                                             jobs=[(76, 5)])
+    assert gaps(cfg, done)[0] < 1e-4
+    assert done[0][2]["prefill_steps"] == steps
+    fed = [(r["prefill_rows"], r["prefill_tiles"], r["prefill_tokens"])
+           for r in records if r["prefill_rows"]]
+    assert fed == ([(1, 5, 75)] if slots == 8
+                   else [(1, 4, 64), (1, 1, 11)])
+    assert not cell.engine.recurrent
 
 
 def bf16_accumulating(x, w):

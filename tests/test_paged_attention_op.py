@@ -171,6 +171,70 @@ def test_long_row_walks_several_chunks():
           mb=mb).check()
 
 
+@pytest.mark.parametrize("pool", ["dense", "latent"])
+def test_pages_of_one_sequence_in_one_call_match_a_page_a_call(pool):
+    """Rows of one call may be successive pages of ONE sequence (the
+    engine's prefill tiles): rows 0..2 share a block-table row at page
+    starts 0, BS, 2 BS, the last partial, row 3 is a page of another
+    sequence. The op writes every row's keys before any row reads, so
+    row 1 reads from the pool what row 0 wrote in that call: outputs
+    and pools equal those of the same pages fed one a call, each
+    reading what the calls before it wrote."""
+    rng = np.random.default_rng(7)
+    t, width = BS, (3 * HD if pool == "latent" else H * HD)
+    lanes = kernel.pool_lanes(width)
+    nb = 2 * MB + 1
+    start = np.array([0, BS, 2 * BS, BS], np.int32)
+    nvalid = np.array([BS, BS, BS - 1, BS], np.int32)
+    table = np.zeros((B, MB), np.int32)
+    table[:3] = 1 + np.arange(MB)                # one sequence, thrice
+    table[3] = 1 + MB + np.arange(MB)            # another
+
+    def draw(*shape):
+        return jnp.asarray(rng.normal(size=shape).astype(np.float32))
+
+    def padded():
+        a = np.zeros((nb, BS, lanes), np.float32)
+        a[..., :width] = rng.normal(size=(nb, BS, width))
+        return jnp.asarray(a)
+
+    if pool == "latent":
+        q, k, v = draw(B, H, t, width), draw(B, t, width), None
+        pools = [padded()]
+        attrs = {"sm_scale": 0.2, "value_lanes": 2 * HD}
+    else:
+        q, k, v = draw(B, H, t, HD), draw(B, H, t, HD), draw(B, H, t, HD)
+        pools = [padded(), padded()]
+        attrs = {"sm_scale": HD ** -0.5}
+
+    def call(pools, nvalid):
+        ins = {"Q": [q], "K": [k], "CacheK": [pools[0]],
+               "BlockTable": [jnp.asarray(table)],
+               "StartPos": [jnp.asarray(start)],
+               "NValid": [jnp.asarray(nvalid)]}
+        if v is not None:
+            ins.update(V=[v], CacheV=[pools[1]])
+        out = attention._paged_attention_op(None, ins, attrs)
+        new = [out["CacheKOut"][0]] + \
+            ([out["CacheVOut"][0]] if v is not None else [])
+        return np.asarray(out["Out"][0]), new
+
+    packed, packed_pools = call(pools, nvalid)
+    serial_pools = pools
+    for r in range(B):                           # a page a call, in order
+        alone = np.where(np.arange(B) == r, nvalid, 0).astype(np.int32)
+        out, serial_pools = call(serial_pools, alone)
+        n = int(nvalid[r])
+        np.testing.assert_array_equal(packed[r, :, :n], out[r, :, :n])
+    for a, b in zip(packed_pools, serial_pools):
+        # block 0 is the scratch block: it takes the muted positions
+        np.testing.assert_array_equal(np.asarray(a)[1:], np.asarray(b)[1:])
+    # and the later tile did read the earlier one's keys: with the
+    # sequence's first page muted, row 1 answers otherwise
+    other, _ = call(pools, np.array([0, BS, BS - 1, BS], np.int32))
+    assert np.abs(other[1] - packed[1]).max() > 1e-3
+
+
 def test_cell_page_shape():
     """The serving cells' page: 16 tokens of 16 heads x 64."""
     Batch(1, [(36, 1), (0, 0)], bs=16, h=16, hd=64, mb=4).check()

@@ -357,12 +357,12 @@ def test_trace_report_build_and_consistency():
 # End to end: GenerationEngine span tree + HTTP continuation
 # ---------------------------------------------------------------------------
 
-def _fresh_engine(max_slots=2):
+def _fresh_engine(max_slots=2, max_seq=SEQ, **kw):
     cfg = gpt.gpt_small(vocab_size=VOCAB, d_model=32, n_heads=4,
-                        n_layers=2, d_ff=64, max_seq_len=SEQ,
+                        n_layers=2, d_ff=64, max_seq_len=max_seq,
                         dropout=0.0, use_flash=False)
     eng = GenerationEngine(cfg, fluid.Scope(), exe=fluid.Executor(),
-                           max_slots=max_slots, max_seq=SEQ)
+                           max_slots=max_slots, max_seq=max_seq, **kw)
     eng.init_scope()
     return eng
 
@@ -630,6 +630,45 @@ def test_executor_regions_are_children_of_the_current_span():
         spans = {s["name"]: s for s in trace.drain_spans()}
     assert spans["executor.compile"]["parent_id"] == \
         spans["executor.resolve"]["span_id"]
+
+
+def test_iteration_record_counts_prefill_tiles_beside_requests():
+    """`prefill_tiles` is the rows a turn's prefill step fed, `prefill_
+    rows` the requests it advanced: a prompt of five pages takes four
+    of a four-row step's tiles and the next step's first, where the
+    short prompt behind it gets its one. The monitor's
+    `serving.gen_prefill_tiles` is the records' sum."""
+    from paddle_tpu import monitor
+    prev = fluid.FLAGS.enable_monitor
+    fluid.set_flags({"FLAGS_enable_monitor": True})
+    monitor.reset_stats()
+    eng = _fresh_engine(max_slots=4, max_seq=40, block_size=4)
+    try:
+        # queued before the worker starts: one admission, in this order
+        long_ = eng.submit(GenerationRequest(
+            [i % VOCAB for i in range(18)], 2, timeout_ms=6e5))
+        short = eng.submit(GenerationRequest([3, 2, 1], 2, timeout_ms=6e5))
+        t0 = time.perf_counter()
+        eng.start()
+        try:
+            assert len(long_.result(timeout=60.0)["tokens"]) == 2
+            assert len(short.result(timeout=60.0)["tokens"]) == 2
+        finally:
+            eng.stop()
+        counters = monitor.get_stats_snapshot()["counters"]
+    finally:
+        monitor.reset_stats()
+        fluid.set_flags({"FLAGS_enable_monitor": prev})
+    recs = [r for r in trace.iteration_records()
+            if r["t_start"] >= t0 and r["prefill_rows"]]
+    assert [(r["prefill_rows"], r["prefill_tiles"], r["prefill_tokens"])
+            for r in recs] == [(1, 4, 16), (2, 2, 1 + 2)]
+    assert long_.timings["prefill_steps"] == 2
+    assert short.timings["prefill_steps"] == 1
+    assert counters["serving.gen_prefill_tiles"] == 6
+    assert counters["serving.gen_chunked_prefills"] == 3
+    assert all(r["prefill_tiles"] == 0 for r in trace.iteration_records()
+               if r["t_start"] >= t0 and not r["prefill_rows"])
 
 
 def _host_events(path):

@@ -1351,7 +1351,7 @@ def run_disagg(args):
       once, shipped over /v1/kv/export -> /v1/kv/adopt, and reused via
       the fleet prefix store.
 
-    --service-ms injects a deterministic per-prefill-chunk delay
+    --service-ms injects a deterministic per-prefill-step delay
     (slow_step at the gen_prefill fault site, armed via FLAGS env in
     the worker processes) so the TTFT comparison is
     machine-independent, exactly like the router scaling run. Gates:
@@ -1453,7 +1453,7 @@ def run_disagg(args):
     worker_env = dict(os.environ)
     worker_env["JAX_PLATFORMS"] = worker_platform
     if args.service_ms > 0:
-        # deterministic per-prefill-chunk service time in EVERY worker
+        # deterministic per-prefill-step service time in EVERY worker
         # of BOTH fleets: prefill cost dominates and is identical
         # across machines, so where prefill *runs* (the thing disagg
         # changes) decides the TTFT comparison
