@@ -6,8 +6,11 @@ The layer list is a string, one letter a block: `M` a Mamba-2 mixer
 (`parallel/moe.py:latent_moe`), `*` attention with grouped KV heads over
 the paged pool (`ops/attention.py:paged_attention`), no positions; `L`
 latent attention with YaRN rotary positions over a paged LATENT pool
-(`ops/latent_attention.py`: one row a token for all heads), `D` a
-gated FFN, `G` gated experts (`parallel/moe.py:gated_moe`). Every
+(`ops/latent_attention.py`: one row a token for all heads; without a
+query latent where `q_rank` is 0, without positions where `rope` is
+empty), `D` a gated FFN, `G` gated experts
+(`parallel/moe.py:gated_moe`), `K` gated delta-rule linear attention
+(`ops/linear_attention.py`: a matrix state a head). Every
 block is ONE mixer behind a pre-RMSNorm with a residual, so a layer of
 attention AND an FFN is two letters (`LD`, `LG`); a final RMSNorm and
 an untied head follow the last.
@@ -17,6 +20,7 @@ logits) and chunk-prefill (T = block_size, a health probe) programs
 with the feeds of `gpt.PagedDecodeStep`. Beside the paged KV pools of
 its attention layers the step names a second kind of per-slot state,
 `state_names`: each Mamba-2 layer's convolution window and SSM state,
+each `K` layer's convolution window and matrix state,
 `[max_slots, ...]` persistables that are not paged (row b of the batch
 is slot b: why the engine packs several pages of one request into a
 prefill step only for a model that names no such state), and
@@ -44,17 +48,21 @@ class HybridConfig:
                  top_k=0, moe_latent=0, moe_inter=0, shared_inter=0,
                  routed_scale=1.0, expert_share=0, eps=1e-5,
                  dtype="bfloat16", max_seq_len=2048, q_rank=0, kv_rank=0,
-                 nope_dim=0, rope_dim=0, v_dim=0, dense_inter=0, rope=None):
-        """`L` layers: `q_rank`, `kv_rank` the latents' widths, a head's
-        `nope_dim` + `rope_dim` query/key and `v_dim` value; `rope` the
-        rotary attributes of `ops/latent_attention.py` (theta, factor,
-        original, beta_fast, beta_slow, mscale, mscale_all_dim). `D`:
-        `dense_inter`. `G`: `moe_inter`, `shared_inter` and the router
-        as `E`."""
+                 nope_dim=0, rope_dim=0, v_dim=0, dense_inter=0, rope=None,
+                 kda_heads=0, kda_head_dim=0, kda_conv_kernel=0):
+        """`L` layers: `q_rank`, `kv_rank` the latents' widths (`q_rank`
+        0: queries straight from the hidden state), a head's `nope_dim`
+        + `rope_dim` query/key and `v_dim` value; `rope` the rotary
+        attributes of `ops/latent_attention.py` (theta, factor,
+        original, beta_fast, beta_slow, mscale, mscale_all_dim), empty
+        for a layer that rotates nothing. `D`: `dense_inter`. `G`:
+        `moe_inter`, `shared_inter` and the router as `E`. `K`:
+        `kda_heads` heads of `kda_head_dim` keys and values, behind
+        convolutions of `kda_conv_kernel` taps."""
         for kind in pattern:
-            if kind not in "ME*LDG":
+            if kind not in "ME*LDGK":
                 raise ValueError(
-                    f"pattern letter {kind!r}: M, E, *, L, D or G")
+                    f"pattern letter {kind!r}: M, E, *, L, D, G or K")
         if n_heads % n_kv_heads or mamba_heads % ssm_groups:
             raise ValueError("heads must divide into their KV heads / "
                              "state groups")
@@ -75,6 +83,8 @@ class HybridConfig:
         self.q_rank, self.kv_rank = q_rank, kv_rank
         self.nope_dim, self.rope_dim, self.v_dim = nope_dim, rope_dim, v_dim
         self.dense_inter, self.rope = dense_inter, dict(rope or {})
+        self.kda_heads, self.kda_head_dim = kda_heads, kda_head_dim
+        self.kda_conv_kernel = kda_conv_kernel
 
     @property
     def n_layers(self):
@@ -100,14 +110,23 @@ class HybridConfig:
                                                  + self.rope_dim)
         return lanes * as_np_dtype(self.dtype).itemsize
 
+    @property
+    def kda_inner(self):
+        return self.kda_heads * self.kda_head_dim
+
     def state_slot_bytes(self):
-        """Bytes of recurrent state a slot holds, all Mamba-2 layers:
-        the float32 SSM state and the convolution window."""
+        """Bytes of recurrent state a slot holds: the float32 SSM state
+        and the convolution window of every Mamba-2 layer, the float32
+        matrix state (keys x values a head) and the window of the three
+        convolutions of every `K` layer."""
         from ..core.dtypes import as_np_dtype
+        item = as_np_dtype(self.dtype).itemsize
         ssm = self.mamba_heads * self.mamba_head_dim * self.ssm_state * 4
-        conv = (self.conv_kernel - 1) * self.conv_channels * \
-            as_np_dtype(self.dtype).itemsize
-        return self.pattern.count("M") * (ssm + conv)
+        conv = (self.conv_kernel - 1) * self.conv_channels * item
+        kda = self.kda_inner * self.kda_head_dim * 4
+        window = (self.kda_conv_kernel - 1) * 3 * self.kda_inner * item
+        return self.pattern.count("M") * (ssm + conv) \
+            + self.pattern.count("K") * (kda + window)
 
     def build_paged_step(self, **kw):
         return build_paged_step(self, **kw)
@@ -217,6 +236,41 @@ def build_paged_step(cfg, batch, max_seq, block_size, num_blocks,
             layers.assign(outs["ConvStateOut"], output=conv_s)
             layers.assign(outs["SsmStateOut"], output=ssm_s)
             y = outs["Out"]
+        elif kind == "K":
+            inner, hk = cfg.kda_inner, cfg.kda_head_dim
+            conv_s = state_var(f"{pre}.conv_state",
+                               [batch, cfg.kda_conv_kernel - 1, 3 * inner],
+                               dt)
+            kda_s = state_var(f"{pre}.kda_state",
+                              [batch, cfg.kda_heads, hk, hk], "float32")
+            state_names += [conv_s.name, kda_s.name]
+            m = f"{pre}.kda"
+            outs = _op("kda_mixer", {
+                "X": u,
+                "Q": _param(f"{m}.q.w", [d, inner], dt, mat),
+                "K": _param(f"{m}.k.w", [d, inner], dt, mat),
+                "V": _param(f"{m}.v.w", [d, inner], dt, mat),
+                "ConvW": _param(f"{m}.conv.w",
+                                [3 * inner, cfg.kda_conv_kernel], dt,
+                                Normal(0.0, 0.3)),
+                "F1": _param(f"{m}.f1.w", [d, hk], dt, mat),
+                "F2": _param(f"{m}.f2.w", [hk, inner], dt, mat),
+                "ALog": _param(f"{m}.A_log", [cfg.kda_heads], "float32",
+                               Constant(1.0)),
+                "DtBias": _param(f"{m}.dt_bias", [inner], "float32",
+                                 Constant(-4.6)),
+                "B": _param(f"{m}.b.w", [d, cfg.kda_heads], dt, mat),
+                "G1": _param(f"{m}.g1.w", [d, hk], dt, mat),
+                "G2": _param(f"{m}.g2.w", [hk, inner], dt, mat),
+                "ONorm": _param(f"{m}.o_norm.w", [hk], dt, one),
+                "O": _param(f"{m}.o.w", [inner, d], dt, mat),
+                "ConvState": conv_s, "KdaState": kda_s,
+                "StartPos": start, "NValid": nvalid},
+                {"Out": dt, "ConvStateOut": dt, "KdaStateOut": "float32"},
+                {"epsilon": cfg.eps})
+            layers.assign(outs["ConvStateOut"], output=conv_s)
+            layers.assign(outs["KdaStateOut"], output=kda_s)
+            y = outs["Out"]
         elif kind == "*":
             def heads(z, n):
                 return layers.transpose(
@@ -245,13 +299,17 @@ def build_paged_step(cfg, batch, max_seq, block_size, num_blocks,
             kv_b = _param(f"{a}.kv_b.w",
                           [cfg.kv_rank, h * (cfg.nope_dim + cfg.v_dim)], dt,
                           mat)
-            proj = _op("mla_project", {
-                "X": u,
+            q_w = h * (cfg.nope_dim + cfg.rope_dim)
+            # with a query latent two products and a norm between
+            # them, without one the one product from the hidden state
+            queries = {
                 "QA": _param(f"{a}.q_a.w", [d, cfg.q_rank], dt, mat),
                 "QNorm": _param(f"{a}.q_norm.w", [cfg.q_rank], dt, one),
-                "QB": _param(f"{a}.q_b.w",
-                             [cfg.q_rank, h * (cfg.nope_dim + cfg.rope_dim)],
-                             dt, mat),
+                "QB": _param(f"{a}.q_b.w", [cfg.q_rank, q_w], dt, mat),
+            } if cfg.q_rank else {
+                "QB": _param(f"{a}.q.w", [d, q_w], dt, mat)}
+            proj = _op("mla_project", {
+                "X": u, **queries,
                 "KVA": _param(f"{a}.kv_a.w", [d, row_w], dt, mat),
                 "KVNorm": _param(f"{a}.kv_norm.w", [cfg.kv_rank], dt, one),
                 "KVB": kv_b, "StartPos": start},

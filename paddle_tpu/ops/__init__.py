@@ -33,6 +33,7 @@ from . import straggler_ops  # noqa: F401
 from . import fused  # noqa: F401
 from . import state_space  # noqa: F401
 from . import latent_attention  # noqa: F401
+from . import linear_attention  # noqa: F401
 
 
 def registered_types():

@@ -90,14 +90,21 @@ def mla_project(u, w, start, heads, nope, rope, inv_freq, amplitude, eps):
     """u [B, T, d] -> (queries [B, H, T, kv_rank + rope], the tokens'
     cache rows [B, T, kv_rank + rope]). `w`: q_a [d, q_rank], q_norm
     [q_rank], q_b [q_rank, H (nope + rope)], kv_a [d, kv_rank + rope],
-    kv_norm [kv_rank], kv_b [kv_rank, H (nope + v)]."""
+    kv_norm [kv_rank], kv_b [kv_rank, H (nope + v)]. A model without a
+    query latent has no q_a and no q_norm, and its q_b [d, H (nope +
+    rope)] takes `u` itself; with `inv_freq` None nothing is rotated
+    (a model that takes its positions from other layers)."""
     b, t, _ = u.shape
     dt = u.dtype
-    pos = _positions(start, t)
+    pos = None if inv_freq is None else _positions(start, t)
     rank = w["kv_norm"].shape[0]
-    c_q = rms_norm(_mm(u, w["q_a"]).astype(dt), w["q_norm"], eps)
+
+    def turn(x):
+        return x if pos is None else rotary(x, pos, inv_freq, amplitude)
+    c_q = u if w.get("q_a") is None else \
+        rms_norm(_mm(u, w["q_a"]).astype(dt), w["q_norm"], eps)
     q = _mm(c_q, w["q_b"]).astype(dt).reshape(b, t, heads, nope + rope)
-    q_rope = rotary(q[..., nope:], pos, inv_freq, amplitude)
+    q_rope = turn(q[..., nope:])
     # absorption: q_nope through the key half of W_kvb, a head at a time
     w_k = w["kv_b"].reshape(rank, heads, -1)[:, :, :nope]
     q_lat = jnp.einsum("bthn,lhn->bthl", q[..., :nope], w_k,
@@ -106,7 +113,7 @@ def mla_project(u, w, start, heads, nope, rope, inv_freq, amplitude, eps):
     kv = _mm(u, w["kv_a"]).astype(dt)
     row = jnp.concatenate(
         [rms_norm(kv[..., :rank], w["kv_norm"], eps),
-         rotary(kv[..., rank:], pos, inv_freq, amplitude)], -1)
+         turn(kv[..., rank:])], -1)
     return queries, row
 
 
@@ -141,11 +148,13 @@ def _yarn_rotary_op(ctx, ins, attrs):
 
 @register_op("mla_project", nondiff_inputs=("StartPos",))
 def _mla_project_op(ctx, ins, attrs):
-    w = {"q_a": ins["QA"][0], "q_norm": ins["QNorm"][0],
-         "q_b": ins["QB"][0], "kv_a": ins["KVA"][0],
+    w = {"q_b": ins["QB"][0], "kv_a": ins["KVA"][0],
          "kv_norm": ins["KVNorm"][0], "kv_b": ins["KVB"][0]}
+    if ins.get("QA"):
+        w.update(q_a=ins["QA"][0], q_norm=ins["QNorm"][0])
     rope = int(attrs["rope_dim"])
-    inv, amp = _yarn(attrs, rope)
+    # no rotary attributes: a latent attention without positions
+    inv, amp = _yarn(attrs, rope) if "theta" in attrs else (None, 1.0)
     q, row = mla_project(ins["X"][0], w, ins["StartPos"][0],
                          int(attrs["heads"]), int(attrs["nope_dim"]), rope,
                          inv, amp, float(attrs.get("epsilon", 1e-5)))

@@ -1006,6 +1006,7 @@ class GenerationEngine:
             rec.state_bytes = len(live) * self.cfg.state_slot_bytes()
             STAT_SET("serving.gen_state_slots_live", rec.state_slots_live)
             STAT_SET("serving.gen_state_bytes", rec.state_bytes)
+            STAT_ADD("serving.gen_state_bytes_moved", rec.state_bytes_moved)
         if rec.kv_pages_read:
             rec.kv_bytes_read = rec.kv_pages_read * self.kv_block_bytes()
             STAT_ADD("serving.gen_kv_bytes_read", rec.kv_bytes_read)
@@ -1229,6 +1230,11 @@ class GenerationEngine:
                 blocks_for_tokens(s + n, bs)
                 for s, n in zip(start.tolist(), nvalid.tolist()) if n)
             rec.kv_pages_table += table.size
+            if self.recurrent:
+                # the rows fed had their recurrent state read and
+                # written, once each: the algorithm's count
+                rec.state_bytes_moved += 2 * int(np.count_nonzero(nvalid)) \
+                    * self.cfg.state_slot_bytes()
             if self._probe is not None:
                 # a row an expert layer: selections made, those on held
                 # experts, held experts hit, the busiest one's tokens

@@ -441,7 +441,7 @@ class IterationRecord:
                  "kv_tokens_resident",
                  "kv_blocks_total", "kv_pages_read", "kv_pages_table",
                  "kv_bytes_read",
-                 "state_slots_live", "state_bytes",
+                 "state_slots_live", "state_bytes", "state_bytes_moved",
                  "prefix_skipped_recurrent", "moe_selected",
                  "moe_selected_held", "moe_experts_hit", "moe_load_max",
                  "logit_rows_fetched",
@@ -468,11 +468,16 @@ class IterationRecord:
         # a model with recurrent layers: the slots whose state is live
         # at the turn's end and the bytes it takes (not paged: a row a
         # slot), and the admissions of the turn for which the prefix
-        # cache was not asked (a cached block carries no state). A model with routed experts, over the decode steps the
-        # turn ran and summed over its expert layers: selections made,
+        # cache was not asked (a cached block carries no state), and
+        # over the steps the turn ran the bytes of that state the fed
+        # rows read and wrote (rows x a slot's bytes x 2: the
+        # algorithm's count). A model with routed experts, over the
+        # decode steps the turn ran and summed over its expert layers:
+        # selections made,
         # those that fell on experts held here, held experts with at
         # least one token, tokens on the busiest held expert
         self.state_slots_live = self.state_bytes = 0
+        self.state_bytes_moved = 0
         self.prefix_skipped_recurrent = 0
         self.moe_selected = self.moe_selected_held = 0
         self.moe_experts_hit = self.moe_load_max = 0

@@ -1190,6 +1190,11 @@ skip("mla_project", "latent attention's projections into the absorbed "
 skip("mla_output", "the value half of W_kvb and W_o behind the latent "
      "pool's attention; covered with `mla_project` in "
      "tests/test_latent_model.py")
+skip("kda_mixer", "stateful serving op over per-slot convolution windows "
+     "and a float32 matrix state a head with start/n_valid feeds; chunks, "
+     "single steps, muted, fresh and partly valid rows against the "
+     "reference's token-by-token recurrence in "
+     "tests/test_linear_attention.py")
 skip("gated_moe", "top-k routed gated experts with a held share and an "
      "int32 probe; against the plain reference layer, the shares adding "
      "up (also over an `ep` mesh axis) and a one-expert router in "

@@ -522,6 +522,30 @@ def test_hybrid_programs_are_the_parents(dtype, decode, prefill):
     assert fingerprint(eng._prefill_prog) == prefill
 
 
+@pytest.mark.parametrize("dtype,decode,prefill", [
+    ("float32",
+     (34, "bd5f239a05c2761a7ec3135ffbfefbda"
+          "97d03c011eb8278c7a6829185ef4c87c"),
+     (31, "faad0f62385f1cbbd200232f76ddc24b"
+          "9e2690d18b5f0579e538e23e72efec49")),
+    ("bfloat16",
+     (34, "6023f17f9993ec098fca7f00182ce1c1"
+          "a3f1c608387a785b6dcf817e3cb2fc4a"),
+     (31, "d758592c1bf53d3c1e54a201f509ea6c"
+          "a23aa8d78d08303b544761c65f12a2ff"))])
+def test_latent_programs_are_the_parents(dtype, decode, prefill):
+    """The latent-attention decoder's two programs (letters L, D, G with
+    a query latent and rotary attributes) digest as they did before the
+    `L` letter learned to do without either (PR 34's tree)."""
+    from benchmark.families import mla_serve
+    _, cfg, _, _ = manifest.cell("kimi_k2_5_ep32_l5.batch_long_ctx",
+                                 rehearsal=True)
+    cfg = dict(cfg, engine=dict(cfg["engine"], dtype=dtype, max_slots=3))
+    eng = mla_serve.build(cfg, {"timeout_ms": 600000}, 1, SEED).engine
+    assert fingerprint(eng._prog, eng.step) == decode
+    assert fingerprint(eng._prefill_prog) == prefill
+
+
 def test_paged_argument_selects_nothing():
     """There is one engine. The constructor's `paged` is what the
     benchmark's call passes: True and nothing build the same programs,
