@@ -38,6 +38,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from ..ops.pallas.grouped_matmul import grouped_matmul
+
 __all__ = ["moe_ffn", "moe_ffn_sharded", "moe_ffn_sparse",
            "moe_ffn_sparse_sharded", "init_moe_params", "route_top_k",
            "experts_apply", "latent_moe", "latent_moe_sharded", "gated_moe",
@@ -91,20 +93,20 @@ def experts_apply(rows, group_sizes, w1, w2, act, b1=None, b2=None):
     of each; rows past the groups' sum belong to no expert and their
     result is undefined (the caller masks it). w1 [E_held, d_in, f],
     w2 [E_held, f, d_out], optional biases [E_held, f] / [E_held,
-    d_out]. Grouped products (`jax.lax.ragged_dot`): what they cost
-    follows the rows, not rows x experts. Returns [M, d_out] float32."""
+    d_out]. Grouped products (`ops/pallas/grouped_matmul.py`): an
+    expert with no row moves no weight, and what they cost follows the
+    rows that are led, not M and not rows x experts. Returns
+    [M, d_out] float32."""
     m = rows.shape[0]
 
     def bias(b):
         return jnp.repeat(b, group_sizes, axis=0, total_repeat_length=m)
 
-    h = jax.lax.ragged_dot(rows, w1, group_sizes,
-                           preferred_element_type=jnp.float32)
+    h = grouped_matmul(rows, w1, group_sizes)
     if b1 is not None:
         h = h + bias(b1)
     h = act(h).astype(rows.dtype)
-    out = jax.lax.ragged_dot(h, w2, group_sizes,
-                             preferred_element_type=jnp.float32)
+    out = grouped_matmul(h, w2, group_sizes)
     return out if b2 is None else out + bias(b2)
 
 

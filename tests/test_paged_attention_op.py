@@ -365,3 +365,48 @@ def test_latent_kernel_compiles_for_v5e(one_chip, monkeypatch, t):
     copies = [ln for ln in text.splitlines()
               if f"= bf16[{nb},{bs},{lanes}]" in ln and " copy(" in ln]
     assert not copies, copies
+
+
+# -- the expert layers' grouped product, compiled for the chip: in this
+# file because it is the one that loads the TPU's compiler --------------------
+
+@pytest.mark.parametrize("m", ["decode", "prefill"])
+@pytest.mark.parametrize("held,d_in,f_in,f_out,d_out,act,rows", [
+    (128, 1024, 2688, 2688, 1024, "_relu2", (1408, 22528)),
+    (12, 7168, 4096, 2048, 7168, "_swiglu", (512, 8192)),
+    (32, 2304, 2048, 1024, 2304, "_swiglu", (1024, 16384))],
+    ids=["nemotron3_super_ep4_l11", "kimi_k2_5_ep32_l5",
+         "kimi_linear_ep8_l8"])
+def test_grouped_matmul_compiles_for_v5e(one_chip, monkeypatch, held, d_in,
+                                         f_in, f_out, d_out, act, rows, m):
+    """`experts_apply` at the sizes of the three expert cells' decode
+    and prefill steps (slots x tokens x top_k rows, bfloat16): Mosaic
+    takes both products' kernels, and the held experts' weights reach
+    them as they lie in HBM, with no copy and no transpose."""
+    from paddle_tpu.ops.pallas import grouped_matmul
+    from paddle_tpu.parallel import moe
+    monkeypatch.setattr(grouped_matmul, "_interpret", lambda: False)
+    grouped_matmul._product.clear_cache()   # no interpreted trace is reused
+    m = rows[m == "prefill"]
+
+    def arg(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    layer = jax.jit(lambda x, sizes, w1, w2: moe.experts_apply(
+        x, sizes, w1, w2, getattr(moe, act)))
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        text = layer.lower(
+            arg((m, d_in)), arg((held,), jnp.int32),
+            arg((held, d_in, f_in)), arg((held, f_out, d_out))
+        ).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", prev)
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 2
+    weights = (f"= bf16[{held},{d_in},{f_in}]",
+               f"= bf16[{held},{f_out},{d_out}]")
+    moved = [ln for ln in text.splitlines()
+             if any(w in ln for w in weights)
+             and (" copy(" in ln or " transpose(" in ln)]
+    assert not moved, moved
