@@ -35,17 +35,14 @@ where it is read); the projections take their operands as they come
 """
 from __future__ import annotations
 
-import math
-
 import jax
 import jax.numpy as jnp
 
 from ..core.registry import register_op
-from .state_space import _conv_window, rms_norm
+from .state_space import _conv_window, rms_norm, rows_in_passes
 
 HIGHEST = jax.lax.Precision.HIGHEST
 L2_EPS = 1e-6
-ROWS_A_PASS = 8       # rows of a prefill step the mixer takes at once
 
 
 def l2_normalise(x):
@@ -200,35 +197,13 @@ def kda_mixer(u, w, conv_state, kda_state, start, nvalid, *, eps):
     [B, H, K, K] are row b's own (row = slot). A row with `start == 0`
     starts from zero state and a zero window; a row with `nvalid == 0`
     gets both back untouched; tokens at t >= nvalid leave the state
-    alone and the window moves by `nvalid`. Returns (out [B, T, d],
-    conv_state, kda_state).
-
-    A decode step (T = 1) advances nearly every row, and takes them all
-    in one pass. A prefill step feeds a page to the few slots still
-    reading their prompts and mutes the rest: there the rows that
-    advance are taken `ROWS_A_PASS` at a time, first to last, by a loop
-    of as many passes as they need, and a muted row is neither computed
-    nor its state read or written (its output is zero)."""
-    b, t, _ = u.shape
-    if t == 1:
-        return _mixer_rows(u, w, conv_state, kda_state, start, nvalid, eps)
-    r = math.gcd(b, ROWS_A_PASS)
-    live = nvalid > 0
-    order = jnp.argsort(~live, stable=True)           # live rows first
-
-    def one_pass(i, carry):
-        out, conv, state = carry
-        rows = jax.lax.dynamic_slice_in_dim(order, i * r, r)
-        # the last pass may reach past the live rows: `_mixer_rows`
-        # hands a muted row's states back as they were
-        o, c, s = _mixer_rows(u[rows], w, conv[rows], state[rows],
-                              start[rows], nvalid[rows], eps)
-        return out.at[rows].set(o), conv.at[rows].set(c), \
-            state.at[rows].set(s)
-
-    passes = (jnp.sum(live.astype(jnp.int32)) + r - 1) // r
-    return jax.lax.fori_loop(0, passes, one_pass,
-                             (jnp.zeros_like(u), conv_state, kda_state))
+    alone and the window moves by `nvalid` (in a prefill step a muted
+    row is not computed at all: `state_space.rows_in_passes`). Returns
+    (out [B, T, d], conv_state, kda_state)."""
+    return rows_in_passes(
+        lambda x, conv, state, s, n: _mixer_rows(x, w, conv, state, s, n,
+                                                 eps),
+        u, conv_state, kda_state, start, nvalid)
 
 
 @register_op("kda_mixer",

@@ -5,7 +5,10 @@ norm inside the mixer. `mamba2_mixer` is one whole mixer as the serving
 engine steps it: in-projection, causal depthwise convolution, the
 selective scan, gated norm, out-projection, with the convolution window
 and the SSM state of every slot carried in and out, for T = 1 (decode)
-and T = block_size (a prefill chunk) alike.
+and T = block_size (a prefill chunk) alike. `rows_in_passes` is how a
+recurrent mixer (this one and `linear_attention.kda_mixer`) takes a
+step's rows: all at once in a decode step, only those that advance in
+a prefill step.
 
 Statistics, the scan and the state are float32 whatever the activations
 are; matrix products take their operands as they come (bfloat16 weights
@@ -13,12 +16,15 @@ and activations accumulate in float32 on the MXU).
 """
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 
 from ..core.registry import register_op
 
 HIGHEST = jax.lax.Precision.HIGHEST
+ROWS_A_PASS = 8       # rows of a prefill step a mixer takes at once
 
 
 def rms_norm(x, scale, eps, groups=1, gate=None):
@@ -108,16 +114,59 @@ def selective_scan(x, dt, a, b, c, d_skip, state):
     return y, new
 
 
-def mamba2_mixer(u, w, conv_state, ssm_state, start, nvalid, *, groups,
-                 eps):
-    """One Mamba-2 mixer over a chunk `u` [B, T, d] of each row's
-    sequence. `w`: in_proj [d, 2 inner + 2 G N + H], conv_w [C, K],
-    conv_b [C], dt_bias, a_log, d [H], norm_w [inner], out_proj
-    [inner, d]. `conv_state` [B, K-1, C] and `ssm_state` [B, H, P, N]
-    are row b's own (row = slot). A row with `start == 0` starts from
-    zero state; a row with `nvalid == 0` gets its state back untouched;
-    tokens at t >= nvalid leave the state alone and the window moves by
-    `nvalid`. Returns (out [B, T, d], conv_state, ssm_state)."""
+def _rows_a_pass(batch):
+    return math.gcd(batch, ROWS_A_PASS)
+
+
+def rows_computed(batch, t, advancing):
+    """The rows `rows_in_passes` computes in a step of `batch` rows of
+    `t` tokens of which `advancing` are fed: all of them at t = 1, else
+    the advancing rows rounded up to whole passes."""
+    if t == 1:
+        return batch
+    r = _rows_a_pass(batch)
+    return -(-advancing // r) * r
+
+
+def rows_in_passes(mix_rows, u, conv_state, state, start, nvalid):
+    """A recurrent mixer over a step's rows, by `mix_rows(u, conv_state,
+    state, start, nvalid) -> (out, conv_state, state)`, which computes
+    the rows it is given in one pass and hands a muted row's states
+    back as they were.
+
+    A decode step (T = 1) advances nearly every row, and takes them all
+    in one pass. A prefill step feeds a page to the few slots still
+    reading their prompts and mutes the rest: there the rows that
+    advance are taken `ROWS_A_PASS` at a time (the largest divisor of it
+    that divides the batch), first to last, by a loop of as many passes as they need,
+    and a muted row is neither computed nor its state read or written
+    (its output is zero)."""
+    b, t, _ = u.shape
+    if t == 1:
+        return mix_rows(u, conv_state, state, start, nvalid)
+    r = _rows_a_pass(b)
+    live = nvalid > 0
+    order = jnp.argsort(~live, stable=True)           # live rows first
+
+    def one_pass(i, carry):
+        out, conv, st = carry
+        rows = jax.lax.dynamic_slice_in_dim(order, i * r, r)
+        # the last pass may reach past the live rows: `mix_rows` hands
+        # a muted row's states back as they were, and its output is
+        # zeroed as a row outside every pass has it
+        o, c, s = mix_rows(u[rows], conv[rows], st[rows], start[rows],
+                           nvalid[rows])
+        o = jnp.where(live[rows][:, None, None], o, 0)
+        return out.at[rows].set(o), conv.at[rows].set(c), \
+            st.at[rows].set(s)
+
+    passes = (jnp.sum(live.astype(jnp.int32)) + r - 1) // r
+    return jax.lax.fori_loop(0, passes, one_pass,
+                             (jnp.zeros_like(u), conv_state, state))
+
+
+def _mixer_rows(u, w, conv_state, ssm_state, start, nvalid, groups, eps):
+    """`mamba2_mixer` over the rows it is given, all in one pass."""
     bsz, t, _ = u.shape
     _, h, p, n = ssm_state.shape
     inner = h * p
@@ -150,6 +199,23 @@ def mamba2_mixer(u, w, conv_state, ssm_state, start, nvalid, *, groups,
     out = jnp.matmul(y, w["out_proj"],
                      preferred_element_type=jnp.float32).astype(u.dtype)
     return out, conv_new, ssm_new
+
+
+def mamba2_mixer(u, w, conv_state, ssm_state, start, nvalid, *, groups,
+                 eps):
+    """One Mamba-2 mixer over a chunk `u` [B, T, d] of each row's
+    sequence. `w`: in_proj [d, 2 inner + 2 G N + H], conv_w [C, K],
+    conv_b [C], dt_bias, a_log, d [H], norm_w [inner], out_proj
+    [inner, d]. `conv_state` [B, K-1, C] and `ssm_state` [B, H, P, N]
+    are row b's own (row = slot). A row with `start == 0` starts from
+    zero state; a row with `nvalid == 0` gets its state back untouched
+    (in a prefill step it is not computed at all: `rows_in_passes`);
+    tokens at t >= nvalid leave the state alone and the window moves by
+    `nvalid`. Returns (out [B, T, d], conv_state, ssm_state)."""
+    return rows_in_passes(
+        lambda x, conv, ssm, s, n: _mixer_rows(x, w, conv, ssm, s, n,
+                                               groups, eps),
+        u, conv_state, ssm_state, start, nvalid)
 
 
 @register_op("mamba2_mixer",

@@ -80,6 +80,7 @@ from .. import goodput as _goodput
 from .. import trace
 from ..monitor import STAT_ADD, STAT_OBSERVE, STAT_SET
 from ..monitor import enabled as _monitor_on
+from ..ops.state_space import rows_computed
 from ..resilience.breaker import CLOSED, HALF_OPEN, OPEN, CircuitBreaker
 from ..resilience.faults import TransientFault
 from ..resilience.faults import injector as _fault_injector
@@ -1007,6 +1008,8 @@ class GenerationEngine:
             STAT_SET("serving.gen_state_slots_live", rec.state_slots_live)
             STAT_SET("serving.gen_state_bytes", rec.state_bytes)
             STAT_ADD("serving.gen_state_bytes_moved", rec.state_bytes_moved)
+            STAT_ADD("serving.gen_state_rows_computed",
+                     rec.state_rows_computed)
         if rec.kv_pages_read:
             rec.kv_bytes_read = rec.kv_pages_read * self.kv_block_bytes()
             STAT_ADD("serving.gen_kv_bytes_read", rec.kv_bytes_read)
@@ -1232,9 +1235,12 @@ class GenerationEngine:
             rec.kv_pages_table += table.size
             if self.recurrent:
                 # the rows fed had their recurrent state read and
-                # written, once each: the algorithm's count
-                rec.state_bytes_moved += 2 * int(np.count_nonzero(nvalid)) \
-                    * self.cfg.state_slot_bytes()
+                # written, once each: the algorithm's count; the mixers
+                # computed those rows in whole passes, or every row
+                fed = int(np.count_nonzero(nvalid))
+                rec.state_bytes_moved += 2 * fed * self.cfg.state_slot_bytes()
+                rec.state_rows_computed += rows_computed(
+                    len(nvalid), tokens.shape[1], fed)
             if self._probe is not None:
                 # a row an expert layer: selections made, those on held
                 # experts, held experts hit, the busiest one's tokens

@@ -371,6 +371,45 @@ def test_a_recurrent_model_keeps_a_page_a_request_a_step():
                        and r["block_size"] == 16 for r in fed)
 
 
+def test_the_records_count_the_rows_the_mixers_compute():
+    """`state_rows_computed` over an engine of 16 slots: a prefill step
+    computes the rows it advances in whole passes of 8, a decode step
+    every row. Twelve requests of 1, 2, 3 and 4 pages, queued before the
+    engine starts, are admitted together and prefill 12, 9, 6, then 3
+    rows: 16, 16, 8 and 8 rows computed, beside 16 for every decode
+    step. The monitor's counter is the records' sum."""
+    from paddle_tpu import monitor
+    cfg, sz = small()
+    cfg = dict(cfg, engine=dict(cfg["engine"], max_slots=16))
+    cell = hybrid_serve.build(cfg, {"timeout_ms": 600000}, 1, SEED)
+    rng = np.random.default_rng(8)
+    prev = fluid.FLAGS.enable_monitor
+    fluid.set_flags({"FLAGS_enable_monitor": True})
+    monitor.reset_stats()
+    try:
+        resps = [cell.engine.submit(GenerationRequest(
+            rng.integers(0, sz["vocab_size"], n).tolist(), 2,
+            timeout_ms=600000)) for n in (10, 30, 45, 60) * 3]
+        t0 = time.perf_counter()
+        cell.warm()
+        for resp in resps:
+            assert len(resp.result(timeout=300)["tokens"]) == 2
+        cell.stop()
+        counted = monitor.get_stats_snapshot()["counters"][
+            "serving.gen_state_rows_computed"]
+    finally:
+        monitor.reset_stats()
+        fluid.set_flags({"FLAGS_enable_monitor": prev})
+    records = [r for r in fluid.trace.iteration_records()
+               if r["t_start"] >= t0]
+    assert [r["prefill_rows"] for r in records if r["prefill_rows"]] == \
+        [12, 9, 6, 3]
+    for r in records:
+        assert r["state_rows_computed"] == \
+            -(-r["prefill_rows"] // 8) * 8 + 16 * bool(r["decode_rows"])
+    assert counted == sum(r["state_rows_computed"] for r in records) > 0
+
+
 # -- (e) what the engine does not do for a recurrent model --------------------
 
 def test_prefix_cache_adopts_nothing_and_spec_disagg_wire_refuse():

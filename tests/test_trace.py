@@ -669,6 +669,10 @@ def test_iteration_record_counts_prefill_tiles_beside_requests():
     assert counters["serving.gen_chunked_prefills"] == 3
     assert all(r["prefill_tiles"] == 0 for r in trace.iteration_records()
                if r["t_start"] >= t0 and not r["prefill_rows"])
+    # a model without recurrent layers: no mixer rows to count
+    assert all(r["state_rows_computed"] == 0
+               for r in trace.iteration_records() if r["t_start"] >= t0)
+    assert "serving.gen_state_rows_computed" not in counters
 
 
 def _host_events(path):
