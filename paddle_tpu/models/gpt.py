@@ -322,7 +322,8 @@ class PagedDecodeStep:
     `state_names` are a second kind of per-slot state, not paged:
     `[batch, ...]` persistables of recurrent layers, row b slot b's
     (models/hybrid.py; none here). `probe_var`, where a model has one,
-    is a few int32 that the decode step fetches beside its logits.
+    is a few int32 that each step fetches beside its logits or its
+    health probe.
     `picks_var` and `logits_name` are the serving engine's, set where
     it appends its own ops to a step with logits
     (serving/generation.py: `_pick_on_device`): the small variable such

@@ -24,8 +24,8 @@ each `K` layer's convolution window and matrix state,
 `[max_slots, ...]` persistables that are not paged (row b of the batch
 is slot b: why the engine packs several pages of one request into a
 prefill step only for a model that names no such state), and
-`probe_var`, four int32 a latent-expert layer that the decode step
-fetches beside its logits.
+`probe_var`, four int32 an expert layer that each step fetches beside
+its logits or its health probe.
 """
 from __future__ import annotations
 
@@ -395,10 +395,10 @@ def build_paged_step(cfg, batch, max_seq, block_size, num_blocks,
                           "Y": layers.cast(head, "float32")},
                   {"Out": "float32"},
                   {"x_num_col_dims": 2, "y_num_col_dims": 1})["Out"]
-        if probes:
-            probe_var = layers.stack(probes, axis=0)
     else:
         out = layers.reduce_mean(layers.cast(x, "float32"), dim=[1, 2])
+    if probes:
+        probe_var = layers.stack(probes, axis=0)
     step = PagedDecodeStep(token, out, cache_names, table, start, nvalid,
                            batch, max_seq, block_size, num_blocks, T,
                            state_prefix)

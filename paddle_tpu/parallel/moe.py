@@ -24,8 +24,12 @@ of the held experts (`experts_apply`):
   which experts it holds (`share`), routes over all, sorts the
   selections that fell on its own experts by expert and evaluates them
   with grouped products whose cost follows the tokens routed: no
-  capacity, and no token is ever dropped. What the experts of other
-  shares would add is theirs to add: under an `ep` mesh axis a psum
+  capacity, and no token is ever dropped. A decode step (one token a
+  row) takes all its selections in one pass; a longer step (prefill,
+  verify) only the held ones, in a loop of passes of a decode step's
+  size, so that no array holds a row for every selection (the loop is
+  not reverse-differentiable). What the experts of other shares would
+  add is theirs to add: under an `ep` mesh axis a psum
   sums the shares, on one chip nothing stands in for them.
   `gated_moe` is the same layer without the latent projections and
   with gated experts (`(silu(u W_gate) * u W_up) W_down`).
@@ -286,13 +290,66 @@ def _swiglu(h):
     return jax.nn.silu(h[:, :f]) * h[:, f:]
 
 
+def passes(held, batch, top_k):
+    """The passes an expert layer takes over `held` selections on held
+    experts (an int, or an integer array of one a layer) in a step of
+    more than one token a row: each takes `batch * top_k`, the
+    selections a decode step of the same batch makes."""
+    return -(-held // (batch * top_k))
+
+
+def _counts(ids, groups):
+    """How many of `ids` [M] int32 fall in each of `groups`: a compare
+    and a sum, which the chip does far faster than a scatter of M
+    ones."""
+    return jnp.sum(ids[:, None] == jnp.arange(groups, dtype=jnp.int32),
+                   axis=0, dtype=jnp.int32)
+
+
+def _held_in_passes(rows_in, eid, w, p, top_k, act, n_held, batch):
+    """The held selections of a step of more than one token a row, in
+    expert order, in `passes`: `eid` [N k] int32 is a selection's
+    held expert (E_held for none), `w` [N k] its weight. Each pass
+    gathers its rows of `rows_in`, evaluates them with the experts'
+    grouped products and adds each product, weighed, to its token's
+    float32 row; a group cut by a pass boundary is evaluated in both
+    passes, each with its own rows. Returns [N, d_out] float32."""
+    nk = eid.shape[0]
+    eh = p["w1"].shape[0]
+    rows = batch * top_k
+    if (eh + 1) * nk >= 2**31:
+        raise ValueError(f"{nk} selections over {eh} held experts do not "
+                         "fit one int32 sort key")
+    # one int32 key a selection, its expert then its slot, so no two
+    # are equal and the sort need not be stable: the held ones first,
+    # by expert
+    key = jax.lax.sort(eid * nk + jnp.arange(nk, dtype=jnp.int32),
+                       is_stable=False)
+
+    def one_pass(i, acc):
+        part = jax.lax.dynamic_slice_in_dim(key, i * rows, rows)
+        slot, expert = part % nk, part // nk
+        tok = slot // top_k
+        f = experts_apply(rows_in[tok], _counts(expert, eh), p["w1"],
+                          p["w2"], act)
+        f = jnp.where((expert < eh)[:, None], f * w[slot][:, None], 0.0)
+        return acc.at[tok].add(f)
+
+    return jax.lax.fori_loop(
+        0, passes(n_held, batch, top_k), one_pass,
+        jnp.zeros((rows_in.shape[0], p["w2"].shape[2]), jnp.float32))
+
+
 def _routed_share(x2, rows_in, p, top_k, scale, share, valid, act,
-                  axis_name):
-    """What this share's held experts add for tokens `x2` [N, d]: route
-    over the whole router, sort the selections that fell on held
-    experts by expert, evaluate them over `rows_in` [N, d_in] (the
-    experts' input a token) with grouped products, weigh and sum each
-    token's. Returns (routed [N, d_out] float32, probe [4] int32)."""
+                  axis_name, tokens):
+    """What this share's held experts add for tokens `x2` [N, d], `tokens`
+    a row of the step: route over the whole router, order the
+    selections that fell on held experts by expert, evaluate them over
+    `rows_in` [N, d_in] (the experts' input a token) with grouped
+    products, weigh and sum each token's. A decode step (one token a
+    row) takes all N k selections in one pass; a longer step only the
+    held ones, in passes of a decode step's size (`_held_in_passes`).
+    Returns (routed [N, d_out] float32, probe [4] int32)."""
     n = x2.shape[0]
     eh = p["w1"].shape[0]
     _, sel, w = route_top_k(x2, p["router_w"], top_k, score="sigmoid",
@@ -303,16 +360,22 @@ def _routed_share(x2, rows_in, p, top_k, scale, share, valid, act,
     # selections sorted by held expert; those of other shares go last,
     # in a group of their own that no product visits
     eid = jnp.where(held, local, eh).reshape(n * top_k)
-    order = jnp.argsort(eid, stable=True)
-    sizes = jnp.zeros((eh + 1,), jnp.int32).at[eid].add(1)[:eh]
-    f = experts_apply(rows_in[order // top_k], sizes, p["w1"], p["w2"], act)
-    wf = jnp.where(held, w, 0.0).reshape(n * top_k)[order]
-    f = jnp.where((eid[order] < eh)[:, None], f * wf[:, None], 0.0)
-    # back to token order: a gather by the inverse permutation, then
-    # each token's k selections summed
-    back = jnp.zeros((n * top_k,), jnp.int32).at[order].set(
-        jnp.arange(n * top_k, dtype=jnp.int32))
-    routed = f[back].reshape(n, top_k, -1).sum(axis=1)
+    if tokens == 1:
+        order = jnp.argsort(eid, stable=True)
+        sizes = jnp.zeros((eh + 1,), jnp.int32).at[eid].add(1)[:eh]
+        f = experts_apply(rows_in[order // top_k], sizes, p["w1"], p["w2"],
+                          act)
+        wf = jnp.where(held, w, 0.0).reshape(n * top_k)[order]
+        f = jnp.where((eid[order] < eh)[:, None], f * wf[:, None], 0.0)
+        # back to token order: a gather by the inverse permutation, then
+        # each token's k selections summed
+        back = jnp.zeros((n * top_k,), jnp.int32).at[order].set(
+            jnp.arange(n * top_k, dtype=jnp.int32))
+        routed = f[back].reshape(n, top_k, -1).sum(axis=1)
+    else:
+        sizes = _counts(eid, eh)
+        routed = _held_in_passes(rows_in, eid, w.reshape(n * top_k), p,
+                                 top_k, act, jnp.sum(sizes), n // tokens)
     hit = jnp.sum(sizes > 0).astype(jnp.int32)
     busiest = jnp.max(sizes)
     n_held = jnp.sum(sizes)
@@ -358,7 +421,7 @@ def latent_moe(x, p, top_k, scale, share=0, n_valid=None, axis_name=None):
                      preferred_element_type=jnp.float32).astype(x.dtype)
     routed, probe = _routed_share(x2, lat, p, top_k, scale, share,
                                   _valid_tokens(b, t, n_valid), _relu2,
-                                  axis_name)
+                                  axis_name, t)
     out = jnp.matmul(routed.astype(x.dtype), p["up"],
                      preferred_element_type=jnp.float32) \
         + _shared_expert(x2, p, _relu2)
@@ -375,7 +438,7 @@ def gated_moe(x, p, top_k, scale, share=0, n_valid=None, axis_name=None):
     x2 = x.reshape(b * t, d)
     routed, probe = _routed_share(x2, x2, p, top_k, scale, share,
                                   _valid_tokens(b, t, n_valid), _swiglu,
-                                  axis_name)
+                                  axis_name, t)
     out = routed + _shared_expert(x2, p, _swiglu)
     return out.astype(x.dtype).reshape(b, t, d), probe
 

@@ -81,6 +81,7 @@ from .. import trace
 from ..monitor import STAT_ADD, STAT_OBSERVE, STAT_SET
 from ..monitor import enabled as _monitor_on
 from ..ops.state_space import rows_computed
+from ..parallel.moe import passes as moe_passes
 from ..resilience.breaker import CLOSED, HALF_OPEN, OPEN, CircuitBreaker
 from ..resilience.faults import TransientFault
 from ..resilience.faults import injector as _fault_injector
@@ -596,7 +597,7 @@ class GenerationEngine:
     def fetch_list(self, prog):
         """What a run of `prog` fetches: the step's picks (or probe
         row) and, in the same fetch, the few int32 a model with a
-        `probe_var` counts in its decode step (`step.fetch_vars`).
+        `probe_var` counts in each step (`step.fetch_vars`).
         `executables()` names the first; a caller that compiles or
         re-runs an executable passes this list to get the one the
         engine runs."""
@@ -1018,6 +1019,9 @@ class GenerationEngine:
                      rec.moe_selected_held / rec.moe_selected)
             STAT_SET("serving.gen_moe_experts_hit", rec.moe_experts_hit)
             STAT_SET("serving.gen_moe_load_max", rec.moe_load_max)
+        if rec.moe_prefill_held:
+            STAT_ADD("serving.gen_moe_prefill_held", rec.moe_prefill_held)
+            STAT_ADD("serving.gen_moe_passes", rec.moe_passes)
         if rec.prefill_tiles:
             STAT_ADD("serving.gen_prefill_tiles", rec.prefill_tiles)
         if rec.logit_rows_fetched:
@@ -1241,7 +1245,14 @@ class GenerationEngine:
                 rec.state_bytes_moved += 2 * fed * self.cfg.state_slot_bytes()
                 rec.state_rows_computed += rows_computed(
                     len(nvalid), tokens.shape[1], fed)
-            if self._probe is not None:
+            if self._probe is not None and step is self.prefill_step:
+                # a row an expert layer, as below: the held selections
+                # were evaluated in passes of a decode step's size
+                held = self._probe[:, 1]
+                rec.moe_prefill_held += int(held.sum())
+                rec.moe_passes += int(moe_passes(
+                    held, len(nvalid), self.cfg.top_k).sum())
+            elif self._probe is not None:
                 # a row an expert layer: selections made, those on held
                 # experts, held experts hit, the busiest one's tokens
                 sel, held, hit, busiest = self._probe.sum(axis=0).tolist()

@@ -444,6 +444,7 @@ class IterationRecord:
                  "state_slots_live", "state_bytes", "state_bytes_moved",
                  "state_rows_computed", "prefix_skipped_recurrent",
                  "moe_selected", "moe_selected_held", "moe_experts_hit", "moe_load_max",
+                 "moe_prefill_held", "moe_passes",
                  "logit_rows_fetched",
                  "slots", "block_size", "host_s", "_open")
 
@@ -477,12 +478,16 @@ class IterationRecord:
         # model with routed experts, over the decode steps the turn ran
         # and summed over its expert layers: selections made,
         # those that fell on experts held here, held experts with at
-        # least one token, tokens on the busiest held expert
+        # least one token, tokens on the busiest held expert; over the
+        # prefill steps, the selections on held experts they evaluated
+        # and the passes their expert layers took to do so
+        # (`moe.passes`, summed over layers)
         self.state_slots_live = self.state_bytes = 0
         self.state_bytes_moved = self.state_rows_computed = 0
         self.prefix_skipped_recurrent = 0
         self.moe_selected = self.moe_selected_held = 0
         self.moe_experts_hit = self.moe_load_max = 0
+        self.moe_prefill_held = self.moe_passes = 0
         # positions whose logits the host asked for in the turn: a
         # decode step leaves them on the device and brings back each
         # row's arg-max, so this counts the rows that were sampled with

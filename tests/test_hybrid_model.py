@@ -7,6 +7,7 @@ engine's programs, GPT's three and this model's two, as the parent
 commit built them."""
 import hashlib
 import json
+import re
 import time
 
 import jax
@@ -261,6 +262,15 @@ def test_the_four_shares_add_up_to_the_uncut_layer():
     np.testing.assert_allclose(summed, whole, rtol=2e-4, atol=2e-5)
     np.testing.assert_allclose(routed_op + shared, whole, rtol=2e-4,
                                atol=2e-5)
+    # over the mesh each share loops over its own held selections: a
+    # step of 24 tokens in one row takes passes of 1 x top_k, and the
+    # shares' trip counts differ
+    sel, _ = ref.route(u, f32(p), sz)
+    trips = [int(moe.passes(int(((sel >= k * held)
+                                 & (sel < (k + 1) * held)).sum()),
+                            1, sz["num_experts_per_tok"]))
+             for k in range(4)]
+    assert len(set(trips)) > 1, trips
     mesh = jax.sharding.Mesh(np.array(jax.devices()[:4]), ("ep",))
     out, probe = moe.latent_moe_sharded(
         u[None], moe_params(p), mesh, sz["num_experts_per_tok"],
@@ -272,7 +282,11 @@ def test_the_four_shares_add_up_to_the_uncut_layer():
 def test_no_token_is_dropped_when_every_token_takes_one_expert():
     """A selection bias that sends all 64 tokens to expert 2 (and to
     three experts of other shares): the held expert sees 64 tokens, 16
-    times the even load, and every one gets its output."""
+    times the even load, and every one gets its output. The step of
+    4 x 16 tokens takes its 64 held selections in passes of a decode
+    step's 4 x 4, so in four, the one group cut at every boundary: it
+    equals the reference and the same tokens taken as a decode step
+    (64 rows of one token, all 256 selections in one pass)."""
     _, sz = small()
     p = leaves(sz, 0)
     bias = np.full(sz["router_width"], -10.0, np.float32)
@@ -282,12 +296,98 @@ def test_no_token_is_dropped_when_every_token_takes_one_expert():
     u = jnp.asarray(rng.normal(size=(4, 16, sz["hidden_size"])), jnp.float32)
     out, probe = jit_moe(sz)(u, p, jnp.full((4,), 16, jnp.int32))
     assert [int(v) for v in probe] == [256, 64, 1, 64]
+    assert moe.passes(64, 4, sz["num_experts_per_tok"]) == 4
     want = jax.jit(lambda u: ref.latent_moe(u, f32(p), sz))(
         u.reshape(64, -1))
     np.testing.assert_allclose(out.reshape(64, -1), want, rtol=2e-4,
                                atol=2e-5)
+    one, probe = jit_moe(sz)(u.reshape(64, 1, -1), p,
+                             jnp.ones((64,), jnp.int32))
+    assert [int(v) for v in probe] == [256, 64, 1, 64]
+    np.testing.assert_allclose(out.reshape(64, -1), one.reshape(64, -1),
+                               rtol=2e-4, atol=2e-5)
     routed = out.reshape(64, -1) - ref.shared_part(u.reshape(64, -1), f32(p))
     assert float(jnp.abs(routed).max(axis=1).min()) > 1e-7
+
+
+def test_a_step_with_no_valid_token_takes_no_pass():
+    """Every row of a 4 x 16 step muted: nothing is selected or
+    evaluated (no pass), and the output is the shared expert's."""
+    _, sz = small()
+    p = leaves(sz, 0)
+    u = jnp.asarray(np.random.default_rng(7).normal(
+        size=(4, 16, sz["hidden_size"])), jnp.float32)
+    out, probe = jit_moe(sz)(u, p, jnp.zeros((4,), jnp.int32))
+    assert [int(v) for v in probe] == [0, 0, 0, 0]
+    np.testing.assert_allclose(
+        out.reshape(64, -1), ref.shared_part(u.reshape(64, -1), f32(p)),
+        rtol=2e-4, atol=2e-5)
+
+
+def routed_share_in_one_pass(x2, rows_in, p, top_k, scale, share, valid,
+                             act, axis_name, tokens):
+    """`moe._routed_share` as every step ran it before a step of more
+    than one token a row took its held selections in passes: all N k
+    selections sorted, gathered, evaluated and gathered back in one
+    pass. What a decode step's program must still be."""
+    n = x2.shape[0]
+    eh = p["w1"].shape[0]
+    _, sel, w = moe.route_top_k(x2, p["router_w"], top_k, score="sigmoid",
+                                select_bias=p["router_bias"],
+                                normalise=True, scale=scale)
+    local = sel - share * eh
+    held = (local >= 0) & (local < eh) & valid[:, None]
+    eid = jnp.where(held, local, eh).reshape(n * top_k)
+    order = jnp.argsort(eid, stable=True)
+    sizes = jnp.zeros((eh + 1,), jnp.int32).at[eid].add(1)[:eh]
+    f = moe.experts_apply(rows_in[order // top_k], sizes, p["w1"], p["w2"],
+                          act)
+    wf = jnp.where(held, w, 0.0).reshape(n * top_k)[order]
+    f = jnp.where((eid[order] < eh)[:, None], f * wf[:, None], 0.0)
+    back = jnp.zeros((n * top_k,), jnp.int32).at[order].set(
+        jnp.arange(n * top_k, dtype=jnp.int32))
+    routed = f[back].reshape(n, top_k, -1).sum(axis=1)
+    hit = jnp.sum(sizes > 0).astype(jnp.int32)
+    busiest = jnp.max(sizes)
+    n_held = jnp.sum(sizes)
+    if axis_name is not None:
+        routed = jax.lax.psum(routed, axis_name)
+        hit = jax.lax.psum(hit, axis_name)
+        n_held = jax.lax.psum(n_held, axis_name)
+        busiest = jax.lax.pmax(busiest, axis_name)
+    probe = jnp.stack([jnp.sum(valid).astype(jnp.int32) * top_k,
+                       n_held, hit, busiest])
+    return routed, probe
+
+
+@pytest.mark.parametrize("layer", ["latent_moe", "gated_moe"])
+def test_a_long_step_holds_no_row_a_selection(layer, monkeypatch):
+    """Both expert layers, hidden 40, latent 24, 16 experts top-4 (4
+    held), lowered: a step of 4 x 16 tokens (N k = 256 selections) has
+    no array of 256 rows of a width of the layer, where a decode step
+    of 64 rows has its [256, width] rows; and the decode step's program
+    is the one-pass layer's, op for op."""
+    d, lat, f, e, eh = 40, 24, 56, 16, 4
+    width, wide = (lat, f) if layer == "latent_moe" else (d, 2 * f)
+    p = {"router_w": (d, e), "router_bias": (e,), "w1": (eh, width, wide),
+         "w2": (eh, f, width), "shared_w1": (d, wide), "shared_w2": (f, d)}
+    if layer == "latent_moe":
+        p.update(down=(d, lat), up=(lat, d))
+    p = {k: jax.ShapeDtypeStruct(v, jnp.float32) for k, v in p.items()}
+
+    def lowered(b, t):
+        return jax.jit(lambda x, p, nv: getattr(moe, layer)(
+            x, p, 4, 2.5, share=1, n_valid=nv)).lower(
+            jax.ShapeDtypeStruct((b, t, d), jnp.float32), p,
+            jax.ShapeDtypeStruct((b,), jnp.int32)).as_text()
+
+    long_step, decode = lowered(4, 16), lowered(64, 1)
+    # a row a selection would be 256 rows of one of the layer's widths
+    rows = {int(w) for w in re.findall(r"tensor<256x(\d+)x", long_step)}
+    assert not rows & {d, lat, f, 2 * f}, rows
+    assert f"tensor<256x{width}x" in decode
+    monkeypatch.setattr(moe, "_routed_share", routed_share_in_one_pass)
+    assert lowered(64, 1) == decode
 
 
 # -- (b) through GenerationEngine ---------------------------------------------
@@ -410,6 +510,69 @@ def test_the_records_count_the_rows_the_mixers_compute():
     assert counted == sum(r["state_rows_computed"] for r in records) > 0
 
 
+def test_the_records_count_the_prefill_steps_passes():
+    """The prefill step fetches its expert layers' probes beside its
+    health probe: over a run of the rehearsal's engine (3 slots, top-4:
+    passes of 12 selections) the records' `moe_prefill_held` and
+    `moe_passes` are the held selections and the passes, layer by
+    layer, that those probes count, and the monitor's counters their
+    sums; the decode-step fields count the decode steps' probes alone."""
+    from paddle_tpu import monitor
+    cfg, sz = small()
+    cell = hybrid_serve.build(cfg, {"timeout_ms": 600000}, 1, SEED)
+    eng = cell.engine
+    cell.warm()
+    fetched = []
+    inner = eng._run_paged
+
+    def spy(prog, step, tokens, *rest):
+        out = inner(prog, step, tokens, *rest)
+        fetched.append((step is eng.prefill_step, len(tokens),
+                        np.array(eng._probe)))
+        return out
+    eng._run_paged = spy
+    rng = np.random.default_rng(9)
+    prev = fluid.FLAGS.enable_monitor
+    fluid.set_flags({"FLAGS_enable_monitor": True})
+    monitor.reset_stats()
+    try:
+        t0 = time.perf_counter()
+        resps = [eng.submit(GenerationRequest(
+            rng.integers(0, sz["vocab_size"], n).tolist(), 3,
+            timeout_ms=600000)) for n in (40, 33, 17, 5)]
+        for resp in resps:
+            assert len(resp.result(timeout=300)["tokens"]) == 3
+        cell.stop()
+        counters = monitor.get_stats_snapshot()["counters"]
+    finally:
+        monitor.reset_stats()
+        fluid.set_flags({"FLAGS_enable_monitor": prev})
+    records = [r for r in fluid.trace.iteration_records()
+               if r["t_start"] >= t0]
+    k = sz["num_experts_per_tok"]
+    prefill = [(b, probe) for pre, b, probe in fetched if pre]
+    decode = [probe for pre, _, probe in fetched if not pre]
+    assert prefill and decode
+    assert all(probe.shape == (2, 4) for _, probe in prefill)
+    held = sum(int(probe[:, 1].sum()) for _, probe in prefill)
+    trips = [-(-int(h) // (b * k)) for b, probe in prefill
+             for h in probe[:, 1]]
+    passes = sum(trips)
+    assert held > 0 and max(trips) > 1
+    assert sum(r["moe_prefill_held"] for r in records) == held
+    assert sum(r["moe_passes"] for r in records) == passes
+    assert counters["serving.gen_moe_prefill_held"] == held
+    assert counters["serving.gen_moe_passes"] == passes
+    assert sum(r["moe_selected"] for r in records) == \
+        sum(int(probe[:, 0].sum()) for probe in decode)
+    assert sum(r["moe_selected_held"] for r in records) == \
+        sum(int(probe[:, 1].sum()) for probe in decode)
+    assert sum(r["moe_experts_hit"] for r in records) == \
+        sum(int(probe[:, 2].sum()) for probe in decode)
+    assert sum(r["moe_load_max"] for r in records) == \
+        sum(int(probe[:, 3].sum()) for probe in decode)
+
+
 # -- (e) what the engine does not do for a recurrent model --------------------
 
 def test_prefix_cache_adopts_nothing_and_spec_disagg_wire_refuse():
@@ -479,18 +642,19 @@ def model_ops(prog, step):
     return ops[:first]
 
 
-def fingerprint(prog, step=None):
+def fingerprint(prog, step=None, end=None):
     """Op list, attributes and shapes, every variable named by the order
     it first appears in (the process's name counters do not show). With
     `step`, a decode or verify step's handle, of the model's own ops
-    (`model_ops`): the digests held below were taken of those."""
+    (`model_ops`): the digests held below were taken of those. `end`
+    leaves the ops from that index on out."""
     blk = prog.global_block()
     order = {}
 
     def idx(n):
         return order.setdefault(n, len(order))
     sig = []
-    for op in (blk.ops if step is None else model_ops(prog, step)):
+    for op in (blk.ops[:end] if step is None else model_ops(prog, step)):
         ins = [(k, [idx(n) for n in v]) for k, v in sorted(op.inputs.items())]
         outs = [(k, [idx(n) for n in v])
                 for k, v in sorted(op.outputs.items())]
@@ -553,12 +717,28 @@ def test_gpt_verify_program_is_the_parents():
           "80dc17eb9198f91284205442575f7ff3"))])
 def test_hybrid_programs_are_the_parents(dtype, decode, prefill):
     """The engine that `engine_logits` builds, not started: its two
-    programs digest as they did in the parent commit (PR 30's tree)."""
+    programs digest as the digests below were taken, the prefill
+    program but for the op that stacks its expert layers' probes,
+    last."""
     cfg, _ = small()
     cfg = dict(cfg, engine=dict(cfg["engine"], dtype=dtype, max_slots=3))
     eng = hybrid_serve.build(cfg, {"timeout_ms": 600000}, 1, SEED).engine
     assert fingerprint(eng._prog, eng.step) == decode
-    assert fingerprint(eng._prefill_prog) == prefill
+    assert_prefill_probes_stacked_last(eng, prefill)
+
+
+def assert_prefill_probes_stacked_last(eng, prefill):
+    """The prefill program is the digest `prefill` and then one `stack`
+    of its expert layers' probes, which the step fetches second."""
+    ops = eng._prefill_prog.global_block().ops
+    probes = [op.output("Probe")[0] for op in ops
+              if op.type in ("latent_moe", "gated_moe")]
+    assert probes and ops[-1].type == "stack"
+    assert ops[-1].input("X") == probes
+    assert eng.fetch_list(eng._prefill_prog) == [
+        eng.prefill_step.logits_var, eng.prefill_step.probe_var]
+    assert ops[-1].output_names() == [eng.prefill_step.probe_var.name]
+    assert fingerprint(eng._prefill_prog, end=-1) == prefill
 
 
 @pytest.mark.parametrize("dtype,decode,prefill", [
@@ -575,14 +755,15 @@ def test_hybrid_programs_are_the_parents(dtype, decode, prefill):
 def test_latent_programs_are_the_parents(dtype, decode, prefill):
     """The latent-attention decoder's two programs (letters L, D, G with
     a query latent and rotary attributes) digest as they did before the
-    `L` letter learned to do without either (PR 34's tree)."""
+    `L` letter learned to do without either, the prefill program but
+    for the stack of its probes, last."""
     from benchmark.families import mla_serve
     _, cfg, _, _ = manifest.cell("kimi_k2_5_ep32_l5.batch_long_ctx",
                                  rehearsal=True)
     cfg = dict(cfg, engine=dict(cfg["engine"], dtype=dtype, max_slots=3))
     eng = mla_serve.build(cfg, {"timeout_ms": 600000}, 1, SEED).engine
     assert fingerprint(eng._prog, eng.step) == decode
-    assert fingerprint(eng._prefill_prog) == prefill
+    assert_prefill_probes_stacked_last(eng, prefill)
 
 
 def test_paged_argument_selects_nothing():

@@ -232,6 +232,15 @@ def test_the_shares_add_up_to_the_uncut_layer():
                                atol=2e-5)
     np.testing.assert_allclose(routed_op + shared, whole, rtol=2e-4,
                                atol=2e-5)
+    # over the mesh each share loops over its own held selections: a
+    # step of 24 tokens in one row takes passes of 1 x top_k, and the
+    # shares' trip counts differ
+    sel, _ = ref.route(u, p, sz)
+    trips = [int(moe.passes(int(((sel >= k * held)
+                                 & (sel < (k + 1) * held)).sum()),
+                            1, sz["num_experts_per_tok"]))
+             for k in range(4)]
+    assert len(set(trips)) > 1, trips
     mesh = jax.sharding.Mesh(np.array(jax.devices()[:4]), ("ep",))
     out, probe = moe.gated_moe_sharded(
         u[None], moe_params(p), mesh, sz["num_experts_per_tok"],
@@ -243,7 +252,11 @@ def test_the_shares_add_up_to_the_uncut_layer():
 def test_no_token_is_dropped_when_every_token_takes_one_expert():
     """A selection bias that sends all 64 tokens to expert 1 (and to an
     expert of another share): the held expert sees 64 tokens, 16 times
-    the even load of top-2 over 8, and every one gets its output."""
+    the even load of top-2 over 8, and every one gets its output. The
+    step of 4 x 16 tokens takes its 64 held selections in passes of a
+    decode step's 4 x 2, so in eight, the one group cut at every
+    boundary: it equals the reference and the same tokens taken as a
+    decode step (64 rows of one token, one pass)."""
     _, sz = small()
     p = leaves(sz, 3)
     bias = np.full(sz["router_width"], -10.0, np.float32)
@@ -253,12 +266,32 @@ def test_no_token_is_dropped_when_every_token_takes_one_expert():
     u = jnp.asarray(rng.normal(size=(4, 16, sz["hidden_size"])), jnp.float32)
     out, probe = jit_moe(sz)(u, p, jnp.full((4,), 16, jnp.int32))
     assert [int(v) for v in probe] == [128, 64, 1, 64]
+    assert moe.passes(64, 4, sz["num_experts_per_tok"]) == 8
     flat = u.reshape(64, -1)
     want = jax.jit(lambda u: ref.gated_moe(u, p, sz))(flat)
     np.testing.assert_allclose(out.reshape(64, -1), want, rtol=2e-4,
                                atol=2e-5)
+    one, probe = jit_moe(sz)(u.reshape(64, 1, -1), p,
+                             jnp.ones((64,), jnp.int32))
+    assert [int(v) for v in probe] == [128, 64, 1, 64]
+    np.testing.assert_allclose(out.reshape(64, -1), one.reshape(64, -1),
+                               rtol=2e-4, atol=2e-5)
     routed = out.reshape(64, -1) - ref.shared_part(flat, p)
     assert float(jnp.abs(routed).max(axis=1).min()) > 1e-7
+
+
+def test_a_step_with_no_valid_token_takes_no_pass():
+    """Every row of a 4 x 16 step muted: nothing is selected or
+    evaluated (no pass), and the output is the shared expert's."""
+    _, sz = small()
+    p = leaves(sz, 3)
+    u = jnp.asarray(np.random.default_rng(7).normal(
+        size=(4, 16, sz["hidden_size"])), jnp.float32)
+    out, probe = jit_moe(sz)(u, p, jnp.zeros((4,), jnp.int32))
+    assert [int(v) for v in probe] == [0, 0, 0, 0]
+    np.testing.assert_allclose(
+        out.reshape(64, -1), ref.shared_part(u.reshape(64, -1), p),
+        rtol=2e-4, atol=2e-5)
 
 
 # -- (e) through GenerationEngine ---------------------------------------------
